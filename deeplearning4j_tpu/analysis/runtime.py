@@ -69,10 +69,7 @@ def device_residency(level: str = "disallow"):
     host<->device transfers raise on EVERY thread (the scheduler/dispatch
     threads included — jax.transfer_guard's context-manager form is
     thread-local, which would silently skip them)."""
-    try:
-        prev = jax.config.jax_transfer_guard
-    except AttributeError:  # much older jax: nothing to restore
-        prev = None
+    prev = jax.config.jax_transfer_guard
     jax.config.update("jax_transfer_guard", level)
     try:
         yield

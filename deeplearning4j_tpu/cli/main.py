@@ -38,7 +38,9 @@ from __future__ import annotations
 
 import argparse
 import json
+import signal
 import sys
+import threading
 from pathlib import Path
 
 
@@ -112,7 +114,7 @@ def cmd_predict(args) -> int:
 def cmd_serve(args) -> int:
     """Serve a saved model over HTTP (the dl4j-streaming serve-route
     analog, serving/server.py)."""
-    import time
+    import jax
 
     from ..serving import InferenceServer
 
@@ -214,7 +216,6 @@ def cmd_serve(args) -> int:
     # flag
     tp_on = int(getattr(decoder, "tp", 1))
     if tp_on > 1:
-        import jax
         mesh_mode = (f", tensor-parallel over {tp_on} of "
                      f"{len(jax.devices())} devices (tp axis; KV pool "
                      "head-sharded, per-device budgets)")
@@ -232,8 +233,17 @@ def cmd_serve(args) -> int:
         # report the fused-kernel plane's ACTUAL engagement (the warmed
         # engine's per-bucket verdicts), not just the flag
         pk_st = decoder.paged_kernel_status()
-        kern = (f", decode kernel {pk_st['mode']}"
-                + ("/fused" if pk_st["engaged"] else "/xla"))
+        if pk_st["engaged"]:
+            verdict = f"fused ({pk_st['execution']})"
+        elif pk_st["declined"]:
+            verdict = f"declined: {pk_st['declined']}"
+        elif pk_st["refused"]:
+            # XLA by default, not by victory: say what the compiler said
+            verdict = "refused: " + next(iter(
+                pk_st["refused"].values()))[:160]
+        else:
+            verdict = "xla"
+        kern = f", decode kernel {pk_st['mode']}/{verdict}"
         kv_mode = (f", paged KV pool {args.kv_pool_mb}MB "
                    f"({decoder.pool.capacity_blocks} blocks of "
                    f"{args.kv_block}"
@@ -257,8 +267,11 @@ def cmd_serve(args) -> int:
     stream_mode = (", SSE streaming + constrained decoding"
                    + (f" ({args.mask_rows} device mask rows)"
                       if mask_on else " (host-only grammar masks)"))
-    gen_mode = (f"; /generate: {args.decode_slots} slots, "
-                f"prefill chunk {args.prefill_chunk}" + kv_mode
+    # slots and chunk as the ENGINE holds them, not as the flags asked
+    gen_mode = (f"; /generate: {getattr(decoder, 'n_slots', 0)} slots, "
+                f"prefill chunk "
+                f"{max(getattr(decoder, 'prefill_buckets', None) or [1])}"
+                + kv_mode
                 + stream_mode + spec_mode + mesh_mode
                 + (f", supervised (hang timeout {args.hang_timeout}s, "
                    f"retry budget {args.retry_budget})"
@@ -266,8 +279,10 @@ def cmd_serve(args) -> int:
                 + slo_mode + prof_mode
                 if args.generate else "")
     chaos = (f"; failpoints ARMED: {', '.join(armed)}" if armed else "")
+    dev = jax.devices()[0]
     print(f"Serving {args.model} ({mode}, {batch_mode}{gen_mode}{chaos}) "
-          f"on http://127.0.0.1:{server.port} "
+          f"on {len(jax.devices())} x {dev.platform} '{dev.device_kind}' "
+          f"at http://127.0.0.1:{server.port} "
           "(POST /predict, /predict/csv"
           + (", /generate" if args.generate else "")
           + (", /admin/drain" if args.generate and not args.no_supervise
@@ -278,11 +293,14 @@ def cmd_serve(args) -> int:
     if args.once:  # test hook: start, report, stop
         server.stop()
         return 0
-    try:
-        while True:
-            time.sleep(3600)
-    except KeyboardInterrupt:
-        server.stop()
+    # SIGINT and SIGTERM both mean "stop cleanly, exit 0". Installed
+    # explicitly: a process started with SIGINT ignored (a background
+    # job of a non-interactive shell) never sees KeyboardInterrupt.
+    stop = threading.Event()
+    for sig in (signal.SIGINT, signal.SIGTERM):
+        signal.signal(sig, lambda _sig, _frm: stop.set())
+    stop.wait()
+    server.stop()
     return 0
 
 
@@ -584,6 +602,11 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    if args.func not in (cmd_router, cmd_telemetry):
+        # every other subcommand compiles; the router and the telemetry
+        # collector run no program of their own
+        from ..util.compile_cache import enable_compile_cache
+        enable_compile_cache()
     return args.func(args)
 
 

@@ -3719,11 +3719,18 @@ class DecodeScheduler:
         kernel, and the per-bucket verdict — the kernel's grid variant
         where it engaged, False where the trace fell back to XLA, None
         for buckets not traced yet (warmup() traces every bucket, so a
-        warmed engine never shows None). Read-side only: consults the
-        ops/pallas_kernels trace-time engagement registry, never
-        triggers a compile or a probe."""
+        warmed engine never shows None). A False verdict is qualified,
+        so that "XLA won the race" is not the only reading of it:
+        ``refused`` maps a bucket to the compiler's message when every
+        kernel candidate RAISED in the autotune probe, ``declined`` says
+        why the seam never offered the kernel at all (a sub-float32
+        compute dtype), and ``execution`` says whether a kernel that
+        does engage is Mosaic-compiled or running in the Pallas
+        interpreter. Read-side only: consults the ops/pallas_kernels
+        trace-time registries, never triggers a compile or a probe."""
         out = {"mode": self.paged_kernel, "engaged": False,
-               "buckets": {}}
+               "buckets": {}, "refused": {}, "declined": None,
+               "execution": None}
         if not self.paged:
             return out
         from ..ops import helpers as ophelpers
@@ -3731,8 +3738,15 @@ class DecodeScheduler:
                 or ophelpers.get_helper("paged_decode_attention") is None):
             out["buckets"] = {nb: False for nb in self.table_buckets}
             return out
-        from ..ops.pallas_kernels import paged_decode_decisions
+        from ..ops.pallas_kernels import (autotune_refusals,
+                                          kernel_execution,
+                                          paged_decode_decisions)
+        out["execution"] = kernel_execution()
+        if jnp.dtype(self._dtype) != jnp.float32:
+            out["declined"] = (f"compute dtype {jnp.dtype(self._dtype).name}"
+                               " (the kernel is float32-only)")
         dec = paged_decode_decisions()
+        refusals = autotune_refusals()
         # match THIS engine's traces exactly: batch/table/block dims,
         # the per-shard head geometry of its own attention layers,
         # compute dtype, int8-ness, AND its mode — the registry is
@@ -3755,6 +3769,17 @@ class DecodeScheduler:
             engaged = [v for v in hits if v]
             out["buckets"][nb] = (engaged[0] if engaged
                                   else (False if hits else None))
+            if hits and not engaged:
+                # XLA by default, not by victory: every kernel
+                # candidate raised ("xla" keys the reference's own
+                # failure, which is not a kernel refusal)
+                for hk in heads:
+                    why = {c: r for c, r in refusals.get(
+                        ("paged_decode", self.n_slots, nb, self.kv_block)
+                        + hk + (dt, quant), {}).items() if c != "xla"}
+                    if why:
+                        out["refused"][nb] = "; ".join(
+                            f"{c}: {r}" for c, r in sorted(why.items()))
         out["engaged"] = any(bool(v) for v in out["buckets"].values())
         if getattr(self, "_m_paged_kernel", None) is not None:
             self._m_paged_kernel.set(1 if out["engaged"] else 0)
@@ -3797,6 +3822,7 @@ class DecodeScheduler:
             "slots": slots,
             "compile_cache": self._compile_counter.counts(),
             "mesh": {"tp": self.tp},
+            "prefill_buckets": list(self.prefill_buckets),
             "chunk_cap": self.chunk_cap,
         }
         if self.maskpool is not None:

@@ -24,14 +24,15 @@ step-time ratio is floor-gated ≥ 0.95 (`bench.py profiler_overhead`).
 **Cost attribution** (:func:`program_costs` + the profiler's rolling
 FLOPs window). At warmup, every compiled program family (decode /
 prefill / verify / draft, per bucket, at the engine's actual mesh size)
-is lowered through ``.lower(...).compile().cost_analysis()`` — the XLA
-cost model's FLOPs and bytes-accessed per invocation. Live dispatch
+is lowered through ``.lower(...).cost_analysis()`` (on the TPU, the
+compiled program's) — the XLA cost model's FLOPs and bytes-accessed per
+invocation. Live dispatch
 counts (stamped by the scheduler per dispatch) combine with the table
 into derived gauges: ``decode_tokens_per_sec``,
-``device_flops_per_sec``, ``device_mfu_estimate`` (against a per-device
-peak — a documented *estimate*: the peak comes from a device-kind table
-or ``DL4J_PEAK_FLOPS``), ``device_hbm_gbps`` and per-family FLOPs
-shares — exposed on `/metrics`, `/info`, and `GET /debug/engine`.
+``device_flops_per_sec``, ``device_mfu_estimate`` (against the
+device's published bf16 peak from :data:`DEVICE_PEAKS`; a device kind
+that table does not list gets no MFU at all), ``device_hbm_gbps`` and
+per-family FLOPs shares — exposed on `/metrics`, `/info`, and `GET /debug/engine`.
 
 **SLO monitor** (:class:`SLOMonitor`). Sliding-window p50/p95/p99 per
 HTTP route plus **multi-window burn rates** against a configurable
@@ -51,7 +52,6 @@ straight back into the flight recorder.
 from __future__ import annotations
 
 import collections
-import os
 import threading
 import time
 import weakref
@@ -81,15 +81,16 @@ def burn_verdict(fast: float, slow: float, fast_burn: float = 6.0,
 PHASES = ("admit", "prefill", "draft", "pool", "decode", "accept",
           "verify", "flush")
 
-# nominal per-device peak FLOP/s by device kind — the MFU denominator.
-# Deliberately coarse (dense fp32/bf16 marketing peaks): MFU here is an
-# ESTIMATE for attribution ("are we at 3% or 30%"), not a benchmark
-# claim. Override with DL4J_PEAK_FLOPS or the peak_flops knob.
-DEVICE_PEAK_FLOPS = {
-    "TPU v2": 22.5e12, "TPU v3": 61.25e12, "TPU v4": 137.5e12,
-    "TPU v5 lite": 98.5e12, "TPU v5p": 229.5e12, "TPU v6 lite": 459e12,
+# Published per-chip peaks keyed by jax ``device_kind`` — the one table
+# MFU and roofline figures divide by. Source: Google Cloud TPU
+# documentation, "TPU v5e" system architecture (197 TFLOP/s bf16 on the
+# MXU, 819 GB/s HBM per chip). A device kind that is not a key has NO
+# peak: its MFU is null, never a default. Host CPUs (the test platform)
+# are listed so that the null is a decision, not a lookup miss.
+DEVICE_PEAKS: Dict[str, Optional[Dict[str, float]]] = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
+    "cpu": None,
 }
-_CPU_PEAK_FLOPS = 1e11  # ~a few AVX cores; CPU MFU is order-of-magnitude
 
 
 # net -> {engine-shape tuple -> cost table}; weak on the net so the
@@ -122,40 +123,30 @@ def cached_program_costs(engine):
     return dict(cached) if cached is not None else None
 
 
-def device_peak_flops(default: float = _CPU_PEAK_FLOPS) -> float:
-    """Per-device peak FLOP/s estimate: ``DL4J_PEAK_FLOPS`` env override,
-    else the device-kind table, else ``default``."""
-    env = os.environ.get("DL4J_PEAK_FLOPS")
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
-    try:
+def device_peak_flops(device_kind: Optional[str] = None
+                      ) -> Optional[float]:
+    """Published bf16 peak FLOP/s of one device of ``device_kind``
+    (default: this process's first device), or None when
+    :data:`DEVICE_PEAKS` has no figure for it."""
+    if device_kind is None:
         import jax
-        kind = jax.devices()[0].device_kind
-    except Exception:
-        return default
-    for key, peak in DEVICE_PEAK_FLOPS.items():
-        if key.lower() in str(kind).lower():
-            return peak
-    return default
+        device_kind = jax.devices()[0].device_kind
+    peaks = DEVICE_PEAKS.get(str(device_kind))
+    return peaks["bf16_flops"] if peaks else None
 
 
 def _cost_of(lowered) -> Dict[str, float]:
     """FLOPs / bytes-accessed of one lowered program via the XLA cost
     model. `Lowered.cost_analysis()` runs HLO-level analysis WITHOUT the
-    backend compile (milliseconds, so warming a many-bucket paged family
-    costs tracing time, not a second full compile pass); older jax falls
-    back to ``.compile().cost_analysis()``. The result is a dict (newer
-    jax) or a one-per-device list of dicts; missing keys read 0 (some
-    backends publish partial models)."""
-    try:
-        c = lowered.cost_analysis()
-    except (AttributeError, NotImplementedError):
+    backend compile (milliseconds) where the client has one; the TPU
+    client answers None (seen on a v5e with jaxlib 0.9.0), and there the
+    compiled program is asked instead — the same program the engine's
+    warm-up already compiled, so with the persistent compile cache on
+    (util/compile_cache.py) this is a load, not a second compile.
+    Missing keys read 0 (some backends publish partial models)."""
+    c = lowered.cost_analysis()
+    if c is None:
         c = lowered.compile().cost_analysis()
-    if isinstance(c, (list, tuple)):
-        c = c[0] if c else {}
     return {"flops": float(c.get("flops", 0.0) or 0.0),
             "bytes": float(c.get("bytes accessed", 0.0) or 0.0)}
 
@@ -280,13 +271,13 @@ class StepPhaseProfiler:
     def __init__(self, metrics: Optional[MetricsRegistry] = None, *,
                  enabled: bool = True, window: int = 256,
                  gauge_every: int = 16,
-                 peak_flops: Optional[float] = None,
-                 peak_hbm_gbps: float = 100.0):
+                 peak_flops: Optional[float] = None):
         self.enabled = bool(enabled)
         self.metrics = metrics if metrics is not None else default_registry()
-        self.peak_flops = (float(peak_flops) if peak_flops
-                           else device_peak_flops())
-        self.peak_hbm_gbps = float(peak_hbm_gbps)
+        # None = the device has no published peak (DEVICE_PEAKS): the
+        # MFU gauge stays unset and the read side reports null
+        self.peak_flops: Optional[float] = (
+            float(peak_flops) if peak_flops else device_peak_flops())
         self._window = max(8, int(window))
         self._gauge_every = max(1, int(gauge_every))
         # cumulative per-phase seconds (scheduler-thread-only writes;
@@ -334,8 +325,9 @@ class StepPhaseProfiler:
             self._g_mfu = m.gauge(
                 "device_mfu_estimate",
                 help="model-FLOPs-utilization estimate: attributed "
-                     "FLOP/s over the per-device peak (device-kind "
-                     "table or DL4J_PEAK_FLOPS) x mesh size")
+                     "FLOP/s over the device's published bf16 peak "
+                     "(profiler.DEVICE_PEAKS); never set on a device "
+                     "kind the table does not list")
             self._g_hbm = m.gauge(
                 "device_hbm_gbps",
                 help="rolling attributed memory traffic (cost_analysis "
@@ -434,7 +426,7 @@ class StepPhaseProfiler:
         self._g_tps.set((self.tokens_total - k0) / dt)
         fps = (self.flops_total - f0) / dt
         self._g_flops.set(fps)
-        if self.peak_flops > 0:
+        if self.peak_flops:
             self._g_mfu.set(fps / self.peak_flops)
         self._g_hbm.set((self.bytes_total - b0) / dt / 1e9)
         total_f = sum(self.family_flops.values())
@@ -459,23 +451,23 @@ class StepPhaseProfiler:
 
     def rates(self) -> Dict[str, float]:
         """Rolling-window rates (the gauges' values, computed fresh)."""
-        if not self.iterations:
-            return {"tokens_per_sec": 0.0, "flops_per_sec": 0.0,
-                    "mfu_estimate": 0.0, "hbm_gbps": 0.0}
-        now = time.monotonic()
-        oldest = self._ring[self.iterations % self._window] \
-            if self.iterations >= self._window else self._ring[0]
+        oldest = None
+        if self.iterations:
+            oldest = self._ring[self.iterations % self._window] \
+                if self.iterations >= self._window else self._ring[0]
         if oldest is None:
             return {"tokens_per_sec": 0.0, "flops_per_sec": 0.0,
-                    "mfu_estimate": 0.0, "hbm_gbps": 0.0}
+                    "mfu_estimate": 0.0 if self.peak_flops else None,
+                    "hbm_gbps": 0.0}
         t0, f0, b0, k0 = oldest
-        dt = max(1e-9, now - t0)
+        dt = max(1e-9, time.monotonic() - t0)
         fps = (self.flops_total - f0) / dt
         return {
             "tokens_per_sec": round((self.tokens_total - k0) / dt, 3),
             "flops_per_sec": round(fps, 1),
+            # null, not a guess, where the device has no published peak
             "mfu_estimate": round(fps / self.peak_flops, 6)
-            if self.peak_flops > 0 else 0.0,
+            if self.peak_flops else None,
             "hbm_gbps": round((self.bytes_total - b0) / dt / 1e9, 6),
         }
 
@@ -506,6 +498,9 @@ class StepPhaseProfiler:
                 for f, v in sorted(self.family_flops.items())}
             if total_f > 0 else {},
             "peak_flops_per_device": self.peak_flops,
+            "peak_note": None if self.peak_flops else (
+                "no published peak for this device kind "
+                "(profiler.DEVICE_PEAKS): MFU is not computed"),
             **self.rates(),
         }
 

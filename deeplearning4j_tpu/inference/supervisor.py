@@ -229,7 +229,11 @@ class EngineSupervisor:
         # watchdog starts below and the degradation/engine state it reads
         # is lock-guarded from the first instant
         with self._lock:
-            self.engine = self._spawn_engine()
+            # the FIRST engine's warm-up failure is fatal: at start-up
+            # there is no traffic to protect, and a server that came up
+            # over a program the compiler refused would only crash-loop
+            # under its first request
+            self.engine = self._spawn_engine(strict_warm=True)
         self._g_ready.set(1)
         self._watchdog: Optional[threading.Thread] = None
         if watchdog:
@@ -238,7 +242,7 @@ class EngineSupervisor:
             self._watchdog.start()
 
     # -- engine lifecycle --------------------------------------------------
-    def _spawn_engine(self) -> DecodeScheduler:
+    def _spawn_engine(self, strict_warm: bool = False) -> DecodeScheduler:
         """Build, hook, start, and WARM a fresh engine. Warming runs one
         synthetic request whose prompt touches every prefill chunk
         bucket plus the decode/admit programs, so the XLA compiles land
@@ -251,15 +255,20 @@ class EngineSupervisor:
         self._apply_degradation(eng, self.degradation_level)
         eng.start()
         if self._warm_on_build:
-            self._warm(eng)
+            try:
+                self._warm(eng, strict=strict_warm)
+            except Exception:  # strict only: leave no loop thread behind
+                eng.stop()
+                raise
         return eng
 
-    def _warm(self, eng: DecodeScheduler) -> None:
-        """Best-effort program-family warm-up (engine.warmup compiles
-        every bucket's program with pure discarded calls — no metrics,
-        trace, or pool side effects). A failure is traced, never
-        swallowed, and never fatal: an unwarmed engine still serves,
-        it just compiles under traffic.
+    def _warm(self, eng: DecodeScheduler, strict: bool = False) -> None:
+        """Program-family warm-up (engine.warmup compiles every
+        bucket's program with pure discarded calls — no metrics, trace,
+        or pool side effects). On a recovery or drain rebuild a failure
+        is traced, never swallowed, and never fatal: an unwarmed engine
+        still serves, it just compiles under traffic. ``strict`` (the
+        first engine of a supervisor) re-raises instead.
 
         When any TRACKED in-flight request carries a grammar, the
         masked program families are warmed too (``warmup(masks=True)``)
@@ -279,6 +288,8 @@ class EngineSupervisor:
             # the chaos drills expose a zero-arg warmup()
             warmup(masks=True) if masks else warmup()
         except Exception as e:
+            if strict:
+                raise
             self.tracer.instant("warmup_skipped", track="supervisor",
                                 args={"error": type(e).__name__,
                                       "detail": str(e)[:200]})

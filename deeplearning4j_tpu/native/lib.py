@@ -1,6 +1,8 @@
 """Loader for the C++ host runtime (native_src/dl4jtpu_native.cpp).
 
-Build-on-first-use with g++ (cached in the package's build dir), loaded via
+Build-on-first-use with g++ (cached in the package's build dir under a
+name that carries a hash of the source, so a stale binary can never be
+loaded and a fresh copy of the tree builds exactly once), loaded via
 ctypes — the JavaCPP/JNI bridge analog of the reference's nd4j-native
 backend loader, with the same silent-fallback contract: if no toolchain is
 available the pure-NumPy implementations take over and everything still
@@ -9,6 +11,7 @@ runs (reference backend discovery falls back the same way).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import subprocess
 import threading
 from pathlib import Path
@@ -19,19 +22,26 @@ import numpy as np
 _SRC = Path(__file__).resolve().parent.parent.parent / "native_src" \
     / "dl4jtpu_native.cpp"
 _BUILD_DIR = Path(__file__).resolve().parent / "_build"
-_SO = _BUILD_DIR / "libdl4jtpu_native.so"
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
+def _so_path() -> Path:
+    """The cached build for the source AS IT IS NOW: file times do not
+    survive a copy of the tree, content does."""
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    return _BUILD_DIR / f"libdl4jtpu_native-{digest}.so"
+
+
 def _build() -> Optional[Path]:
     import os
     import uuid
+    so = _so_path()
+    if so.exists():
+        return so
     _BUILD_DIR.mkdir(parents=True, exist_ok=True)
-    if _SO.exists() and _SO.stat().st_mtime >= _SRC.stat().st_mtime:
-        return _SO
     # compile to a unique temp path and rename atomically: concurrent
     # builders (multi-process tests) and killed builds must never leave a
     # half-written .so at the canonical path
@@ -42,8 +52,8 @@ def _build() -> Optional[Path]:
         proc = subprocess.run(cmd, capture_output=True, timeout=120)
         if proc.returncode != 0:
             return None
-        os.replace(tmp, _SO)
-        return _SO
+        os.replace(tmp, so)
+        return so
     except (OSError, subprocess.TimeoutExpired):
         return None
     finally:

@@ -29,6 +29,9 @@ def default_mesh(n_devices: Optional[int] = None, axis: str = DATA_AXIS) -> Mesh
     """1-D mesh over the first n local devices."""
     devs = jax.devices()
     n = n_devices or len(devs)
+    if n > len(devs):
+        # devs[:n] would quietly build a smaller mesh than was asked for
+        raise ValueError(f"mesh of {n} devices asked for, have {len(devs)}")
     return Mesh(np.asarray(devs[:n]), (axis,))
 
 

@@ -48,7 +48,49 @@ import urllib.request
 from typing import Dict, List, Optional, Tuple
 
 __all__ = ["ReplicaProcess", "ReplicaSupervisor", "lm_spec_argv",
-           "write_announce", "main"]
+           "write_announce", "one_chip_envs", "main"]
+
+
+def _host_tpu_chips() -> List[str]:
+    """Ids of the TPU chips this host exposes to a process, read from
+    the v5e's device nodes (``/dev/vfio/<n>``) — never from JAX: the
+    caller is a parent that must not initialise a backend. (The PCI bus
+    is no guide: it lists four chips on a machine that was handed one.)"""
+    try:
+        names = os.listdir("/dev/vfio")
+    except OSError:
+        return []
+    return sorted((n for n in names if n.isdigit()), key=int)
+
+
+def one_chip_envs(n: int) -> List[Dict[str, str]]:
+    """One environment fragment per replica of an ``n``-replica fleet on
+    THIS host, confining replica ``i`` to a TPU chip of its own.
+
+    A chip belongs to one process at a time, so replicas that inherited
+    the parent's environment unchanged would all ask for every chip: one
+    wins and the rest fail at start-up and are respawned for ever. On a
+    host without TPU chips, or when the caller asked for the CPU
+    (``JAX_PLATFORMS=cpu``), there is nothing to divide and the
+    fragments are empty. More replicas than chips raises ``ValueError``
+    with the count, at launch."""
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return [{} for _ in range(n)]
+    chips = _host_tpu_chips()
+    if not chips:
+        return [{} for _ in range(n)]
+    if n > len(chips):
+        raise ValueError(
+            f"{n} replicas need {n} TPU chips (a chip belongs to one "
+            f"process at a time) but this host exposes {len(chips)} "
+            f"({', '.join(chips)})")
+    return [{"TPU_VISIBLE_CHIPS": chips[i],
+             "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+             "TPU_PROCESS_BOUNDS": "1,1,1",
+             # each one-chip runtime opens its own controller port
+             "TPU_MESH_CONTROLLER_ADDRESS": f"localhost:{8476 + i}",
+             "TPU_MESH_CONTROLLER_PORT": str(8476 + i)}
+            for i in range(n)]
 
 
 def write_announce(path: str, port: int, armed: List[str]) -> None:
@@ -542,6 +584,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--trace-buffer", type=int, default=8192)
     ap.add_argument("--failpoint-endpoint", action="store_true")
     args = ap.parse_args(argv)
+
+    from ..util.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from ..inference import failpoints
     from .server import InferenceServer

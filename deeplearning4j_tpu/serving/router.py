@@ -93,7 +93,7 @@ from ..inference.profiler import SLOMonitor, burn_verdict
 from ..inference.trace import FlightRecorder
 from .durable import DurableLogConsumer, DurableLogProducer
 from .replica import (ReplicaProcess, ReplicaSupervisor, _get_json,
-                      write_announce)
+                      one_chip_envs, write_announce)
 from .telemetry import (TRACE_HEADER, FleetMetrics, TraceContext,
                         format_trace_header, new_trace_id,
                         parse_trace_header, span_id)
@@ -1655,7 +1655,7 @@ class _ReplicaClientError(Exception):
 # subprocess entry point
 # ---------------------------------------------------------------------------
 
-def main(argv: Optional[List[str]] = None) -> int:
+def build_parser():
     import argparse
 
     ap = argparse.ArgumentParser(
@@ -1700,6 +1700,11 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="directory hits keep the rendezvous target and "
                          "instruct it to PULL the chain from the holder "
                          "(instead of routing to the holder)")
+    return ap
+
+
+def main(argv: Optional[List[str]] = None) -> int:
+    ap = build_parser()
     args = ap.parse_args(argv)
     if bool(args.replicas) == bool(args.spawn):
         ap.error("pass exactly one of --replicas or --spawn")
@@ -1709,8 +1714,14 @@ def main(argv: Optional[List[str]] = None) -> int:
         replica_argv = list(args.replica_arg)
         if args.paged_kernel is not None:
             replica_argv += ["--paged-kernel", args.paged_kernel]
+        try:
+            # one TPU chip per replica, decided here without touching
+            # JAX (this process must never hold a chip its replicas need)
+            envs = one_chip_envs(args.spawn)
+        except ValueError as e:
+            ap.error(f"--spawn {args.spawn}: {e}")
         sup = ReplicaSupervisor(
-            [ReplicaProcess(replica_argv, name=f"r{i}")
+            [ReplicaProcess(replica_argv, name=f"r{i}", env=envs[i])
              for i in range(args.spawn)])
     else:
         sup = ReplicaSupervisor(

@@ -153,6 +153,7 @@ from __future__ import annotations
 
 import hashlib
 import json
+import os
 import re
 import select
 import socket
@@ -798,11 +799,22 @@ class InferenceServer:
                 elif url.path == "/info":
                     import jax  # mesh topology: visible vs used devices
                     dec = server._decoder
+                    devs = jax.devices()
                     body = {"model": type(server.net).__name__,
                             "config": json.loads(server.net.conf.to_json()),
                             "params": server.net.num_params(),
                             "batching": server.batching,
-                            "mesh": {"devices": len(jax.devices()),
+                            # what this process runs on, so a client
+                            # (or a parent that must stay off the
+                            # chip) can check it over HTTP
+                            "platform": devs[0].platform,
+                            "device_kind": devs[0].device_kind,
+                            "mesh": {"devices": len(devs),
+                                     # the chip a fleet parent confined
+                                     # this process to (JAX numbers its
+                                     # device 0 in every such process)
+                                     "visible_chips": os.environ.get(
+                                         "TPU_VISIBLE_CHIPS"),
                                      "tp": getattr(dec, "tp", 1)},
                             "slo": server.slo.snapshot()}
                     prof = getattr(dec, "profiler", None)
@@ -861,7 +873,6 @@ class InferenceServer:
                     # the fleet aggregator brackets this read with its
                     # own wall clock to place this process's trace ts
                     # axis on the fleet timeline to within ±RTT/2
-                    import os
                     self._send({**server.tracer.clock(),
                                 "pid": os.getpid()})
                 elif url.path == "/trace":

@@ -501,8 +501,11 @@ def test_engine_cost_attribution_and_debug_snapshot(lm_net):
         costs = snap["costs"]
         assert costs["per_invocation"]["decode"]
         assert costs["tokens_per_sec"] > 0
-        assert costs["mfu_estimate"] > 0
-        assert costs["peak_flops_per_device"] > 0
+        # the CPU has no published peak (profiler.DEVICE_PEAKS): MFU is
+        # null and says why, never a figure against a made-up peak
+        assert costs["mfu_estimate"] is None
+        assert costs["peak_flops_per_device"] is None
+        assert "no published peak" in costs["peak_note"]
         assert costs["dispatches"]["decode"] >= 1
         assert snap["phases"]["decode"]["seconds"] > 0
         assert snap["compile_cache"]["decode"] >= 0
