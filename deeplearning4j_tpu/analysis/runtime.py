@@ -31,7 +31,7 @@ import contextlib
 import sys
 import threading
 from pathlib import Path
-from typing import Dict, List, Optional, Sequence, Set, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -45,13 +45,25 @@ _PKG = "deeplearning4j_tpu"
 
 
 # -- sanctioned transfer boundaries ---------------------------------------
-def host_read(x) -> np.ndarray:
+def host_read(x, ready: Optional[Callable[[], None]] = None) -> np.ndarray:
     """Blocking device->host read, declared. Hot-loop code must funnel its
     (few, deliberate) host reads through here: graftlint rule JG006 flags
     any other sync in scheduler-loop code, and under
     ``jax.transfer_guard("disallow")`` this is the allow-listed boundary
-    that still passes."""
+    that still passes.
+
+    The read is a wait for the device, then a copy. A caller that wants
+    the two told apart passes ``ready``, which is called between them
+    (`StepPhaseProfiler.ready`: the ``*_wait`` phase ends, the ``*_read``
+    phase begins). The copy is queued behind the computation BEFORE the
+    wait, as a bare ``np.asarray`` queues it: waiting first and asking
+    for the copy only then costs a host round trip per read (0.6 ms an
+    iteration on a v5e, `PERF.md` section 6, PR 26). Still one transfer."""
     with jax.transfer_guard("allow"):
+        if ready is not None:
+            x.copy_to_host_async()
+            jax.block_until_ready(x)
+            ready()
         return np.asarray(x)
 
 

@@ -1039,7 +1039,6 @@ class DecodeScheduler:
         self._m_rejected = m.counter("decode_rejected_total")
         self._m_cancelled = m.counter("decode_cancelled_total")
         self._m_latency = m.histogram("decode_seq_latency_sec")
-        self._m_ttft = m.histogram("decode_time_to_first_token_sec")
         self._m_step_time = m.histogram("decode_step_time_sec")
         self._m_prefill_tokens = m.counter("prefill_tokens_total")
         # TTFT observability (ISSUE 14 satellite): the histogram SSE
@@ -2616,11 +2615,6 @@ class DecodeScheduler:
             h.t_first_token = now
             h.steps_to_first_token = seq.steps
             ttft = now - h.t_submit
-            # two series, one value, deliberately: decode_time_to_
-            # first_token_sec is the PR-1-era name dashboards already
-            # scrape; generate_first_token_seconds (exemplar-linked
-            # into /trace) is the ISSUE 14 streaming-TTFT contract
-            self._m_ttft.record(ttft)
             self._m_first_token.record(ttft, exemplar=h.request_id)
             if self.tracer.enabled:
                 # the request waterfall's TTFT marker (ISSUE 14
@@ -2766,7 +2760,11 @@ class DecodeScheduler:
             self._m_prefill_tokens.inc(n_real)
             self._m_prefill_chunk.record(n_real)
             if seq.sampling:  # final chunk: its output is the first token
-                self._consume(i, seq, host_read(probs))
+                prof = self.profiler
+                prof.begin("prefill_wait")
+                row = host_read(probs, prof.ready)
+                prof.begin("accept")
+                self._consume(i, seq, row)
             self.tracer.end("prefill_chunk", track=self._slot_tracks[i])
             self._prefill_next = (i + 1) % self.n_slots
             return i
@@ -3191,7 +3189,12 @@ class DecodeScheduler:
             raise _EngineFenced
         failpoints.fire("scheduler.iteration")
         prof = self.profiler
-        prof.iter_begin()
+        # a pass with no slot held and nothing queued will idle: the
+        # trace gets no sched_iter for it. The unlocked look at _queue is
+        # one GIL-atomic truth test, and a submit() that lands after it
+        # costs that iteration its admit annotation and nothing else
+        prof.iter_begin(annotate=any(s is not None for s in self._slots)
+                        or bool(self._queue))  # graftlint: disable=CC005
         self._evict_cancelled()
         if self.tier is not None:
             # pace the tier worker and integrate landed promotions
@@ -3207,15 +3210,16 @@ class DecodeScheduler:
         active = [(i, s) for i, s in enumerate(self._slots)  # graftlint: disable=CC004
                   if s is not None]
         if not active:
-            return False  # idle pass: no laps recorded (a 10 Hz idle
-            # wake stamping µs admit laps would swamp the histograms)
-        prof.lap("admit")
+            prof.iter_abandon()
+            return False  # idle pass: no phase recorded (a 10 Hz idle
+            # wake stamping µs admit phases would swamp the histograms)
+        prof.begin("prefill_launch")
         t0 = time.monotonic()
         self._emitted_this_iter = 0
         chunked = self._run_prefill_chunk()
-        prof.lap("prefill")
+        prof.begin("draft")
         self._run_draft_catchup()
-        prof.lap("draft")
+        prof.begin("pool")
         # decode step: every decode-ready slot, plus token-by-token
         # prefill for slots chunked prefill cannot serve (disabled, or
         # no bucket fits the remaining cache headroom). With speculation
@@ -3253,7 +3257,7 @@ class DecodeScheduler:
                         or not self._ensure_writable(i, seq, seq.written):
                     continue  # seq itself was preempted for blocks
             (spec if want > 1 else fed).append((i, seq))
-        prof.lap("pool")
+        prof.begin("decode_launch")
         if fed:
             ids = np.zeros((self.n_slots,), np.int32)
             live = np.zeros((self.n_slots,), bool)
@@ -3306,8 +3310,9 @@ class DecodeScheduler:
                         self._dev_array(ids), self._dev_array(live),
                         self._states)
             self._states = new_states
-            probs = host_read(probs)
-            prof.lap("decode")
+            prof.begin("decode_wait")
+            probs = host_read(probs, prof.ready)
+            prof.begin("accept")
             for i, seq in fed:
                 seq.steps += 1
                 seq.written += 1
@@ -3318,10 +3323,10 @@ class DecodeScheduler:
                     continue  # still prefilling; output not sampled yet
                 self._consume(i, seq, probs[i])
             self.tracer.end("decode_step", track=self._sched_track)
-        prof.lap("accept")
+        prof.begin("verify")
         if spec:
             self._run_speculation(spec)
-        prof.lap("verify")
+        prof.begin("flush")
         if self._emitted_this_iter:
             self._m_tokens.inc(self._emitted_this_iter)
         self._m_occupancy.record(len(active))
@@ -3381,7 +3386,8 @@ class DecodeScheduler:
                     if not self._running:
                         return
                     if not self._queue:
-                        self._cond.wait(timeout=0.1)
+                        with self.profiler.idle():
+                            self._cond.wait(timeout=0.1)
 
     # -- crash / fence / degradation surface (inference/supervisor.py) ----
     def _crash(self, exc: BaseException) -> None:

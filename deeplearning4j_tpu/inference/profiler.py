@@ -9,17 +9,22 @@ DeepSpark discipline, arXiv 1602.08191: commodity-cluster monitoring is
 always-on, floor-gated overhead). Three pieces:
 
 **Step-phase profiler** (:class:`StepPhaseProfiler`). The scheduler loop
-stamps each iteration's phases — batch assembly (``admit``), prefill
-dispatch, draft rounds, pool ops + candidate assembly (``pool``), the
-decode dispatch + device wait (``decode``), host-side acceptance
-(``accept``), speculative verify (``verify``), and the metric/trace
-flush (``flush``) — into per-phase histograms
-(``decode_step_phase_seconds{phase=...}``) and a rolling step-time
-decomposition, so "decode is slow" resolves into "68% of step time is
-the decode dispatch, 19% is host acceptance". Appends are plain
+names each phase of an iteration as it BEGINS (:data:`PHASES`: batch
+assembly ``admit``, the prefill chunk's ``prefill_launch``, draft
+rounds, pool ops + candidate assembly ``pool``, ``decode_launch``,
+host-side acceptance ``accept``, speculative ``verify``, the
+metric/trace ``flush``). Naming rule: a phase in which the scheduler's
+thread is blocked until the device is done ends in ``_wait``; a phase
+that copies a result device -> host ends in ``_read`` (the two halves of
+`analysis.runtime.host_read`). One call site feeds three readers: the
+cumulative ``phase_seconds``, the per-phase histograms
+(``decode_step_phase_seconds{phase=...}``) and, while a `jax.profiler`
+trace is on, a ``sched/<phase>`` annotation on the scheduler thread's
+host line inside one ``sched_iter`` step annotation per iteration — the
+program's phases on the device trace's own clock. Appends are plain
 scheduler-thread float arithmetic on preallocated state (the trace
-buffer's lock-free single-writer discipline): the armed-vs-disarmed
-step-time ratio is floor-gated ≥ 0.95 (`bench.py profiler_overhead`).
+buffer's lock-free single-writer discipline). What the plane costs on
+the chip is in `PERF.md` (section 6, PR 26).
 
 **Cost attribution** (:func:`program_costs` + the profiler's rolling
 FLOPs window). At warmup, every compiled program family (decode /
@@ -52,10 +57,13 @@ straight back into the flight recorder.
 from __future__ import annotations
 
 import collections
+import contextlib
 import threading
 import time
 import weakref
 from typing import Callable, Dict, List, Optional, Tuple
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from .metrics import MetricsRegistry, default_registry
 
@@ -77,9 +85,16 @@ def burn_verdict(fast: float, slow: float, fast_burn: float = 6.0,
     replicas about what "burning" means."""
     return fast >= fast_burn and slow >= slow_burn, fast < 1.0
 
-# iteration phases, in stamp order (engine._step_once lap boundaries)
-PHASES = ("admit", "prefill", "draft", "pool", "decode", "accept",
-          "verify", "flush")
+# iteration phases, in the order engine._step_once begins them. `*_wait`:
+# the scheduler thread is blocked until the device is done; `*_read`: a
+# result is copied device -> host. Readers (benchmark/metrics/) go by
+# those two suffixes, so a new phase that blocks or copies takes one.
+PHASES = ("admit", "prefill_launch", "prefill_wait", "prefill_read",
+          "draft", "pool", "decode_launch", "decode_wait", "decode_read",
+          "accept", "verify", "flush")
+_READ_OF = {p: p[:-len("_wait")] + "_read" for p in PHASES
+            if p.endswith("_wait")}
+_NO_SPAN = contextlib.nullcontext()
 
 # Published per-chip peaks keyed by jax ``device_kind`` — the one table
 # MFU and roofline figures divide by. Source: Google Cloud TPU
@@ -264,8 +279,16 @@ class StepPhaseProfiler:
     entry per iteration, no device work. Cross-thread readers
     (`GET /debug/engine`, the gauges) see GIL-atomic snapshots one
     iteration stale at worst. ``enabled=False`` reduces every call to
-    one attribute test (`bench.py profiler_overhead` gates the armed
-    cost at ≥ 0.95 step-time ratio).
+    one attribute test and opens no trace annotation.
+
+    A phase is named when it begins: ``iter_begin`` opens ``admit``,
+    each :meth:`begin` closes the open phase — its seconds go to
+    ``phase_seconds`` and its histogram — and opens the next, and
+    ``iter_end`` closes the last. The same boundaries open and close the
+    ``sched/<phase>`` trace annotations, so an annotation spans exactly
+    the interval whose seconds its phase is given. A phase may open more
+    than once in an iteration (``accept`` follows a prompt's last chunk
+    and the decode step); its seconds add up.
     """
 
     def __init__(self, metrics: Optional[MetricsRegistry] = None, *,
@@ -288,7 +311,9 @@ class StepPhaseProfiler:
                 "decode_step_phase_seconds",
                 help="scheduler iteration wall time by phase "
                      "(admit=batch assembly, pool=pool ops + candidate "
-                     "assembly, accept=host-side token acceptance)",
+                     "assembly, accept=host-side token acceptance, "
+                     "*_wait=blocked on the device, *_read=device->host "
+                     "copy)",
                 labels={"phase": p})
             for p in PHASES} if self.enabled else {}
         # rolling ring of per-iteration (ts_end, flops, bytes, tokens):
@@ -308,8 +333,13 @@ class StepPhaseProfiler:
         self.family_flops: Dict[str, float] = {}
         # per-iteration scratch, reset by iter_begin
         self._iter_counts: List[Tuple[str, int, int]] = []
-        self._t_iter = 0.0
-        self._t_lap = 0.0
+        self._phase = "admit"  # the open phase
+        self._t_phase = 0.0    # when it began
+        # the open trace annotations (a TraceMe starts when constructed
+        # and records when stopped; both cost well under a microsecond
+        # with no trace on)
+        self._iter_span: Optional[StepTraceAnnotation] = None
+        self._span: Optional[TraceAnnotation] = None
         self._t_gauges = 0.0  # last _refresh_gauges wall time
         if self.enabled:
             m = self.metrics
@@ -335,29 +365,75 @@ class StepPhaseProfiler:
             self._g_share: Dict[str, object] = {}
 
     # -- hot path (scheduler thread only) ----------------------------------
-    def iter_begin(self) -> None:
+    def iter_begin(self, annotate: bool = True) -> None:
+        """Open an iteration and its first phase, ``admit``.
+        ``annotate=False`` is the scheduler's word that this pass will
+        most likely find nothing to run: its ``sched_iter`` annotation
+        then waits for the first :meth:`begin`, so that an engine idling
+        at 10 Hz does not write an empty step into the trace on every
+        wake."""
         if not self.enabled:
             return
-        now = time.monotonic()
-        self._t_iter = now
-        self._t_lap = now
         if self._iter_counts:
             self._iter_counts.clear()
+        self._phase = "admit"
+        self._t_phase = time.monotonic()
+        if annotate:
+            self._annotate("admit")
 
-    def lap(self, phase: str) -> None:
-        """Close the current phase: everything since the previous lap
-        (or iter_begin) is attributed to ``phase``. Skipped phases cost
-        one monotonic read and land only in the decomposition (sub-µs
-        laps stay out of the histograms, which would otherwise drown in
-        zeros from phases that did not run this iteration)."""
+    def _annotate(self, phase: str) -> None:
+        if self._iter_span is None:
+            self._iter_span = StepTraceAnnotation(
+                "sched_iter", step_num=self.iterations)
+        self._span = TraceAnnotation("sched/" + phase)
+
+    def begin(self, phase: str) -> None:
+        """Close the open phase — everything since it began is its —
+        and open ``phase``. Sub-microsecond phases stay out of the
+        histograms (they would drown in zeros from phases that did not
+        run this iteration) and land only in the decomposition."""
         if not self.enabled:
             return
         now = time.monotonic()
-        dt = now - self._t_lap
-        self._t_lap = now
-        self.phase_seconds[phase] += dt
+        self._close(now)
+        self._phase = phase
+        self._t_phase = now
+        self._annotate(phase)
+
+    def ready(self) -> None:
+        """The device is done: the open ``*_wait`` phase ends and its
+        ``*_read`` begins. `analysis.runtime.host_read` calls this
+        between its wait and its copy."""
+        if self.enabled:
+            self.begin(_READ_OF[self._phase])
+
+    @staticmethod
+    def _stop(span) -> None:
+        if span is not None:
+            span.__exit__(None, None, None)
+
+    def _close(self, now: float) -> None:
+        self._stop(self._span)
+        self._span = None
+        dt = now - self._t_phase
+        self.phase_seconds[self._phase] += dt
         if dt >= 1e-6:
-            self._hists[phase].record(dt)
+            self._hists[self._phase].record(dt)
+
+    def iter_abandon(self) -> None:
+        """The pass found nothing to run: its annotations close, and
+        nothing is booked (a 10 Hz idle wake stamping microsecond admit
+        phases would swamp the histograms)."""
+        self._stop(self._span)
+        self._stop(self._iter_span)
+        self._span = self._iter_span = None
+
+    def idle(self):
+        """Context manager around the scheduler's idle wait: a
+        ``sched/idle`` annotation, so that a device gap with nothing to
+        run reads as waiting for a request and not as host work. Books
+        no phase."""
+        return TraceAnnotation("sched/idle") if self.enabled else _NO_SPAN
 
     def count(self, family: str, bucket: int, n: int = 1) -> None:
         """Stamp ``n`` dispatches of ``(family, bucket)`` this iteration
@@ -371,7 +447,10 @@ class StepPhaseProfiler:
         derived gauges every ``gauge_every`` iterations."""
         if not self.enabled:
             return
-        self.lap("flush")
+        now = time.monotonic()
+        self._close(now)
+        self._stop(self._iter_span)  # the step ends with its last phase
+        self._iter_span = None
         flops = bytes_ = 0.0
         for family, bucket, n in self._iter_counts:
             c = self.costs.get((family, bucket))
@@ -386,7 +465,6 @@ class StepPhaseProfiler:
         self.flops_total += flops
         self.bytes_total += bytes_
         self.tokens_total += tokens
-        now = time.monotonic()
         idx = self.iterations % self._window
         # increment BEFORE the store: a concurrent rates() reader
         # indexes ring[iterations % window] as the oldest entry — with

@@ -332,7 +332,7 @@ async function refresh() {
     '&#9632;decode)</p>' + tr.map(waterfall).join('') : '';
   const c = m.counters || {}, h = m.histograms || {};
   const r = m.ratios || {};
-  const ttft = h.decode_time_to_first_token_sec, ck = h.prefill_chunk_size;
+  const ttft = h.generate_first_token_seconds, ck = h.prefill_chunk_size;
   const lk = c.prefix_cache_lookup_tokens_total;
   if (c.prefill_tokens_total !== undefined || ttft)
     document.getElementById('decode').innerText =

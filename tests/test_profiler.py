@@ -448,16 +448,19 @@ def test_step_phase_profiler_unit():
                        ("prefill", 16): {"flops": 1000.0, "bytes": 50.0}})
     for _ in range(4):
         prof.iter_begin()
-        prof.lap("admit")
+        prof.begin("prefill_launch")
         prof.count("prefill", 16)
-        prof.lap("prefill")
+        prof.begin("decode_launch")
         prof.count("decode", 0)
-        prof.lap("decode")
+        prof.begin("decode_wait")
+        prof.ready()
+        prof.begin("accept")
         prof.iter_end(tokens=2)
     dec = prof.decomposition()
     assert set(dec) == set(
-        ("admit", "prefill", "draft", "pool", "decode", "accept",
-         "verify", "flush"))
+        ("admit", "prefill_launch", "prefill_wait", "prefill_read",
+         "draft", "pool", "decode_launch", "decode_wait", "decode_read",
+         "accept", "verify", "flush"))
     assert abs(sum(p["share"] for p in dec.values()) - 1.0) < 0.01
     assert prof.family_dispatches == {"decode": 4, "prefill": 4}
     assert prof.flops_total == pytest.approx(4 * 1100.0)
@@ -472,10 +475,12 @@ def test_disabled_profiler_is_inert():
     m = MetricsRegistry()
     prof = StepPhaseProfiler(m, enabled=False)
     prof.iter_begin()
-    prof.lap("decode")
+    prof.begin("decode_wait")
+    prof.ready()
     prof.count("decode", 0)
     prof.iter_end(tokens=5)
     assert prof.iterations == 0 and prof.tokens_total == 0
+    assert not any(prof.phase_seconds.values())
     assert "decode_tokens_per_sec" not in m.snapshot()["gauges"]
 
 
@@ -507,14 +512,14 @@ def test_engine_cost_attribution_and_debug_snapshot(lm_net):
         assert costs["peak_flops_per_device"] is None
         assert "no published peak" in costs["peak_note"]
         assert costs["dispatches"]["decode"] >= 1
-        assert snap["phases"]["decode"]["seconds"] > 0
+        assert snap["phases"]["decode_wait"]["seconds"] > 0
         assert snap["compile_cache"]["decode"] >= 0
         assert snap["mesh"]["tp"] == 1
         assert snap["slots"][0] is None  # finished -> freed
         # phase histograms landed as labeled series
         hists = m.snapshot()["histograms"]
-        assert 'decode_step_phase_seconds{phase="decode"}' in hists
-        assert hists['decode_step_phase_seconds{phase="decode"}'][
+        assert 'decode_step_phase_seconds{phase="decode_launch"}' in hists
+        assert hists['decode_step_phase_seconds{phase="decode_launch"}'][
             "count"] > 0
     finally:
         eng.stop()
@@ -759,7 +764,7 @@ def test_idle_tick_decays_rate_gauges():
     prof = StepPhaseProfiler(m, gauge_every=1)
     for _ in range(3):
         prof.iter_begin()
-        prof.lap("decode")
+        prof.begin("decode_launch")
         prof.iter_end(tokens=100)
     busy = m.snapshot()["gauges"]["decode_tokens_per_sec"]["value"]
     assert busy > 0

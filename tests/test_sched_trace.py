@@ -1,0 +1,305 @@
+"""The scheduler iteration on the profiler's clock (ISSUE 26).
+
+Three things are held here. (i) A `jax.profiler` trace of a toy paged
+engine, taken with the benchmark harness's options, carries the
+scheduler's phases as `sched/<phase>` annotations inside one `sched_iter`
+per iteration, on one host line beside the `PjitFunction(...)` launches.
+(ii) The phases partition the iteration: their seconds add up to its wall
+time, the wait for the device and the copy to the host are told apart, and
+a disarmed profiler books and annotates nothing. (iii) The names that the
+benchmark's accepted readers match — the two paged programs' module names
+and the ring's dispatch spans — are pinned, so that a refactor cannot null
+a roofline in silence.
+"""
+import glob
+import re
+import threading
+import time
+
+import jax
+import numpy as np
+import pytest
+from jax.profiler import ProfileData
+
+from deeplearning4j_tpu.inference import (DecodeScheduler, MetricsRegistry,
+                                          StepPhaseProfiler)
+from deeplearning4j_tpu.inference import profiler as profiler_mod
+from deeplearning4j_tpu.inference.kvpool import SCRATCH_BLOCK
+from deeplearning4j_tpu.inference.trace import FlightRecorder
+from deeplearning4j_tpu.models.zoo import transformer_lm
+from deeplearning4j_tpu.nn.graph import ComputationGraph
+
+V = 13
+CHUNK = 16
+
+
+@pytest.fixture(scope="module")
+def eng():
+    # wide enough that an iteration lasts milliseconds: the microseconds
+    # between one annotation's end and the next one's start must stay a
+    # small share of it
+    conf = transformer_lm(vocab_size=V, d_model=128, n_heads=2, n_blocks=4,
+                          rope=True)
+    for vert in conf.vertices.values():
+        layer = getattr(vert, "layer", None)
+        if layer is not None and hasattr(layer, "max_cache_len"):
+            layer.max_cache_len = 96
+    e = DecodeScheduler(ComputationGraph(conf).init(), V, n_slots=2,
+                        prefill_chunk=CHUNK, kv_pool_mb=4.0, kv_block=8,
+                        metrics=MetricsRegistry(),
+                        tracer=FlightRecorder(8192)).start()
+    assert e.paged
+    e.generate(_prompt(0, 40), 4, timeout=300)      # compiles, outside
+    yield e
+    e.stop()
+
+
+def _prompt(salt, n):
+    # distinct first tokens: no prefix of an earlier prompt to restore
+    return [(salt + 3 * i) % (V - 1) + 1 for i in range(n)]
+
+
+def _within_a_time_limit(fn, seconds):
+    out = {}
+    t = threading.Thread(target=lambda: out.update(value=fn()), daemon=True)
+    t.start()
+    t.join(timeout=seconds)
+    assert not t.is_alive(), f"not done after {seconds} s"
+    return out["value"]
+
+
+# ------------------------------------------------- (i) the profiler trace --
+def _trace_one_request(eng, trace_dir):
+    opts = jax.profiler.ProfileOptions()     # benchmark/harness/runner.py's
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    it0 = eng.profiler.iterations
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        time.sleep(0.25)                     # nothing to run: sched/idle
+        # long enough that one descheduling of the thread between two
+        # annotations is a small share of the traced iterations
+        eng.generate(_prompt(1, 40), 40, timeout=120)
+        time.sleep(0.25)
+    finally:
+        jax.profiler.stop_trace()
+    return it0, eng.profiler.iterations
+
+
+def test_trace_carries_the_phases_on_the_schedulers_line(eng, tmp_path):
+    it0, it1 = _within_a_time_limit(
+        lambda: _trace_one_request(eng, str(tmp_path)), 240)
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    lines = [[(e.start_ns, e.start_ns + e.duration_ns, e.name)
+              for e in line.events]
+             for plane in ProfileData.from_file(path).planes
+             if plane.name == "/host:CPU" for line in plane.lines]
+    sched = [evs for evs in lines
+             if any(n.startswith("sched") for _, _, n in evs)]
+    assert len(sched) == 1, "the phases belong to one thread's line"
+    evs = sorted(sched[0])
+    iters = [(s, e) for s, e, n in evs if n == "sched_iter"]
+    phases = [(s, e, n) for s, e, n in evs if n.startswith("sched/")]
+    assert len(iters) == it1 - it0 >= 5      # one step per iteration
+    assert {n for _, _, n in phases} >= {
+        "sched/" + p for p in ("admit", "prefill_launch", "prefill_wait",
+                               "prefill_read", "pool", "decode_launch",
+                               "decode_wait", "decode_read", "accept",
+                               "flush")}
+    covered = 0
+    for s, e in iters:
+        kids = [p for p in phases if s <= p[0] < e]
+        assert kids[0][2] == "sched/admit" and kids[-1][2] == "sched/flush"
+        assert all(k[1] <= e for k in kids)
+        assert all(a[1] <= b[0] for a, b in zip(kids, kids[1:])), \
+            "phases of one iteration do not overlap"
+        covered += sum(k[1] - k[0] for k in kids)
+    assert covered >= 0.95 * sum(e - s for s, e in iters)
+    # every launch of the decode program lies inside a decode_launch
+    launches = [(s, e) for s, e, n in evs
+                if n == "PjitFunction(_step_paged_fn)"]
+    spans = [(s, e) for s, e, n in phases if n == "sched/decode_launch"]
+    assert launches and all(any(a <= s and e <= b for a, b in spans)
+                            for s, e in launches)
+    # with nothing to run the thread says so, outside any iteration
+    idle = [(s, e) for s, e, n in phases if n == "sched/idle"]
+    assert idle and not any(a <= s < b for s, _ in idle for a, b in iters)
+
+
+# ------------------------------------------ (ii) phases partition the lap --
+class _Ticks:
+    """A clock that advances one millisecond per reading."""
+
+    def __init__(self):
+        self.now = 0.0
+
+    def monotonic(self):
+        self.now += 0.001
+        return self.now
+
+
+def test_phases_partition_the_iteration(monkeypatch):
+    clock = _Ticks()
+    monkeypatch.setattr(profiler_mod, "time", clock)
+    prof = StepPhaseProfiler(MetricsRegistry())
+    assert set(prof.phase_seconds) == set(profiler_mod.PHASES)
+    walls = 0.0
+    for _ in range(3):
+        prof.iter_begin()
+        t_begin = clock.now
+        for phase in ("prefill_launch", "prefill_wait"):
+            prof.begin(phase)
+        prof.ready()                         # prefill_wait -> prefill_read
+        for phase in ("accept", "draft", "pool", "decode_launch",
+                      "decode_wait"):
+            prof.begin(phase)
+        prof.ready()                         # decode_wait -> decode_read
+        for phase in ("accept", "verify", "flush"):
+            prof.begin(phase)
+        prof.iter_end(tokens=1)
+        walls += clock.now - t_begin         # iter_end read the clock once
+    ph = prof.phase_seconds
+    assert sum(ph.values()) == pytest.approx(walls, rel=1e-9)
+    assert ph["accept"] == pytest.approx(0.006)      # twice an iteration
+    assert all(ph[p] == pytest.approx(0.003) for p in ph if p != "accept")
+    # the benchmark's three readers go by the two suffixes
+    assert {p for p in ph if p.endswith("_wait")} == {"prefill_wait",
+                                                      "decode_wait"}
+    assert {p for p in ph if p.endswith("_read")} == {"prefill_read",
+                                                      "decode_read"}
+    hists = prof.metrics.snapshot()["histograms"]
+    assert hists['decode_step_phase_seconds{phase="decode_read"}'][
+        "count"] == 3
+
+
+def test_only_a_prompts_last_chunk_waits_and_reads(eng):
+    prof, begun = eng.profiler, []
+    real = prof.begin
+    prof.begin = lambda phase: (begun.append(phase), real(phase))
+    n0 = len(eng.tracer.events())
+    try:
+        eng.generate(_prompt(2, 40), 4, timeout=120)
+        time.sleep(0.2)                       # the last iteration ends
+    finally:
+        del prof.begin
+    new = [e for e in eng.tracer.events()[n0:] if e["ph"] == "B"]
+    chunks = [e["args"]["tokens"] for e in new if e["name"] == "prefill_chunk"]
+    steps = [e for e in new if e["name"] == "decode_step"]
+    assert chunks == [16, 16, 8]
+    # three chunks launched, one waited for and read; its sample is accept
+    assert begun.count("prefill_wait") == begun.count("prefill_read") == 1
+    i = begun.index("prefill_wait")
+    assert begun[i - 1:i + 3] == ["prefill_launch", "prefill_wait",
+                                  "prefill_read", "accept"]
+    # every decode step is launched, waited for, read and accepted
+    assert len(steps) == 3 == begun.count("decode_wait")
+    j = begun.index("decode_wait")
+    assert begun[j - 1:j + 3] == ["decode_launch", "decode_wait",
+                                  "decode_read", "accept"]
+    # an iteration begins with its chunk's launch; none is left unbooked
+    assert begun.count("prefill_launch") == begun.count("flush") >= 6
+
+
+class _Span:
+    made, stopped = [], []
+
+    def __init__(self, name, **kwargs):
+        self.name = name
+        _Span.made.append(name)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        _Span.stopped.append(self.name)
+
+
+@pytest.fixture
+def spans(monkeypatch):
+    _Span.made, _Span.stopped = [], []
+    monkeypatch.setattr(profiler_mod, "TraceAnnotation", _Span)
+    monkeypatch.setattr(profiler_mod, "StepTraceAnnotation", _Span)
+    return _Span
+
+
+def _one_pass(prof):
+    prof.iter_begin()
+    prof.begin("decode_wait")
+    prof.ready()
+    prof.begin("accept")
+    prof.count("decode", 0)
+    prof.iter_end(tokens=5)
+    with prof.idle():
+        pass
+
+
+def test_a_disarmed_profiler_books_nothing_and_opens_no_annotation(spans):
+    prof = StepPhaseProfiler(MetricsRegistry(), enabled=False)
+    _one_pass(prof)
+    prof.iter_begin()
+    prof.iter_abandon()
+    assert spans.made == [] and spans.stopped == []
+    assert not any(prof.phase_seconds.values()) and prof.iterations == 0
+
+
+def test_an_armed_profiler_annotates_what_it_books(spans):
+    prof = StepPhaseProfiler(MetricsRegistry())
+    _one_pass(prof)
+    assert spans.made == ["sched_iter", "sched/admit", "sched/decode_wait",
+                          "sched/decode_read", "sched/accept", "sched/idle"]
+    assert sorted(spans.stopped) == sorted(spans.made)
+    assert spans.stopped.index("sched/accept") \
+        < spans.stopped.index("sched_iter")       # a phase ends in its step
+    # a pass that found nothing to run: closed, not booked
+    before = dict(prof.phase_seconds)
+    prof.iter_begin()
+    prof.iter_abandon()
+    assert spans.made[-2:] == spans.stopped[-2:][::-1] == ["sched_iter",
+                                                           "sched/admit"]
+    assert prof.phase_seconds == before and prof.iterations == 1
+    # a pass expected to idle writes no step, unless it runs after all
+    n = len(spans.made)
+    prof.iter_begin(annotate=False)
+    prof.iter_abandon()
+    assert len(spans.made) == n
+    prof.iter_begin(annotate=False)
+    prof.begin("prefill_launch")
+    prof.iter_end()
+    assert spans.made[n:] == ["sched_iter", "sched/prefill_launch"]
+
+
+# ----------------------------------- (iii) names the benchmark's readers match
+def test_paged_program_names_are_what_the_rooflines_match(eng):
+    nb = eng.table_buckets[0]
+    table = eng._dev_array(np.full((eng.n_slots, nb), SCRATCH_BLOCK,
+                                   np.int32))
+    step = eng._jstep.lower(
+        eng._params, eng._variables,
+        eng._dev_array(np.zeros((eng.n_slots,), np.int32)),
+        eng._dev_array(np.zeros((eng.n_slots,), bool)), table, eng._states)
+    chunk = eng._jprefill.lower(
+        eng._params, eng._variables, eng._dev_index(0),
+        eng._dev_array(np.zeros((eng.prefill_buckets[0],), np.int32)),
+        eng._dev_index(1), table, eng._states)
+    names = [re.search(r"module @(\S+)", low.as_text()).group(1)
+             for low in (step, chunk)]
+    # benchmark/metrics/decode_step_roofline.py, prefill_chunk_roofline.py
+    assert names == ["jit__step_paged_fn", "jit__prefill_paged_fn"]
+    assert eng._thread.name == "decode-scheduler"
+
+
+def test_ring_carries_the_dispatch_spans_the_benchmark_reads(eng):
+    n0 = len(eng.tracer.events())
+    h = eng.submit(_prompt(3, 24), 3)
+    h.result(timeout=120)
+    new = [e for e in eng.tracer.events()[n0:] if e["ph"] == "B"]
+    steps = [e for e in new if e["name"] == "decode_step"]
+    chunks = [e for e in new if e["name"] == "prefill_chunk"]
+    # engine_driver.spans_between / harness/facts.chunks read these keys
+    assert steps and all(e["args"]["live_slots"] == 1 for e in steps)
+    assert [(e["args"]["bucket"], e["args"]["tokens"]) for e in chunks] \
+        == [(16, 16), (16, 8)]
+    assert all(e["args"]["request"] == h.request_id for e in chunks)
+    # no record per phase: the profiler's trace shows those
+    assert not any(e["name"] in profiler_mod.PHASES
+                   or e["name"].startswith("sched") for e in new)
