@@ -789,7 +789,8 @@ class DecodeScheduler:
                 self.restore_buckets = pow2_buckets(
                     self._cache_cap // self.kv_block)
                 self._jrestore = jax.jit(functools.partial(
-                    gather_blocks, block=self.kv_block))
+                    gather_blocks, block=self.kv_block),
+                    donate_argnames=("states",))
                 # storage is donated: publish updates the pool in place
                 # instead of re-materializing the whole budget's worth of
                 # arrays per call; the caller rebinds pool.storage to the
@@ -839,8 +840,15 @@ class DecodeScheduler:
                 self.pool.storage = jax.device_put(
                     self.pool.storage,
                     storage_shardings(self.pool.storage, self.mesh))
+        # THE donation rule: a program that takes the carried state and
+        # returns it donates it (the pool is updated in place: no copy of
+        # the page arrays on the device, no fresh output buffers from the
+        # allocator on the host), and every caller rebinds from the
+        # result; a program that only reads the state does not
+        # (_jtier_spill, _jpublish's argument 0)
         self._jstep = jax.jit(
-            self._step_paged_fn if self.paged else self._step_fn)
+            self._step_paged_fn if self.paged else self._step_fn,
+            donate_argnames=("states",))
         # one prefill program per pow2 chunk bucket (the SAME jitted
         # callable; each distinct ids length C is its own XLA program,
         # compiled once and reused across requests — the batcher's
@@ -852,17 +860,20 @@ class DecodeScheduler:
         # defeating the bucket discipline.
         self._jprefill = jax.jit(
             self._prefill_paged_fn if self.paged
-            else self._prefill_fn)  # graftlint: disable=JG004
+            else self._prefill_fn,
+            donate_argnames=("states",))  # graftlint: disable=JG004
         # slot admission zeroes one slot's rows in ONE fused program
         # (eagerly tree-mapped .at[].set(0) dispatched per leaf AND fed
         # the slot index as an implicit scalar transfer per leaf)
-        self._jzero = jax.jit(self._zero_fn)
+        self._jzero = jax.jit(self._zero_fn, donate_argnames=("states",))
         if self.paged:
             # restore remaps the table host-side; the only device work is
             # setting the slot's pos past the hit (one tiny program) and
             # the occasional copy-on-write block duplication (one more)
-            self._jsetpos = jax.jit(self._setpos_fn)
-            self._jcow = jax.jit(self._cow_fn)
+            self._jsetpos = jax.jit(self._setpos_fn,
+                                    donate_argnames=("states",))
+            self._jcow = jax.jit(self._cow_fn,
+                                 donate_argnames=("states",))
         # -- hierarchical KV tiering (ISSUE 19, kvtier.py) ------------------
         # opt-in (host_cache_mb=0 keeps the engine byte-identical to the
         # tierless build: no TierManager, no extra programs, no hot-path
@@ -889,8 +900,11 @@ class DecodeScheduler:
                     disk_dir=tier_dir,
                     chunk_bytes=self._tier_chunk,
                     metrics=self.metrics, tracer=self.tracer)
+                # spill only READS the pool (its slices are outputs of
+                # their own): not donated, the pool lives on
                 self._jtier_spill = jax.jit(self._tier_spill_fn)
-                self._jtier_restore = jax.jit(self._tier_restore_fn)
+                self._jtier_restore = jax.jit(
+                    self._tier_restore_fn, donate_argnames=("states",))
                 self.pool.tier = self.tier
                 self.tier.attach_engine(
                     self._tier_capture,
@@ -923,7 +937,8 @@ class DecodeScheduler:
                 (self.mask_rows, self.vocab_size), np.dtype(self._dtype)))
             self._jstep_m = jax.jit(
                 self._step_masked_paged_fn if self.paged
-                else self._step_masked_fn)
+                else self._step_masked_fn,
+                donate_argnames=("states",))
             self._jmask_upload = jax.jit(self._mask_upload_fn)
         # -- speculative decoding (ISSUE 10 tentpole) ----------------------
         # a cheap draft proposes `speculate` tokens per decode-ready slot
@@ -1005,14 +1020,19 @@ class DecodeScheduler:
                     self._draft_states = jax.device_put(
                         self._draft_states,
                         state_shardings(self._draft_states, self.mesh))
-                self._jdraft_step = jax.jit(self._draft_step_fn)
-                self._jdraft_prefill = jax.jit(self._draft_prefill_fn)  # graftlint: disable=JG004
-                self._jdraft_zero = jax.jit(self._zero_fn)
+                self._jdraft_step = jax.jit(
+                    self._draft_step_fn, donate_argnames=("states",))
+                self._jdraft_prefill = jax.jit(  # graftlint: disable=JG004
+                    self._draft_prefill_fn, donate_argnames=("states",))
+                self._jdraft_zero = jax.jit(
+                    self._zero_fn, donate_argnames=("states",))
                 self._jverify = jax.jit(
                     self._verify_paged_fn if self.paged
-                    else self._verify_fn)
-                self._jfixpos = jax.jit(self._fixpos_fn)
-                self._jdraft_fixpos = jax.jit(self._fixpos_fn)
+                    else self._verify_fn, donate_argnames=("states",))
+                self._jfixpos = jax.jit(
+                    self._fixpos_fn, donate_argnames=("states",))
+                self._jdraft_fixpos = jax.jit(
+                    self._fixpos_fn, donate_argnames=("states",))
                 if self._masks is not None:
                     # masks compose with speculation: the draft proposes
                     # under the same mask the verify applies (per-round
@@ -1020,8 +1040,11 @@ class DecodeScheduler:
                     # the proposed chain), acceptance rule untouched
                     self._jverify_m = jax.jit(
                         self._verify_masked_paged_fn if self.paged
-                        else self._verify_masked_fn)
-                    self._jdraft_step_m = jax.jit(self._draft_step_masked_fn)
+                        else self._verify_masked_fn,
+                        donate_argnames=("states",))
+                    self._jdraft_step_m = jax.jit(
+                        self._draft_step_masked_fn,
+                        donate_argnames=("states",))
         self._prefill_next = 0  # round-robin over prefilling slots
         self._emitted_this_iter = 0  # scheduler-thread-only tally
         m = self.metrics
@@ -1658,8 +1681,10 @@ class DecodeScheduler:
         layer's pool arrays — the device side of a tier demotion. The
         block index stays TRACED (dynamic slice), so the whole tier
         ladder costs exactly one XLA program regardless of which block
-        spills; the result is an immutable functional snapshot, safe
-        against immediate reuse of the freed page."""
+        spills; the result is a snapshot in buffers of its own (the
+        pool is NOT donated here), enqueued before any later program
+        that updates the pool in place, so it is safe against immediate
+        reuse of the freed page."""
         b = bid[0]
         out = {}
         for key, st in states.items():
@@ -3501,9 +3526,21 @@ class DecodeScheduler:
 
     def warmup(self, masks: Optional[bool] = None) -> None:
         """Compile every program family up front by invoking each jitted
-        callable once per bucket shape and DISCARDING the results (the
-        programs are pure; nothing observable changes — no metrics, no
-        trace records, no pool state, no slot bookkeeping).
+        callable once per bucket shape. Nothing observable changes — no
+        metrics, no trace records, no pool state, no slot bookkeeping —
+        but the carried state IS rebound from every call: the programs
+        donate it (the rule at ``_jstep``'s construction), so a result
+        thrown away would leave ``_states`` / ``_draft_states`` pointing
+        at deleted buffers. The arguments make each program the identity
+        on live data (all-masked ``live``, scratch table, chunks of no
+        real token, scratch -> scratch copy-on-write and tier round
+        trip, ``nomask`` fixpos). ``_jzero(slot0)``, ``_jsetpos(slot0,
+        0)`` and, in the contiguous layout, the chunk's padded rows and
+        the restore into slot 0 are NOT the identity: they write, then
+        zero, slot 0's rows. That is harmless only because warm-up runs
+        with no slot admitted (construction / recovery / drain-swap
+        windows the supervisor owns) and admission zeroes a slot before
+        its first use.
 
         ``masks``: also warm the GRAMMAR-MASKED program variants
         (masked decode/verify/draft + the mask-upload family). Default
@@ -3527,17 +3564,25 @@ class DecodeScheduler:
         # differently would compile a parallel family and blow budgets)
         ids = self._dev_array(np.zeros((self.n_slots,), np.int32))
         # all-masked: every slot's state transition is frozen in-program
-        # (and paged writes redirect to the scratch page), so even the
-        # discarded outputs never held corrupted rows
+        # (and paged writes redirect to the scratch page), so the
+        # rebound state holds what it held
         live = self._dev_array(np.zeros((self.n_slots,), bool))
         slot0 = self._dev_index(0)
         one = self._dev_index(1)
+        # the warm-up chunks have NO real token (n_real = 0, a traced
+        # value: same programs): every lane is padding, so a paged chunk
+        # writes zeros to the scratch page and leaves ``pos`` where it
+        # was. A real lane of a chunk wider than its table bucket (this
+        # sweep of all pairs has them, and they overflow by design)
+        # would write NaN rows to the scratch page, which every later
+        # step gathers
+        no_real = slot0  # the same [0]
         if self.paged:
             for nb in self.table_buckets:
                 table = self._dev_array(np.full(
                     (self.n_slots, nb), SCRATCH_BLOCK, np.int32))
-                self._jstep(params, variables, ids, live, table,
-                            self._states)
+                _, self._states = self._jstep(
+                    params, variables, ids, live, table, self._states)
             # the FULL budgeted prefill family: one program per (chunk
             # bucket, table bucket) pair — live dispatch selects the
             # table bucket from the slot's DEPTH (`_table_for(written +
@@ -3549,13 +3594,14 @@ class DecodeScheduler:
                 for nb in self.table_buckets:
                     table = self._dev_array(np.full(
                         (self.n_slots, nb), SCRATCH_BLOCK, np.int32))
-                    self._jprefill(params, variables, slot0,
-                                   self._dev_array(np.zeros((b,),
-                                                            np.int32)),
-                                   one, table, self._states)
-            self._jsetpos(self._states, slot0, self._dev_index(0))
-            self._jcow(self._states, self._dev_index(SCRATCH_BLOCK),
-                       self._dev_index(SCRATCH_BLOCK))
+                    _, self._states = self._jprefill(
+                        params, variables, slot0,
+                        self._dev_array(np.zeros((b,), np.int32)),
+                        no_real, table, self._states)
+            self._states = self._jsetpos(self._states, slot0, slot0)
+            self._states = self._jcow(
+                self._states, self._dev_index(SCRATCH_BLOCK),
+                self._dev_index(SCRATCH_BLOCK))
             if self.tier is not None:
                 # tier spill/restore: warm with the scratch row, fed
                 # back through np.asarray + _dev_array — the EXACT
@@ -3566,30 +3612,31 @@ class DecodeScheduler:
                 rows = {lk: {pk: self._dev_array(np.asarray(a))
                              for pk, a in pks.items()}
                         for lk, pks in dev.items()}
-                self._jtier_restore(self._states, scratch, rows)
+                self._states = self._jtier_restore(self._states, scratch,
+                                                   rows)
         else:
-            self._jstep(params, variables, ids, live, self._states)
+            _, self._states = self._jstep(params, variables, ids, live,
+                                          self._states)
             for b in self.prefill_buckets:
-                self._jprefill(params, variables, slot0,
-                               self._dev_array(np.zeros((b,),
-                                                        np.int32)),
-                               one, self._states)
+                _, self._states = self._jprefill(
+                    params, variables, slot0,
+                    self._dev_array(np.zeros((b,), np.int32)),
+                    no_real, self._states)
             if self.pool is not None:
                 for b in self.restore_buckets:
                     idx = np.full((b,), SCRATCH_BLOCK, np.int32)
-                    self._jrestore(self._states, slot0,
-                                   self._dev_array(idx),
-                                   one, self.pool.storage)
-                    # publish donates its storage argument — rebind, or
-                    # the pool would be left pointing at consumed
-                    # buffers. Writing slot 0's (all-zero, fresh-engine)
-                    # rows into unallocated block 0 is harmless: any
-                    # future insert() scatters real data over it.
+                    self._states = self._jrestore(
+                        self._states, slot0, self._dev_array(idx),
+                        one, self.pool.storage)
+                    # publish donates its storage argument too. Writing
+                    # slot 0's rows into unallocated block 0 is
+                    # harmless: any future insert() scatters real data
+                    # over it.
                     self.pool.storage = self._jpublish(
                         self._states, slot0, self._dev_index(0),
                         self._dev_array(np.zeros((b,), np.int32)),
                         self.pool.storage)
-        self._jzero(self._states, slot0)
+        self._states = self._jzero(self._states, slot0)
         if masks is None:
             masks = (self.maskpool is not None
                      and self.maskpool.resident_rows() > 0)
@@ -3602,11 +3649,13 @@ class DecodeScheduler:
                 for nb in self.table_buckets:
                     table = self._dev_array(np.full(
                         (self.n_slots, nb), SCRATCH_BLOCK, np.int32))
-                    self._jstep_m(params, variables, ids, live, table,
-                                  mstate0, self._masks, self._states)
+                    _, self._states = self._jstep_m(
+                        params, variables, ids, live, table, mstate0,
+                        self._masks, self._states)
             else:
-                self._jstep_m(params, variables, ids, live, mstate0,
-                              self._masks, self._states)
+                _, self._states = self._jstep_m(
+                    params, variables, ids, live, mstate0, self._masks,
+                    self._states)
             if self.maskpool.resident_rows() == 0:
                 # upload family (pure writes of zeros = admit-all rows).
                 # Guarded: on a warm engine that already holds resident
@@ -3629,12 +3678,14 @@ class DecodeScheduler:
                 for nb in self.table_buckets:
                     table = self._dev_array(np.full(
                         (self.n_slots, nb), SCRATCH_BLOCK, np.int32))
-                    self._jverify(params, variables, ids2, live, table,
-                                  self._states)
+                    _, self._states = self._jverify(
+                        params, variables, ids2, live, table, self._states)
             else:
-                self._jverify(params, variables, ids2, live, self._states)
+                _, self._states = self._jverify(params, variables, ids2,
+                                                live, self._states)
             dp, dv = self._draft_params, self._draft_variables
-            self._jdraft_step(dp, dv, ids, live, self._draft_states)
+            _, self._draft_states = self._jdraft_step(
+                dp, dv, ids, live, self._draft_states)
             if masks and self._jverify_m is not None:
                 # speculation x grammar composition: the masked verify
                 # mirrors verify's table bucketing, the masked draft
@@ -3647,24 +3698,28 @@ class DecodeScheduler:
                     for nb in self.table_buckets:
                         table = self._dev_array(np.full(
                             (self.n_slots, nb), SCRATCH_BLOCK, np.int32))
-                        self._jverify_m(params, variables, ids2, live,
-                                        table, mstate2, self._masks,
-                                        self._states)
+                        _, self._states = self._jverify_m(
+                            params, variables, ids2, live, table, mstate2,
+                            self._masks, self._states)
                 else:
-                    self._jverify_m(params, variables, ids2, live,
-                                    mstate2, self._masks, self._states)
-                self._jdraft_step_m(dp, dv, ids, live, mstate0,
-                                    self._masks, self._draft_states)
-            for b in self.prefill_buckets:
-                self._jdraft_prefill(
-                    dp, dv, slot0,
-                    self._dev_array(np.zeros((b,), np.int32)), one,
+                    _, self._states = self._jverify_m(
+                        params, variables, ids2, live, mstate2,
+                        self._masks, self._states)
+                _, self._draft_states = self._jdraft_step_m(
+                    dp, dv, ids, live, mstate0, self._masks,
                     self._draft_states)
-            self._jdraft_zero(self._draft_states, slot0)
+            for b in self.prefill_buckets:
+                _, self._draft_states = self._jdraft_prefill(
+                    dp, dv, slot0,
+                    self._dev_array(np.zeros((b,), np.int32)), no_real,
+                    self._draft_states)
+            self._draft_states = self._jdraft_zero(self._draft_states,
+                                                   slot0)
             posv = self._dev_array(np.zeros((self.n_slots,), np.int32))
             nomask = self._dev_array(np.zeros((self.n_slots,), bool))
-            self._jfixpos(self._states, posv, nomask)
-            self._jdraft_fixpos(self._draft_states, posv, nomask)
+            self._states = self._jfixpos(self._states, posv, nomask)
+            self._draft_states = self._jdraft_fixpos(
+                self._draft_states, posv, nomask)
         if self.paged:
             # the bucket loop above traced every decode program through
             # the paged_decode_attention seam, so the kernel variant is

@@ -492,8 +492,9 @@ class KVPool:
             if self.tier is not None:
                 # demotion interception: capture the page row BEFORE the
                 # id returns to the free list (the captured device
-                # snapshot is immutable under functional updates, so the
-                # reused id can be rewritten immediately)
+                # snapshot has buffers of its own and is dispatched
+                # before any later write of the pool, so the reused id
+                # can be rewritten immediately)
                 self.tier.offer_spill(victim.hash, victim.block_id)
             self._free.append(victim.block_id)
             freed += 1

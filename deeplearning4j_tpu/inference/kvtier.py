@@ -337,9 +337,10 @@ class TierManager:
 
     def offer_spill(self, h: Optional[str], block_id: int) -> None:
         """`_evict_lru` hook, called BEFORE the block id returns to the
-        free list. Captures the page row as an immutable device
-        snapshot (functional update semantics make the freed id safe to
-        reuse immediately) and queues it for the worker; on any
+        free list. Captures the page row as a device snapshot in
+        buffers of its own (the slice is dispatched before any later
+        in-place write of the pool, so the freed id is safe to reuse
+        immediately) and queues it for the worker; on any
         degradation — queue full, no capture, injected fault — the
         block is dropped from the directory and recomputed cold later."""
         if h is None:

@@ -158,12 +158,26 @@ def _cost_of(lowered) -> Dict[str, float]:
     compiled program is asked instead — the same program the engine's
     warm-up already compiled, so with the persistent compile cache on
     (util/compile_cache.py) this is a load, not a second compile.
-    Missing keys read 0 (some backends publish partial models)."""
+    Missing keys read 0 (some backends publish partial models).
+
+    ``donated_bytes`` is what the call hands over to be updated in
+    place: the bytes of every argument leaf the lowering marks donated
+    (global shapes under a mesh). The carried state's bytes for a family
+    that returns it (the engine's donation rule); 0 means the family
+    copies the state on every invocation. Whether the compiler then
+    aliased it is the compiled program's ``alias_size_in_bytes``."""
+    import jax
+    import numpy as np
+
     c = lowered.cost_analysis()
     if c is None:
         c = lowered.compile().cost_analysis()
+    donated = sum(
+        int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
+        for a in jax.tree_util.tree_leaves(lowered.args_info) if a.donated)
     return {"flops": float(c.get("flops", 0.0) or 0.0),
-            "bytes": float(c.get("bytes accessed", 0.0) or 0.0)}
+            "bytes": float(c.get("bytes accessed", 0.0) or 0.0),
+            "donated_bytes": float(donated)}
 
 
 def program_costs(engine) -> Dict[Tuple[str, int], Dict[str, float]]:

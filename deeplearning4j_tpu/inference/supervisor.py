@@ -264,8 +264,8 @@ class EngineSupervisor:
 
     def _warm(self, eng: DecodeScheduler, strict: bool = False) -> None:
         """Program-family warm-up (engine.warmup compiles every
-        bucket's program with pure discarded calls — no metrics, trace,
-        or pool side effects). On a recovery or drain rebuild a failure
+        bucket's program with calls that are the identity on live data
+        — no metrics, trace, or pool side effects). On a recovery or drain rebuild a failure
         is traced, never swallowed, and never fatal: an unwarmed engine
         still serves, it just compiles under traffic. ``strict`` (the
         first engine of a supervisor) re-raises instead.

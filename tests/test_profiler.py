@@ -545,6 +545,13 @@ def test_program_costs_paged_covers_table_buckets(lm_net):
     assert decode_keys == sorted(eng.table_buckets)
     prefill_keys = sorted(b for f, b in costs if f == "prefill")
     assert prefill_keys == sorted(eng.prefill_buckets)
+    # the engine's donation rule: both families hand the carried state
+    # over to be updated in place — at least the page arrays' bytes
+    page_bytes = sum(a.nbytes for st in eng._states.values()
+                     for k, a in st.items() if k.endswith("_pages"))
+    assert page_bytes > 0
+    for (fam, b), c in costs.items():
+        assert c["donated_bytes"] >= page_bytes, (fam, b)
 
 
 # ------------------------------------------------------------ HTTP layer --
