@@ -1,4 +1,7 @@
-"""The configuration's graph, built with the program's public builder DSL.
+"""StarCoder2's graph, built with the program's public builder DSL, and the
+map of the harness's weight tree (`weights.py` beside this file) onto the
+program's layer names. With `harness/engine_driver.py`, the only code of the
+benchmark that imports the program.
 
 A copy of `deeplearning4j_tpu/models/zoo.py:transformer_lm` (PR 21's tree),
 because that function does not pass `rope_base`, `max_cache_len` or the
@@ -56,3 +59,18 @@ def build_conf(cfg: dict, dtype: str = "bfloat16"):
                                        loss="mcxent"), "ln_f")
     gb.set_outputs("out")
     return gb.build()
+
+
+def graph_tree(params: dict) -> dict:
+    """The harness's weight tree under the zoo graph's layer names."""
+    tree = {"embed": {"W": params["embed_w"], "b": params["embed_b"]},
+            "ln_f": {"gain": params["lnf_g"], "beta": params["lnf_b"]},
+            "out": {"W": params["head_w"], "b": params["head_b"]}}
+    for i, p in enumerate(params["blocks"]):
+        tree[f"ln{i}a"] = {"gain": p["ln1_g"], "beta": p["ln1_b"]}
+        tree[f"attn{i}"] = {"Wq": p["wq"], "Wk": p["wk"], "Wv": p["wv"],
+                            "Wo": p["wo"], "b": p["bo"]}
+        tree[f"ln{i}b"] = {"gain": p["ln2_g"], "beta": p["ln2_b"]}
+        tree[f"ff{i}"] = {"W": p["w_up"], "b": p["b_up"]}
+        tree[f"ff{i}o"] = {"W": p["w_down"], "b": p["b_down"]}
+    return tree

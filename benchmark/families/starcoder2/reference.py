@@ -15,11 +15,9 @@ It runs after the window has closed and the engine's state is freed, one
 block at a time with the weights upcast inside the jitted block, attention
 over query blocks, so it fits beside the bfloat16 weights.
 
-`quant="fp8"` is the control: the same forward with every weight matrix
-rounded to fp8 (e4m3) per output channel and every matmul input rounded to
-fp8 per token, the nearest precision below bfloat16. `quant="int8"` is the
-same with int8 (W8A8, symmetric); it is kept for the record: its readings
-lie too close to the bfloat16 engine's own to separate (PERF.md)."""
+`quant` is the control precision (`harness/precision.py`, shared by every
+family): every matmul with a weight goes through its `mm`, which rounds both
+sides to fp8 (the control) or int8 (kept for the record)."""
 from __future__ import annotations
 
 from functools import partial
@@ -27,33 +25,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from benchmark.harness.precision import mm as _mm
+
 _QBLOCK = 512
-
-
-def _fq(x, axis):
-    """Symmetric fake int8 quantisation along `axis`."""
-    s = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / 127.0
-    s = jnp.where(s == 0, 1.0, s)
-    return jnp.round(x / s) * s
-
-
-def _fq8(x, axis):
-    """Fake fp8 (e4m3) quantisation along `axis`, scaled to the format's
-    largest finite value."""
-    s = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / 448.0
-    s = jnp.where(s == 0, 1.0, s)
-    return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
-
-
-def _mm(x, w, quant):
-    w = w.astype(jnp.float32)
-    if quant == "int8":
-        x, w = _fq(x, -1), _fq(w, 0)
-    elif quant == "fp8":
-        x, w = _fq8(x, -1), _fq8(w, 0)
-    elif quant is not None:
-        raise ValueError(f"unknown control precision {quant!r}")
-    return jnp.matmul(x, w)
 
 
 def _ln(x, g, b, eps):

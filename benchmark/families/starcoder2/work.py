@@ -1,4 +1,4 @@
-"""Operations and bytes the *algorithm* needs, from shapes alone.
+"""Operations and bytes the StarCoder2 block *needs*, from shapes alone.
 
 The same count whatever implements the step: an embedding is a row gather
 (not a one-hot matmul), the head is needed only where a token is sampled,
@@ -10,7 +10,7 @@ A configuration is the published dict (`hidden_size`, `num_hidden_layers`,
 `vocab_size`); weights and cache are `bytes_per_el` wide (2 = bfloat16)."""
 from __future__ import annotations
 
-from typing import Iterable, Tuple
+from typing import Iterable, Optional, Tuple
 
 
 def dims(cfg: dict) -> dict:
@@ -52,10 +52,14 @@ def _attn_flops(cfg: dict, keys_seen: int) -> int:
     return m["L"] * 4 * m["h"] * m["dh"] * keys_seen
 
 
-def decode_step(cfg: dict, depths: Iterable[int],
-                bytes_per_el: int = 2) -> Tuple[float, float]:
+def decode_step(cfg: dict, depths: Iterable[int], bytes_per_el: int = 2,
+                run: Optional[dict] = None, t_lo: Optional[float] = None,
+                t_hi: Optional[float] = None) -> Tuple[float, float]:
     """One decode step over live slots; `depths[i]` = keys slot i attends
-    over (its prompt and generated tokens so far, the new one included)."""
+    over (its prompt and generated tokens so far, the new one included).
+    The run and the interval the steps lie in (`harness/facts.decode_work`)
+    are not read: a dense block's work follows from shapes and depths
+    alone."""
     depths = list(depths)
     n = len(depths)
     m = dims(cfg)
@@ -71,9 +75,11 @@ def decode_step(cfg: dict, depths: Iterable[int],
 
 
 def prefill_chunk(cfg: dict, n_tokens: int, depth0: int, final: bool,
-                  bytes_per_el: int = 2) -> Tuple[float, float]:
+                  bytes_per_el: int = 2, run: Optional[dict] = None,
+                  span: Optional[dict] = None) -> Tuple[float, float]:
     """One prefill chunk of `n_tokens` real tokens after `depth0` cached
-    positions; `final` chunks also sample the first output token (head)."""
+    positions; `final` chunks also sample the first output token (head).
+    The run and the chunk's own span are not read (see `decode_step`)."""
     m = dims(cfg)
     flops = 2 * m["L"] * layer_matmul_params(cfg) * n_tokens
     # causal: token i (0-based) sees depth0 + i + 1 keys
@@ -88,11 +94,6 @@ def prefill_chunk(cfg: dict, n_tokens: int, depth0: int, final: bool,
             + (depth0 + n_tokens) * kvb      # cache read once per chunk
             + n_tokens * kvb)                # cache write
     return float(flops), float(byts)
-
-
-def least_seconds(flops: float, byts: float, peaks: dict) -> float:
-    return max(flops / peaks["bf16_flops_per_s"],
-               byts / peaks["hbm_bytes_per_s"])
 
 
 def param_count(cfg: dict) -> int:

@@ -34,9 +34,8 @@ def _pad(n: int, to: int) -> int:
     return -(-n // to) * to
 
 
-def _batch_gaps(params, cfg, batch, prompts, T, P, quants):
+def _batch_gaps(logits_at, params, cfg, batch, prompts, T, P, quants):
     import jax.numpy as jnp
-    from . import reference
 
     R = len(batch)
     ids = np.zeros((R, T), np.int32)
@@ -53,20 +52,23 @@ def _batch_gaps(params, cfg, batch, prompts, T, P, quants):
         tok[i, :n] = r["tokens"]
         live[i, :n] = True
     ids, pos = jnp.asarray(ids), jnp.asarray(pos)
-    ref = reference.logits_at(params, cfg, ids, pos)
+    ref = logits_at(params, cfg, ids, pos)
     best = ref.max(-1)
     out = {}
     for q in quants:
         chosen = jnp.asarray(tok) if q is None else \
-            reference.logits_at(params, cfg, ids, pos, quant=q).argmax(-1)
+            logits_at(params, cfg, ids, pos, quant=q).argmax(-1)
         gap = best - jnp.take_along_axis(ref, chosen[..., None], -1)[..., 0]
         out[q] = np.asarray(gap, np.float64)[live]
     return out
 
 
-def gaps(params: dict, cfg: dict, sample: List[dict], prompts: dict,
-         t_pad: int, p_pad: int, quants=(None,), batch: int = 4) -> dict:
-    """`prompts[index]` is a request's prompt ids. For each entry of `quants`
+def gaps(logits_at, params: dict, cfg: dict, sample: List[dict],
+         prompts: dict, t_pad: int, p_pad: int, quants=(None,),
+         batch: int = 4) -> dict:
+    """`logits_at` is the plain reference of the configuration's family
+    (`families/<model_type>/reference.py`); `prompts[index]` is a request's
+    prompt ids. For each entry of `quants`
     (None: the served tokens; "fp8"/"int8": the control's first choices) the
     gap statistics over the sample, run through the reference `batch`
     requests at a time at one padded shape (one compiled program a cell)."""
@@ -77,7 +79,7 @@ def gaps(params: dict, cfg: dict, sample: List[dict], prompts: dict,
     for i in range(0, len(sample), batch):
         chunk = sample[i:i + batch]
         chunk = chunk + [None] * (batch - len(chunk))
-        for q, g in _batch_gaps(params, cfg, chunk, prompts, T, P,
+        for q, g in _batch_gaps(logits_at, params, cfg, chunk, prompts, T, P,
                                 quants).items():
             parts[q].append(g)
     out = {}

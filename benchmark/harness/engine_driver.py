@@ -2,7 +2,8 @@
 
 This is the call `/generate` makes (`serving/server.py:_decoder_factory`
 builds the engine with the same keyword arguments). The only module of the
-harness, with `graph.py`, that imports the program."""
+harness that imports the program; a family's `graph.py` is the other place
+in the benchmark that does."""
 from __future__ import annotations
 
 import threading
@@ -12,12 +13,6 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from .loadgen import Request
-
-# geometry keys a workload file may set, passed straight to DecodeScheduler
-ENGINE_KEYS = ("n_slots", "max_queue", "prefill_chunk", "kv_block",
-                "kv_pool_mb", "kv_dtype", "paged_kernel", "prefix_cache_mb",
-                "mask_rows", "speculate", "host_cache_mb", "disk_cache_mb")
-
 
 class StampSink:
     """Duck-types `logitproc.TokenStream` for `submit(stream=...)`: the
@@ -40,32 +35,17 @@ class StampSink:
         self.sent = len(handle.tokens)
 
 
-def graph_tree(params: dict) -> dict:
-    """The harness's weight tree under the zoo graph's layer names."""
-    tree = {"embed": {"W": params["embed_w"], "b": params["embed_b"]},
-            "ln_f": {"gain": params["lnf_g"], "beta": params["lnf_b"]},
-            "out": {"W": params["head_w"], "b": params["head_b"]}}
-    for i, p in enumerate(params["blocks"]):
-        tree[f"ln{i}a"] = {"gain": p["ln1_g"], "beta": p["ln1_b"]}
-        tree[f"attn{i}"] = {"Wq": p["wq"], "Wk": p["wk"], "Wv": p["wv"],
-                            "Wo": p["wo"], "b": p["bo"]}
-        tree[f"ln{i}b"] = {"gain": p["ln2_g"], "beta": p["ln2_b"]}
-        tree[f"ff{i}"] = {"W": p["w_up"], "b": p["b_up"]}
-        tree[f"ff{i}o"] = {"W": p["w_down"], "b": p["b_down"]}
-    return tree
-
-
-def build_net(cfg: dict, params: dict, dtype: str):
-    """The configuration's graph with the harness's weights installed, the
-    way `model_serializer.restore_computation_graph` installs a checkpoint's
+def build_net(family, cfg: dict, params: dict, dtype: str):
+    """The configuration's graph, as its family builds it, with the
+    harness's weights installed the way
+    `model_serializer.restore_computation_graph` installs a checkpoint's
     — minus `init()`, which would first draw a second set of weights."""
     import jax
     import jax.numpy as jnp
     from deeplearning4j_tpu.nn.graph import ComputationGraph
-    from .graph import build_conf
 
-    net = ComputationGraph(build_conf(cfg, dtype))
-    tree = graph_tree(params)
+    net = ComputationGraph(family.graph.build_conf(cfg, dtype))
+    tree = family.graph.graph_tree(params)
     jdt = jnp.dtype(dtype)
     for name, impl in net._impls.items():
         want = jax.eval_shape(lambda i=impl: i.init_params(
@@ -84,13 +64,15 @@ def build_net(cfg: dict, params: dict, dtype: str):
 
 
 def build_engine(net, vocab: int, geometry: dict):
+    """The workload file's `engine` block goes to `DecodeScheduler` whole: a
+    key it does not know is its own TypeError, here at set-up, with the
+    key's name."""
     from deeplearning4j_tpu.inference.engine import DecodeScheduler
     from deeplearning4j_tpu.inference.metrics import MetricsRegistry
     from deeplearning4j_tpu.inference.trace import FlightRecorder
 
-    kw = {k: geometry[k] for k in ENGINE_KEYS if k in geometry}
     eng = DecodeScheduler(net, vocab, metrics=MetricsRegistry(),
-                          tracer=FlightRecorder(capacity=1 << 17), **kw)
+                          tracer=FlightRecorder(capacity=1 << 17), **geometry)
     if not eng.paged:
         raise RuntimeError("the cell's geometry did not engage the paged "
                            "KV pool; the cell measures the paged path")
@@ -203,17 +185,20 @@ def run_window(eng, requests: List[Request], seconds: float,
     """Open loop: each request is sent when it is due, whether or not earlier
     ones finished. `at` maps window offsets (s) to callbacks run by this
     thread between sends (the trace's start and stop). Returns the window's
-    counters; per-request stamps land on the Request objects."""
+    counters; per-request stamps land on the Request objects. Under
+    `counters` is the difference over the window of every counter of the
+    engine's `MetricsRegistry` (`snapshot()`, twice), under `spans` every
+    begin record of the program's ring for the window: a reader of a new
+    counter or span needs no edit here."""
     from deeplearning4j_tpu.analysis.runtime import CompileCounter
 
     prof = eng.profiler
     compiles = CompileCounter.for_scheduler(eng)
-    preempted = eng.metrics.counter("decode_preempted_total")
     side = _SideThread(sorted((at or {}).items()))
     snap0 = {"phase_seconds": dict(prof.phase_seconds),
              "iterations": prof.iterations,
              "dispatches": dict(prof.family_dispatches),
-             "preempted": preempted.value}
+             "counters": eng.metrics.snapshot()["counters"]}
     t0 = time.monotonic()
     side.start(t0)
 
@@ -237,7 +222,8 @@ def run_window(eng, requests: List[Request], seconds: float,
     idle_until(t0 + seconds)
     snap1 = {"phase_seconds": dict(prof.phase_seconds),
              "iterations": prof.iterations,
-             "dispatches": dict(prof.family_dispatches)}
+             "dispatches": dict(prof.family_dispatches),
+             "counters": eng.metrics.snapshot()["counters"]}
     deadline = t0 + seconds + drain_s
     for r in requests:
         if r.handle is None:
@@ -249,6 +235,8 @@ def run_window(eng, requests: List[Request], seconds: float,
             r.handle.cancel()
     t_end = time.monotonic()
     side.join()
+    counters = {k: v - snap0["counters"].get(k, 0)
+                for k, v in snap1["counters"].items()}
     return {
         "t0": t0, "seconds": seconds, "drained_s": t_end - (t0 + seconds),
         "phase_seconds": {k: snap1["phase_seconds"][k] - v
@@ -256,7 +244,8 @@ def run_window(eng, requests: List[Request], seconds: float,
         "iterations": snap1["iterations"] - snap0["iterations"],
         "dispatches": {k: v - snap0["dispatches"].get(k, 0)
                        for k, v in snap1["dispatches"].items()},
-        "preempted": preempted.value - snap0["preempted"],
+        "counters": counters,
+        "preempted": counters.get("decode_preempted_total", 0),
         "compiles": compiles.counts(),
         "capacity_blocks": eng.pool.capacity_blocks,
         "pool_live_max": eng.metrics.gauge("kv_pool_blocks_live").max,
@@ -265,15 +254,19 @@ def run_window(eng, requests: List[Request], seconds: float,
 
 
 def spans_between(eng, t_lo: float, t_hi: float) -> List[dict]:
-    """The program's own `prefill_chunk` and `decode_step` spans (begin
-    records carry the arguments), on the host's monotonic clock."""
+    """Every span of the program's own that its ring still holds for the
+    interval: the begin records, which carry the arguments, with their name
+    and track, on the host's monotonic clock (among them today `queued`,
+    `decode`, `prefill_chunk` with its request, bucket and tokens, and
+    `decode_step` with its live slots)."""
     t_ref = eng.tracer.clock()["trace_t0"]
     out = []
     for e in eng.tracer.events():
-        if e["ph"] == "B" and e["name"] in ("prefill_chunk", "decode_step"):
+        if e["ph"] == "B":
             t = e["ts"] + t_ref
             if t_lo <= t <= t_hi:
-                out.append({"name": e["name"], "t": t, **e.get("args", {})})
+                out.append({**e.get("args", {}), "name": e["name"],
+                            "track": e["track"], "t": t})
     return out
 
 

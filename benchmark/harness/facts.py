@@ -1,9 +1,9 @@
-"""What the per-metric readers share: selections over the run's rows."""
+"""What the per-metric readers share: selections over the run's rows and
+spans, and the work of an interval as the configuration's family counts it
+(`run["family"].work`, found by `model_type`)."""
 from __future__ import annotations
 
 from typing import Iterable, List, Tuple
-
-from . import work
 
 
 def decode_depths(rows: Iterable[dict], t_lo: float, t_hi: float) -> List[int]:
@@ -18,8 +18,8 @@ def decode_depths(rows: Iterable[dict], t_lo: float, t_hi: float) -> List[int]:
     return out
 
 
-def chunks(run: dict, t_lo: float, t_hi: float) -> List[Tuple[int, int, bool]]:
-    """(tokens, depth0, final) of each `prefill_chunk` span begun in
+def chunks(run: dict, t_lo: float, t_hi: float) -> List[Tuple]:
+    """(tokens, depth0, final, span) of each `prefill_chunk` span begun in
     [t_lo, t_hi]; depth from the request's earlier chunks in the window."""
     plen = {r["id"]: r["prompt_len"] for r in run["rows"]}
     fed, out = {}, []
@@ -30,24 +30,32 @@ def chunks(run: dict, t_lo: float, t_hi: float) -> List[Tuple[int, int, bool]]:
         fed[s["request"]] = d0 + s["tokens"]
         if t_lo <= s["t"] <= t_hi:
             out.append((s["tokens"], d0, d0 + s["tokens"]
-                        >= plen[s["request"]]))
+                        >= plen[s["request"]], s))
     return out
 
 
-def decode_work(cfg: dict, depths: List[int], executions: int):
-    """Work of `executions` decode steps that together fed `depths`: the
-    weights are read once per step, the rest per token."""
+def decode_work(run: dict, depths: List[int], executions: int,
+                t_lo: float, t_hi: float):
+    """Work of the `executions` decode steps of [t_lo, t_hi] that together
+    fed `depths`: the weights are read once per step, the rest per token.
+    The family's `work` gets the run and the interval beside the depths
+    (what a routed layer reads depends on what was routed): it picks the
+    spans and counters it needs from `run["window"]` itself."""
     if not depths or executions < 1:
         return 0.0, 0.0
-    f, b = work.decode_step(cfg, depths)
-    _, b1 = work.decode_step(cfg, [])
+    work, cfg = run["family"].work, run["cfg"]
+    f, b = work.decode_step(cfg, depths, run=run, t_lo=t_lo, t_hi=t_hi)
+    _, b1 = work.decode_step(cfg, [], run=run, t_lo=t_lo, t_hi=t_hi)
     return f, b + (executions - 1) * b1
 
 
-def prefill_work(cfg: dict, chs: List[Tuple[int, int, bool]]):
+def prefill_work(run: dict, chs: List[Tuple]):
+    """Work of the chunks that `chunks` found; the family's `work` gets the
+    run and each chunk's own span beside its sizes."""
+    work, cfg = run["family"].work, run["cfg"]
     f = b = 0.0
-    for n, d0, final in chs:
-        fi, bi = work.prefill_chunk(cfg, n, d0, final)
+    for n, d0, final, span in chs:
+        fi, bi = work.prefill_chunk(cfg, n, d0, final, run=run, span=span)
         f, b = f + fi, b + bi
     return f, b
 

@@ -25,3 +25,10 @@ def peaks_for(device_kind: str) -> dict:
         raise KeyError(
             f"no published peak for device kind {device_kind!r}; add it to "
             f"benchmark/harness/peaks.py with its source") from None
+
+
+def least_seconds(flops: float, byts: float, peaks: dict) -> float:
+    """The least time the chip could take for that work: the larger of
+    operations over peak operations and bytes over peak bytes a second."""
+    return max(flops / peaks["bf16_flops_per_s"],
+               byts / peaks["hbm_bytes_per_s"])

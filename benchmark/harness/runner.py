@@ -2,13 +2,14 @@
 tools (`tools/sweep.py`, `tools/seeds.py`) reuse in one process."""
 from __future__ import annotations
 
-import importlib.util
 import json
 import shutil
 import sys
 import time
 from pathlib import Path
 from typing import Optional
+
+from . import family
 
 
 def prepare(root: Path, rehearse: bool) -> None:
@@ -33,7 +34,8 @@ def load_json(path: Path) -> dict:
 
 
 def load_cell(root: Path, name: str) -> dict:
-    """Everything the cell's name leads to, found by name under benchmark/."""
+    """Everything the cell's name leads to, found by name under benchmark/:
+    its files, and the family of its configuration's `model_type`."""
     bench = load_json(root / "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -42,18 +44,16 @@ def load_cell(root: Path, name: str) -> dict:
     cell = cells[name]
     entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     b = root / "benchmark"
-    return {"root": root, "bench": bench, "cell": cell,
-            "cfg": load_json(root / entry["file"]),
+    cfg = load_json(root / entry["file"])
+    return {"root": root, "bench": bench, "cell": cell, "cfg": cfg,
+            "family": family.load(root, cfg),
             "mix": load_json(b / "traffic" / f"{cell['traffic']}.json"),
             "wl": load_json(b / "workloads" / f"{cell['name']}.json")}
 
 
 def reader(root: Path, name: str):
     path = root / "benchmark" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return family.module_at(f"_metric_{name}", path).read
 
 
 def metric_names(bench: dict, cell: str, trace: bool) -> list:
@@ -84,9 +84,9 @@ def setup(ctx: dict, seed: int) -> dict:
     mix's lengths can reach. All of it is `setup_s`."""
     import jax
     import jax.numpy as jnp
-    from . import engine_driver, loadgen, weights
+    from . import engine_driver, loadgen
 
-    cfg, wl = ctx["cfg"], ctx["wl"]
+    cfg, wl, fam = ctx["cfg"], ctx["wl"], ctx["family"]
     dtype = wl.get("dtype", "bfloat16")
     t = [time.time()]
 
@@ -94,10 +94,10 @@ def setup(ctx: dict, seed: int) -> dict:
         t.append(time.time())
         return t[-1] - t[-2]
 
-    params = weights.make_params(cfg, seed, jnp.dtype(dtype))
+    params = fam.weights.make_params(cfg, seed, jnp.dtype(dtype))
     jax.block_until_ready(params)
     took = {"weights_s": lap()}
-    net = engine_driver.build_net(cfg, params, dtype)
+    net = engine_driver.build_net(fam, cfg, params, dtype)
     eng = engine_driver.build_engine(net, cfg["vocab_size"], wl["engine"])
     took["engine_s"] = lap()
     limits = loadgen.length_limits(ctx["mix"])
@@ -114,14 +114,13 @@ def reseed(ctx: dict, st: dict, seed: int) -> None:
     serves a dozen. The old weights are freed as the last reference goes."""
     import jax
     import jax.numpy as jnp
-    from . import engine_driver, weights
-
+    fam = ctx["family"]
     dtype = ctx["wl"].get("dtype", "bfloat16")
     st["params"] = None
     st["net"].params = {}
-    st["params"] = weights.make_params(ctx["cfg"], seed, jnp.dtype(dtype))
+    st["params"] = fam.weights.make_params(ctx["cfg"], seed, jnp.dtype(dtype))
     jax.block_until_ready(st["params"])
-    st["net"].params = engine_driver.graph_tree(st["params"])
+    st["net"].params = fam.graph.graph_tree(st["params"])
 
 
 def trace_hooks(ctx: dict, seconds: float, trace: dict) -> dict:
@@ -190,7 +189,8 @@ def compare(ctx: dict, st: dict, m: dict, seed: int,
     t = time.time()
     got = {}
     if sample:
-        got = check.gaps(st["params"], ctx["cfg"], sample, m["prompts"],
+        got = check.gaps(ctx["family"].reference.logits_at, st["params"],
+                         ctx["cfg"], sample, m["prompts"],
                          lim["total_max"], lim["out_max"],
                          quants=(None,) + tuple(controls),
                          batch=wl["check"].get("batch", 4))
@@ -207,9 +207,9 @@ def facts(ctx: dict, m: dict, device: dict, setup_s: float) -> dict:
     pk = peaks.peaks_for(device["kind"]) if device["platform"] == "tpu" \
         else None
     return {"rows": m["rows"], "window": m["window"], "cfg": ctx["cfg"],
-            "geometry": ctx["wl"]["engine"], "traffic": ctx["mix"],
-            "peaks": pk, "device": device, "setup_s": setup_s,
-            "trace": m["trace"]}
+            "family": ctx["family"], "geometry": ctx["wl"]["engine"],
+            "traffic": ctx["mix"], "peaks": pk, "device": device,
+            "setup_s": setup_s, "trace": m["trace"]}
 
 
 def read_metrics(ctx: dict, run: dict, trace_on: bool) -> dict:

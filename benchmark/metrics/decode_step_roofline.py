@@ -1,8 +1,9 @@
 """Decode program's share of its roofline, over the traced interval: the
 least time the chip could take for the decode steps' work (weights once a
-step, each live slot's cache at its real depth; `harness/work.py`) over the
-device time of the decode module's executions in the trace."""
-from benchmark.harness import facts, reducer, work
+step, each live slot's cache at its real depth; the `work.py` of the
+configuration's family) over the device time of the decode module's
+executions in the trace."""
+from benchmark.harness import facts, peaks, reducer
 
 # XLA module name of the decode program today (jit of
 # DecodeScheduler._step_paged_fn); a rename is repaired here.
@@ -18,5 +19,5 @@ def read(run):
     depths = facts.decode_depths(run["rows"], t_on, t_off)
     if not n or not depths or seconds <= 0:
         return None
-    f, b = facts.decode_work(run["cfg"], depths, n)
-    return 100.0 * work.least_seconds(f, b, run["peaks"]) / seconds
+    f, b = facts.decode_work(run, depths, n, t_on, t_off)
+    return 100.0 * peaks.least_seconds(f, b, run["peaks"]) / seconds

@@ -2,7 +2,7 @@
 least time for the chunks the program's `prefill_chunk` spans report there
 (real tokens, real depth, head only on a prompt's last chunk) over the
 device time of the prefill module's executions in the trace."""
-from benchmark.harness import facts, reducer, work
+from benchmark.harness import facts, peaks, reducer
 
 # jit of DecodeScheduler._prefill_paged_fn
 MODULE = r"^jit__prefill_paged_fn$"
@@ -17,5 +17,5 @@ def read(run):
     chs = facts.chunks(run, t_on, t_off)
     if not n or not chs or seconds <= 0:
         return None
-    f, b = facts.prefill_work(run["cfg"], chs)
-    return 100.0 * work.least_seconds(f, b, run["peaks"]) / seconds
+    f, b = facts.prefill_work(run, chs)
+    return 100.0 * peaks.least_seconds(f, b, run["peaks"]) / seconds

@@ -11,8 +11,8 @@ def read(run):
     w = run["window"]
     t0, t1 = w["t0"], w["t0"] + w["seconds"]
     depths = facts.decode_depths(run["rows"], t0, t1)
-    fd, _ = facts.decode_work(run["cfg"], depths, 1)
-    fp, _ = facts.prefill_work(run["cfg"], facts.chunks(run, t0, t1))
+    fd, _ = facts.decode_work(run, depths, 1, t0, t1)
+    fp, _ = facts.prefill_work(run, facts.chunks(run, t0, t1))
     if fd + fp <= 0:
         return None
     return 100.0 * (fd + fp) / (w["seconds"]

@@ -9,7 +9,15 @@ open loop through `DecodeScheduler.submit`, drains, frees the engine, checks
 the served tokens against the plain reference, and prints one JSON line.
 Everything about a cell is data: `BENCHMARK.json` names the configuration,
 the traffic mix and the metrics; each is a file found by that name under
-`benchmark/{configs,traffic,workloads,metrics}/`.
+`benchmark/{configs,traffic,workloads,metrics}/`. Everything about an
+architecture is files too: the configuration's `model_type` names a
+directory `benchmark/families/<model_type>/` (weights, graph, reference,
+work; `harness/family.py`). So a cell of an architecture the benchmark has
+is new data files plus one entry, and a new architecture is its family
+directory besides; neither edits a file that is there. (Before PR 28 that
+held for a third StarCoder2-shaped configuration and for nothing else: the
+block was written into four modules of the harness.) A family without its
+directory is exit 2, before any device is asked for.
 
 `--rehearse-cpu` (the benchmark's own flag, never given by the driver) lets
 the same path run on the CPU at a toy size for the tests; its result names

@@ -5,7 +5,7 @@ from pathlib import Path
 
 import pytest
 
-from benchmark.harness import peaks, runner, stats, work
+from benchmark.harness import family, peaks, runner, stats
 
 ROOT = Path(__file__).resolve().parents[2]
 
@@ -79,6 +79,11 @@ def _cfg(name):
                       .read_text())
 
 
+def _work(cfg):
+    """The family's count of work, found as a run finds it."""
+    return family.load(ROOT, cfg).work
+
+
 @pytest.mark.parametrize("name,per_layer,kv", [
     # hand count: d*d*2 (q, o) + d*kv*2 (k, v) + 2*d*ff
     ("starcoder2-3b", 3072 * 3072 * 2 + 3072 * 256 * 2 + 2 * 3072 * 12288,
@@ -88,6 +93,7 @@ def _cfg(name):
 ])
 def test_work_against_a_hand_count(name, per_layer, kv):
     cfg = _cfg(name)
+    work = _work(cfg)
     L, d, v = cfg["num_hidden_layers"], cfg["hidden_size"], cfg["vocab_size"]
     h = cfg["num_attention_heads"]
     assert work.layer_matmul_params(cfg) == per_layer
@@ -111,10 +117,64 @@ def test_work_against_a_hand_count(name, per_layer, kv):
 
 
 def test_parameter_count_is_the_issue_s():
-    assert work.param_count(_cfg("starcoder2-3b")) * 2 == \
-        pytest.approx(6.36e9, rel=2e-3)
-    assert work.param_count(_cfg("starcoder2-7b-d16")) * 2 == \
-        pytest.approx(7.85e9, rel=2e-3)
+    for name, gb in (("starcoder2-3b", 6.36e9), ("starcoder2-7b-d16", 7.85e9)):
+        cfg = _cfg(name)
+        assert _work(cfg).param_count(cfg) * 2 == pytest.approx(gb, rel=2e-3)
+
+
+# Computed on the parent of PR 28 (commit 6298da9, `harness/work.py` before
+# it moved to `families/starcoder2/work.py`): the move reproduces every
+# number exactly, and so do both rooflines and `serve_mfu_pct`.
+PINNED_WORK = {
+    "starcoder2-3b": {
+        "decode_1x100": (6095536128.0, 6063734784.0),
+        "decode_16x600": (100477698048.0, 6356127744.0),
+        "decode_empty": (0.0, 6060625920.0),
+        "chunk_256_0": (1485837434880.0, 5775826944.0),
+        "chunk_256_1024_final": (1582776188928.0, 6109384704.0),
+        "chunk_37_3000_final": (254477426688.0, 6155286528.0),
+        "param_count": 3181310976,
+        "kv_bytes_per_position": 30720,
+    },
+    "starcoder2-7b-d16": {
+        "decode_1x100": (7428243456.0, 7403662336.0),
+        "decode_16x600": (121211191296.0, 7715588096.0),
+        "decode_empty": (0.0, 7400343552.0),
+        "chunk_256_0": (1787817885696.0, 6966378496.0),
+        "chunk_256_1024_final": (1865580281856.0, 7453034496.0),
+        "chunk_37_3000_final": (290388934656.0, 7501413376.0),
+        "param_count": 3926668800,
+        "kv_bytes_per_position": 32768,
+    },
+}
+
+
+@pytest.mark.parametrize("name", sorted(PINNED_WORK))
+def test_work_is_what_it_was_before_the_move(name):
+    cfg, pin = _cfg(name), PINNED_WORK[name]
+    work = _work(cfg)
+    got = {
+        "decode_1x100": work.decode_step(cfg, [100]),
+        "decode_16x600": work.decode_step(cfg, [600] * 16),
+        "decode_empty": work.decode_step(cfg, []),
+        "chunk_256_0": work.prefill_chunk(cfg, 256, 0, False),
+        "chunk_256_1024_final": work.prefill_chunk(cfg, 256, 1024, True),
+        "chunk_37_3000_final": work.prefill_chunk(cfg, 37, 3000, True),
+        "param_count": work.param_count(cfg),
+        "kv_bytes_per_position": work.kv_bytes_per_position(cfg)}
+    assert got == pin
+    # the run, which this family is handed and ignores
+    run = {"window": {"spans": [], "counters": {"x": 1}}}
+    assert work.decode_step(cfg, [100], run=run, t_lo=0.0, t_hi=1.0) == \
+        pin["decode_1x100"]
+    assert work.prefill_chunk(cfg, 256, 0, False, run=run, span={}) == \
+        pin["chunk_256_0"]
+
+
+def test_least_seconds_is_the_larger_bound():
+    pk = peaks.peaks_for("TPU v5 lite")
+    assert peaks.least_seconds(197e12, 0.0, pk) == 1.0
+    assert peaks.least_seconds(197e12, 2 * 819e9, pk) == 2.0
 
 
 def test_unknown_device_kind_is_an_error():

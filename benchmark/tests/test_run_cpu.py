@@ -84,3 +84,24 @@ def test_rehearsal_prints_the_contracts_line(root, trace):
         assert got == {"ttft_p95_ms", "itl_p95_ms", "out_tok_s", "setup_s"}
         assert all(m["value"] > 0 for m in line["metrics"].values())
     assert not (root / "benchmark" / ".trace").exists()
+
+
+def test_a_cell_like_the_chat_cell_reports_its_ttft_per_layer(
+        tmp_path_factory):
+    """A cell that joins the lists `sc2-3b.chat` is on has no end-to-end
+    `ttft_p95_ms`: the same arithmetic is read per layer, under `chat.`."""
+    root = make_root(tmp_path_factory.mktemp("chat"), like="sc2-3b.chat")
+    got = {}
+    for trace in (0, 1):
+        p = _run(root, "--seed", "7", "--trace", str(trace), "--rehearse-cpu")
+        assert p.returncode == 0, p.stderr[-2000:]
+        line = json.loads(p.stdout.strip().splitlines()[-1])
+        assert line["correct"] is True
+        got[trace] = line["metrics"]
+    assert set(got[0]) == {"itl_p95_ms", "out_tok_s", "setup_s"}
+    assert {"chat.ttft_p95_ms", "chat.queue_p95_ms",
+            "chat.gen_late_p95_ms", "sched_iter_ms"} <= set(got[1])
+    assert not set(got[1]) & {"ttft_p95_ms", "queue_p95_ms",
+                              "gen_late_p95_ms", "prefill_chunk_roofline",
+                              "chat.prefill_chunk_roofline"}
+    assert got[1]["chat.ttft_p95_ms"]["value"] > 0

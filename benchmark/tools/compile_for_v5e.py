@@ -25,14 +25,13 @@ def main() -> int:
     import jax.numpy as jnp
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
-    from benchmark.harness import engine_driver, loadgen, weights
-    from benchmark.harness.graph import build_conf
+    from benchmark.harness import engine_driver, loadgen
     from deeplearning4j_tpu.nn.graph import ComputationGraph
     from deeplearning4j_tpu.inference.engine import DecodeScheduler
 
     jax.config.update("jax_enable_compilation_cache", False)
     ctx = runner.load_cell(root, args.workload)
-    cfg, wl = ctx["cfg"], ctx["wl"]
+    cfg, wl, fam = ctx["cfg"], ctx["wl"], ctx["family"]
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
@@ -41,15 +40,12 @@ def main() -> int:
     def sds(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip)
 
-    shapes = weights.shapes(cfg)
+    # the family's own tree, as shapes: nothing is drawn
     tree = jax.tree_util.tree_map(
-        lambda s: jax.ShapeDtypeStruct(s, dt, sharding=chip), shapes,
-        is_leaf=lambda x: isinstance(x, tuple))
-    net = ComputationGraph(build_conf(cfg, str(dt)))
-    params = engine_driver.graph_tree(tree)
-    kw = {k: wl["engine"][k] for k in engine_driver.ENGINE_KEYS
-          if k in wl["engine"]}
-    eng = DecodeScheduler(net, cfg["vocab_size"], **kw)
+        sds, jax.eval_shape(lambda: fam.weights.make_params(cfg, 0, dt)))
+    net = ComputationGraph(fam.graph.build_conf(cfg, str(dt)))
+    params = fam.graph.graph_tree(tree)
+    eng = DecodeScheduler(net, cfg["vocab_size"], **wl["engine"])
     states = jax.tree_util.tree_map(sds, eng._states)
     lim = loadgen.length_limits(ctx["mix"])
     facts = engine_driver.engine_facts(eng)
