@@ -68,7 +68,10 @@ _STATIC_ATTRS = {"shape", "ndim", "dtype", "size", "name", "aval",
 # builtins that inspect structure, not values — they launder taint
 _SANITIZERS = {"isinstance", "len", "type", "hasattr", "getattr", "id",
                "repr", "str", "callable", "issubclass", "enumerate",
-               "range", "zip"}
+               "range", "zip",
+               # the engine's one probe of a state entry's STRUCTURE
+               # (`isinstance(st, dict) and "k_pages" in st`, by name)
+               "_is_paged"}
 _SYNC_BUILTINS = {"float", "int", "bool", "complex"}
 _SYNC_METHODS = {"item", "tolist", "block_until_ready"}
 _NUMPY_NAMES = {"np", "numpy"}

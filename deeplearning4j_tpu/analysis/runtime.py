@@ -180,6 +180,9 @@ class CompileCounter:
         jcow = getattr(scheduler, "_jcow", None)
         if jcow is not None:
             c.track("block_cow", jcow, budget=1)
+        jsumtab = getattr(scheduler, "_jsumtab", None)
+        if jsumtab is not None:  # a net whose attention recycles pages
+            c.track("summary_table", jsumtab, budget=1)
         # KV tiering (ISSUE 19): spill slices and restore writes keep
         # the block index traced — one program each for the whole tier
         # ladder, whatever spills or promotes

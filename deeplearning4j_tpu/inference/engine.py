@@ -100,6 +100,7 @@ from ..analysis.runtime import (CompileCounter, device_index, host_read,
                                 ledger_check_request, ledger_check_zero,
                                 ledger_forget, ledger_note)
 from ..models.sampling import sample_logits
+from ..nn.layers.attention import SelfAttentionLayerImpl
 from ..nn.layers.recurrent import (BaseRecurrentImpl,
                                    _materialize_rnn_states)
 from ..nn.multilayer import _compute_dtype_of
@@ -128,6 +129,18 @@ _MIN_CHUNK_BUCKET = 16
 # leak
 _LEDGER_KINDS = frozenset(
     ("trie_pin", "pool_block", "mask_row", "engine_slot"))
+
+
+def _carries_kv_cache(impl) -> bool:
+    """This impl carries a position-addressed K/V cache (contiguous stripe
+    or pool pages): `SelfAttentionLayerImpl` and the layers built on it."""
+    return isinstance(impl, SelfAttentionLayerImpl)
+
+
+def _is_paged(st) -> bool:
+    """This state entry is a paged attention layer's (pool-wide page arrays
+    under `PAGE_KEYS` beside the per-slot leaves)."""
+    return isinstance(st, dict) and "k_pages" in st
 
 
 class _EngineFenced(Exception):
@@ -292,7 +305,8 @@ class _ActiveSeq:
     __slots__ = ("handle", "prompt", "fed", "rng", "temperature", "top_k",
                  "top_p", "eos_id", "steps", "pool_node", "block_ids",
                  "shared", "written", "phase", "resumed", "folded",
-                 "cow_starved", "fork", "draft_fed", "proc")
+                 "cow_starved", "fork", "draft_fed", "proc", "rolled",
+                 "summary_ids")
 
     def __init__(self, handle: DecodeHandle, prompt: Sequence[int],
                  temperature: float, top_k: Optional[int],
@@ -311,6 +325,12 @@ class _ActiveSeq:
         self.block_ids: List[int] = []  # table entries, logical order
         self.shared: List[bool] = []    # True = trie-owned (COW on write)
         self.written = 0  # host mirror of the slot's device cache pos
+        # a net whose attention recycles pages (EVA, `_roll_window`):
+        # logical blocks [0, rolled) went back to the pool when their
+        # windows closed (their `block_ids` entries are scratch), and the
+        # chunk summaries live in pages of their own, for the request's life
+        self.rolled = 0
+        self.summary_ids: List[int] = []
         # request-track span currently open ("queued" -> "prefill" ->
         # "decode", with "preempted" bridging a swap-out) — the single
         # source of truth for span transitions, because a RESUMED
@@ -333,6 +353,11 @@ class _ActiveSeq:
         # penalty counts, grammar DFA state, stop matcher, device-mask
         # residency. None for plain requests — the hot path unchanged.
         self.proc: Optional[LogitState] = None
+
+    @property
+    def blocks_held(self) -> int:
+        """Pool blocks this sequence's table entries stand for now."""
+        return len(self.block_ids) - self.rolled + len(self.summary_ids)
 
     def full_context(self) -> List[int]:
         """Every token the sequence is conditioned on so far (prompt —
@@ -396,6 +421,12 @@ class DecodeScheduler:
     pressure the latest-submitted slot is preempted (blocks released,
     sequence requeued and later resumed, token-identically). Attention
     nets only; recurrent nets fall back to contiguous with a warning.
+    What a request holds is asked of the net's attention layers
+    (``blocks_needed``): a net with `EvaAttentionLayer` gives its exact
+    pages back as each window closes and keeps one summary page per
+    ``kv_block`` chunks (`_roll_window`); such a net is served through
+    the pool only, and the prefix trie stands aside for it
+    (docs/serving.md, "The EVA cache").
 
     ``prefix_cache_mb``: byte budget (MiB) for the CONTIGUOUS-mode side
     prefix pool (ignored when ``kv_pool_mb`` is set — the paged pool is
@@ -594,11 +625,43 @@ class DecodeScheduler:
         stateful = [impl for _, impl in self._impl_items()
                     if isinstance(impl, BaseRecurrentImpl)]
         self._chunk_dense = bool(stateful) and all(
-            type(impl).__name__ == "SelfAttentionLayerImpl"
-            for impl in stateful)
+            _carries_kv_cache(impl) for impl in stateful)
         attn_keys = [key for key, st in abstract_states.items()
                      if isinstance(st, dict) and "k" in st and "v" in st
                      and "pos" in st]
+        # what a request holds in the pool is asked of the layer kind
+        # (`blocks_needed`), and so is whether its pages are recycled
+        # while it lives (`page_recycling`: EVA's (window, chunk), None
+        # for a cache of one row per position). One block table serves
+        # every layer, so every layer must answer alike
+        self._attn_impl = next((impl for impl in stateful
+                                if _carries_kv_cache(impl)), None)
+        recycling = {impl.page_recycling() for impl in stateful
+                     if _carries_kv_cache(impl)}
+        self._eva: Optional[Tuple[int, int]] = None
+        if recycling - {None}:
+            if len(recycling) > 1:
+                raise ValueError(
+                    "attention layers that recycle pages differently "
+                    f"({sorted(map(str, recycling))}) cannot share one "
+                    "block table: window and global layers in one model "
+                    "are not served yet")
+            self._eva = recycling.pop()
+            window, chunk = self._eva
+            if not (kv_pool_mb and kv_pool_mb > 0) or kv_dtype \
+                    or speculate or (mesh is not None and mesh != 1):
+                raise ValueError(
+                    "a net with EvaAttentionLayer is served through the "
+                    "paged pool only (kv_pool_mb > 0), single-device, with "
+                    "no int8 KV and no speculation")
+            if window % self.prefill_chunk or self.prefill_chunk % chunk \
+                    or window % int(kv_block) or int(kv_block) % chunk:
+                raise ValueError(
+                    f"EVA paging needs chunk_size={chunk} | kv_block="
+                    f"{kv_block} | window_size={window} and chunk_size | "
+                    f"prefill_chunk={self.prefill_chunk} | window_size: a "
+                    "chunk of prompt never straddles a window or splits a "
+                    "summary chunk")
         # -- tensor-parallel mesh (inference/sharding.py, ISSUE 9) --
         # resolved BEFORE the KV layout: pool byte budgets are per-device
         # (each device holds Hkv/tp heads per block), and the pool must
@@ -740,6 +803,15 @@ class DecodeScheduler:
                             "pos": zeros(st["pos"].shape,
                                          st["pos"].dtype),
                         }
+                        if self._eva is not None:
+                            # summary block -> page, per slot: carried
+                            # on the device and written by `_sumtab_fn`
+                            # when a summary page is claimed, so the
+                            # programs keep one [n_slots, nb] table
+                            self._states[key]["summary_table"] = zeros(
+                                (self.n_slots,
+                                 -(-pool.capacity_blocks // self._eva[1])),
+                                jnp.int32)
                     self._cache_cap = pool.capacity_blocks * self.kv_block
                     self.table_buckets = pow2_buckets(pool.capacity_blocks)
                     self._table = np.full(
@@ -874,6 +946,16 @@ class DecodeScheduler:
                                     donate_argnames=("states",))
             self._jcow = jax.jit(self._cow_fn,
                                  donate_argnames=("states",))
+        self._jsumtab = None
+        if self._eva is not None:
+            if not self.paged:
+                raise ValueError(
+                    f"kv_pool_mb={kv_pool_mb} holds no two blocks of "
+                    f"{self.kv_block} positions: a net with "
+                    "EvaAttentionLayer is served through the paged pool "
+                    "only")
+            self._jsumtab = jax.jit(self._sumtab_fn,
+                                    donate_argnames=("states",))
         # -- hierarchical KV tiering (ISSUE 19, kvtier.py) ------------------
         # opt-in (host_cache_mb=0 keeps the engine byte-identical to the
         # tierless build: no TierManager, no extra programs, no hot-path
@@ -1002,7 +1084,7 @@ class DecodeScheduler:
                 self.draft_blocks = kk if draft_net is None else 0
                 caps = [int(getattr(impl.conf, "max_cache_len", 1024))
                         for _, impl in self._draft_impl_items()
-                        if type(impl).__name__ == "SelfAttentionLayerImpl"]
+                        if _carries_kv_cache(impl)]
                 self._draft_cap = min(caps) if caps else None
                 # the draft's private KV cache: contiguous per-slot
                 # stripes even under a paged main cache (K layers only,
@@ -1098,6 +1180,25 @@ class DecodeScheduler:
             # best-of-n COW forks: candidates that attached to a fork
             # group's published prompt blocks (zero-copy remaps)
             self._m_forks = m.counter("decode_forks_total")
+        if self._eva is not None:
+            self._m_eva_rolled = m.counter(
+                "eva_windows_rolled_total",
+                help="windows closed: their exact pages went back to the "
+                     "pool, their chunk summaries stay")
+            self._m_eva_recycled = m.counter("eva_blocks_recycled_total")
+            # rows decode tokens attended over, from host-side depths
+            self._m_eva_rows_exact = m.counter(
+                "eva_rows_exact_total",
+                help="exact rows of the open window attended by decode "
+                     "tokens")
+            self._m_eva_rows_summary = m.counter(
+                "eva_rows_summary_total",
+                help="chunk-summary rows of closed windows attended by "
+                     "decode tokens")
+            self._m_publish_skipped = m.counter(
+                "prefix_publish_skipped_total",
+                help="finished prompts the prefix trie did not adopt: "
+                     "their pages were recycled while the request lived")
         if self.speculate:
             self._m_spec_proposed = m.counter("spec_tokens_proposed_total")
             self._m_spec_accepted = m.counter("spec_tokens_accepted_total")
@@ -1172,7 +1273,7 @@ class DecodeScheduler:
     def _min_cache_len(self) -> Optional[int]:
         caps = []
         for _, impl in self._impl_items():
-            if type(impl).__name__ == "SelfAttentionLayerImpl":
+            if _carries_kv_cache(impl):
                 caps.append(int(getattr(impl.conf, "max_cache_len", 1024)))
         return min(caps) if caps else None
 
@@ -1252,7 +1353,7 @@ class DecodeScheduler:
         table, the layer never returns them."""
         out = {}
         for key, st in states.items():
-            if isinstance(st, dict) and "k_pages" in st:
+            if _is_paged(st):
                 out[key] = {**st, "table": table, "wmask": wmask,
                             "paged_kernel": self.paged_kernel,
                             "mesh": self.mesh}
@@ -1344,7 +1445,7 @@ class DecodeScheduler:
             return a
         out = {}
         for key, st in states.items():
-            if isinstance(st, dict) and "k_pages" in st:
+            if _is_paged(st):
                 out[key] = {k: (v if k in PAGE_KEYS else f(v))
                             for k, v in st.items()}
             else:
@@ -1363,7 +1464,7 @@ class DecodeScheduler:
             return part
         out = {}
         for key, st in states.items():
-            if isinstance(st, dict) and "k_pages" in st:
+            if _is_paged(st):
                 out[key] = {k: (sub[key][k] if k in PAGE_KEYS
                                 else f(v, sub[key][k]))
                             for k, v in st.items()}
@@ -1461,7 +1562,7 @@ class DecodeScheduler:
                                              keepdims=False)[0]
         fixed = {}
         for key, st in new_sub.items():
-            if isinstance(st, dict) and "k_pages" in st:
+            if _is_paged(st):
                 # the layer advanced pos by the PADDED chunk length; the
                 # sequence is only n_real tokens deeper (no overflow
                 # sentinel to preserve — paged bucketing covers the
@@ -1583,8 +1684,8 @@ class DecodeScheduler:
         moment pos steps back over them."""
         out = {}
         for key, st in states.items():
-            if isinstance(st, dict) and "pos" in st \
-                    and ("k" in st or "k_pages" in st):
+            if _is_paged(st) or (isinstance(st, dict) and "pos" in st
+                                 and "k" in st):
                 out[key] = {**st, "pos": jnp.where(mask, posv, st["pos"])}
             else:
                 out[key] = st
@@ -1603,6 +1704,14 @@ class DecodeScheduler:
             # already in the compiled family — no new programs.
             cap = max(1, min(cap, int(self.chunk_cap)))
         n_real = min(remaining, cap)
+        if self._eva is not None:
+            # a chunk lies inside one window and starts on a summary
+            # chunk's boundary (the layer's T > 1 contract): a cap the
+            # degradation ladder made uneven is rounded down to one
+            window, chunk = self._eva
+            n_real = min(n_real, window - seq.fed % window)
+            if n_real < remaining:
+                n_real -= n_real % chunk
         bucket = bucket_for(n_real, self.prefill_buckets)
         if self._cache_cap is not None and \
                 seq.fed + bucket > self._cache_cap:
@@ -1635,7 +1744,7 @@ class DecodeScheduler:
             return a
         out = {}
         for key, st in states.items():
-            if isinstance(st, dict) and "k_pages" in st:
+            if _is_paged(st):
                 out[key] = {k: (v if k in PAGE_KEYS else zero_row(v))
                             for k, v in st.items()}
             else:
@@ -1651,8 +1760,22 @@ class DecodeScheduler:
         v = val[0]
         out = {}
         for key, st in states.items():
-            if isinstance(st, dict) and "k_pages" in st:
+            if _is_paged(st):
                 out[key] = {**st, "pos": st["pos"].at[s].set(v)}
+            else:
+                out[key] = st
+        return out
+
+    def _sumtab_fn(self, states, slot, col, bid):
+        """Point one slot's summary block ``col`` at page ``bid`` in every
+        EVA layer's carried ``summary_table`` (`_roll_window` claimed the
+        page): 1-element int32 array args, as `_setpos_fn`. The slot's row
+        needs no release: admission zeroes it with the slot's other rows."""
+        out = {}
+        for key, st in states.items():
+            if _is_paged(st) and "summary_table" in st:
+                out[key] = {**st, "summary_table": st["summary_table"]
+                            .at[slot[0], col[0]].set(bid[0])}
             else:
                 out[key] = st
         return out
@@ -1666,7 +1789,7 @@ class DecodeScheduler:
         d = dst[0]
         out = {}
         for key, st in states.items():
-            if isinstance(st, dict) and "k_pages" in st:
+            if _is_paged(st):
                 # scale pages (int8 KV mode) duplicate with their values
                 out[key] = {
                     k: (v.at[d].set(v[s]) if k in PAGE_KEYS else v)
@@ -1688,7 +1811,7 @@ class DecodeScheduler:
         b = bid[0]
         out = {}
         for key, st in states.items():
-            if isinstance(st, dict) and "k_pages" in st:
+            if _is_paged(st):
                 out[key] = {
                     pk: jax.lax.dynamic_index_in_dim(
                         st[pk], b, axis=0, keepdims=False)
@@ -1702,7 +1825,7 @@ class DecodeScheduler:
         b = bid[0]
         out = {}
         for key, st in states.items():
-            if isinstance(st, dict) and "k_pages" in st and key in rows:
+            if _is_paged(st) and key in rows:
                 st2 = dict(st)
                 for pk, row in rows[key].items():
                     st2[pk] = jax.lax.dynamic_update_index_in_dim(
@@ -1803,7 +1926,24 @@ class DecodeScheduler:
 
     # -- paged mode: block tables, lazy alloc, COW, preempt-and-swap -------
     def _blocks_for(self, positions: int) -> int:
+        """LOGICAL blocks of ``positions``: the block table's width."""
         return -(-positions // self.kv_block)
+
+    def _blocks_held(self, depth: int) -> int:
+        """Pool blocks a request holds with ``depth`` positions cached, as
+        its attention layers say (`blocks_needed`): the logical count for a
+        cache of one row per position, fewer where pages are recycled."""
+        return self._attn_impl.blocks_needed(depth, self.kv_block)
+
+    def _blocks_peak(self, depth: int) -> int:
+        """The most a request holds on its way to ``depth`` positions: what
+        admission and the pool-size check reserve. Where windows roll, the
+        peak stands at the close of the last whole window."""
+        held = self._blocks_held(depth)
+        if self._eva is not None and depth > self._eva[0]:
+            window = self._eva[0]
+            held = max(held, self._blocks_held((depth - 1) // window * window))
+        return held
 
     def _table_for(self, max_pos: int) -> np.ndarray:
         """The host table sliced to the pow2 bucket covering ``max_pos``
@@ -1838,6 +1978,9 @@ class DecodeScheduler:
         — the lazy allocation of the paged layout: a block is claimed
         only when ``pos`` is about to cross into it. False means ``seq``
         was preempted by its own allocation (see _alloc_or_preempt)."""
+        if self._eva is not None and \
+                not self._roll_window(slot, seq, upto_pos):
+            return False
         need = self._blocks_for(upto_pos)
         added = 0
         while len(seq.block_ids) < need:
@@ -1856,6 +1999,48 @@ class DecodeScheduler:
                 "block_alloc", track=self._slot_tracks[slot],
                 args={"request": seq.handle.request_id, "blocks": added,
                       "free": self.pool.free_blocks})
+        return True
+
+    def _roll_window(self, slot: int, seq: _ActiveSeq,
+                     upto_pos: int) -> bool:
+        """The page lifetimes of a net that recycles (EVA), before the
+        write of positions up to ``upto_pos``, all of one window (the
+        chunk rule of `_pick_chunk`). Exact blocks of the windows that
+        closed go back to the free list BEFORE the next claim needs one:
+        no later query reads them, their chunks' summaries were written as
+        their rows were. Then a summary page is claimed for every
+        ``kv_block`` chunks begun; those live as long as the request.
+        False means ``seq`` was preempted by its own claim."""
+        window, chunk = self._eva
+        per_window = window // self.kv_block
+        first = (upto_pos - 1) // window * per_window
+        if first > seq.rolled:
+            rid = seq.handle.request_id
+            with self.profiler.nested("roll"):
+                if self.tracer.enabled:
+                    self.tracer.begin(
+                        "window_roll", track=self._sched_track,
+                        args={"request": rid,
+                              "window": (upto_pos - 1) // window,
+                              "blocks_freed": first - seq.rolled})
+                for j in range(seq.rolled, first):
+                    self.pool.free_block(seq.block_ids[j])
+                    seq.block_ids[j] = SCRATCH_BLOCK
+                self._table[slot, seq.rolled:first] = SCRATCH_BLOCK
+                ledger_note("pool_block", rid, seq.rolled - first)
+                self._m_eva_rolled.inc((first - seq.rolled) // per_window)
+                self._m_eva_recycled.inc(first - seq.rolled)
+                seq.rolled = first
+                self.tracer.end("window_roll", track=self._sched_track)
+        need = -(-upto_pos // (chunk * self.kv_block))
+        while len(seq.summary_ids) < need:
+            bid = self._alloc_or_preempt(slot, seq)
+            if bid is None:
+                return False
+            self._states = self._jsumtab(
+                self._states, self._dev_index(slot),
+                self._dev_index(len(seq.summary_ids)), self._dev_index(bid))
+            seq.summary_ids.append(bid)
         return True
 
     def _ensure_writable(self, slot: int, seq: _ActiveSeq,
@@ -1928,7 +2113,8 @@ class DecodeScheduler:
             tr.instant("preempt", track=self._slot_tracks[slot],
                        args={"request": h.request_id,
                              "blocks_released": sum(
-                                 1 for sh in seq.shared if not sh),
+                                 1 for sh in seq.shared[seq.rolled:]
+                                 if not sh) + len(seq.summary_ids),
                              "tokens_done": len(h.tokens)})
             # the swap gap on the request track: everything between
             # "preempt" and the matching "resume" is time the request
@@ -1963,14 +2149,20 @@ class DecodeScheduler:
         and reset its table row to scratch. ``keep``: ids adopted by the
         trie at publish (ownership already transferred)."""
         freed = 0
-        for bid, sh in zip(seq.block_ids, seq.shared):
+        for bid, sh in zip(seq.block_ids[seq.rolled:],
+                           seq.shared[seq.rolled:]):
             if not sh and bid not in keep:
                 self.pool.free_block(bid)
                 freed += 1
+        for bid in seq.summary_ids:
+            self.pool.free_block(bid)
+        freed += len(seq.summary_ids)
         if freed:
             ledger_note("pool_block", seq.handle.request_id, -freed)
         seq.block_ids = []
         seq.shared = []
+        seq.summary_ids = []
+        seq.rolled = 0
         self._table[slot, :] = SCRATCH_BLOCK
 
     def _try_restore_paged(self, slot: int, seq: _ActiveSeq) -> None:
@@ -1982,6 +2174,8 @@ class DecodeScheduler:
         prompt token is then re-fed to produce the first output
         distribution, and its write copy-on-writes the final shared
         block (`_ensure_writable`)."""
+        if self._eva is not None:
+            return  # nothing was published (`_publish_paged`): no lookup
         B = self.pool.block
         self._m_prefix_lookups.inc()
         self._m_prefix_lookup_tokens.inc(len(seq.prompt))
@@ -2048,6 +2242,13 @@ class DecodeScheduler:
         one) are skipped and freed normally."""
         B = self.pool.block
         n_full = len(seq.prompt) // B
+        if self._eva is not None:
+            # the prompt's pages were recycled as its windows closed, and
+            # what is left stands for this request's own window: the trie
+            # adopts nothing, and says so
+            if n_full:
+                self._m_publish_skipped.inc()
+            return frozenset()
         if n_full < 1 or n_full > len(seq.block_ids):
             return frozenset()
         adopted = frozenset(self.pool.adopt(
@@ -2170,7 +2371,10 @@ class DecodeScheduler:
             # pool-bytes admission: a prompt is rejected only when it
             # cannot fit the WHOLE pool — there is no per-slot stripe to
             # outgrow, so "too long" means more blocks than exist
-            blocks_needed = self._blocks_for(needed)
+            # the most it ever holds, and its block table's width (the
+            # same number unless its layers recycle pages)
+            blocks_needed = max(self._blocks_peak(needed),
+                                self._blocks_for(needed))
             if blocks_needed > self.pool.capacity_blocks:
                 self._m_rejected.inc()
                 self.tracer.instant("reject", req=rid, args={
@@ -2468,7 +2672,7 @@ class DecodeScheduler:
         if reclaim_memo[0] is None:
             reclaim_memo[0] = self.pool.reclaimable_blocks()
         return (reclaim_memo[0] - pending_blocks
-                >= self._blocks_for(len(seq.prompt)))
+                >= self._blocks_peak(len(seq.prompt)))
 
     def _admit(self) -> None:
         admitted: List[Tuple[int, _ActiveSeq]] = []
@@ -2479,7 +2683,7 @@ class DecodeScheduler:
             # resident slots' outstanding prefill claims (scheduler-
             # thread-only reads, same discipline as _step_once)
             pending_blocks = sum(
-                max(0, self._blocks_for(len(s.prompt)) - len(s.block_ids))
+                max(0, self._blocks_peak(len(s.prompt)) - s.blocks_held)
                 for s in self._slots if s is not None)  # graftlint: disable=CC004
         with self._cond:
             blocked = False
@@ -2518,7 +2722,7 @@ class DecodeScheduler:
                     self._slots[i] = seq
                     ledger_note("engine_slot", seq.handle.request_id, +1)
                     if self.paged:
-                        pending_blocks += self._blocks_for(len(seq.prompt))
+                        pending_blocks += self._blocks_peak(len(seq.prompt))
                     if not seq.resumed:
                         self._m_seqs.inc()
                     admitted.append((i, seq))
@@ -3310,6 +3514,13 @@ class DecodeScheduler:
                 table = self._table_for(max(s.written + 1
                                             for _, s in fed))
                 prof.count("decode", table.shape[1])
+                if self._eva is not None:
+                    window, chunk = self._eva
+                    at = [s.written for _, s in fed if s.sampling]
+                    self._m_eva_rows_exact.inc(
+                        sum(t % window + 1 for t in at))
+                    self._m_eva_rows_summary.inc(
+                        sum(t // window for t in at) * (window // chunk))
                 if mstate is not None:
                     probs, new_states = self._jstep_m(
                         self._params, self._variables,
@@ -3599,6 +3810,9 @@ class DecodeScheduler:
                         self._dev_array(np.zeros((b,), np.int32)),
                         no_real, table, self._states)
             self._states = self._jsetpos(self._states, slot0, slot0)
+            if self._jsumtab is not None:  # slot 0, block 0 -> scratch
+                self._states = self._jsumtab(self._states, slot0, slot0,
+                                             slot0)
             self._states = self._jcow(
                 self._states, self._dev_index(SCRATCH_BLOCK),
                 self._dev_index(SCRATCH_BLOCK))
@@ -3817,7 +4031,7 @@ class DecodeScheduler:
         quant = self.kv_dtype == "int8"
         heads = set()
         for _, impl in self._impl_items():
-            if type(impl).__name__ == "SelfAttentionLayerImpl":
+            if _carries_kv_cache(impl):
                 H = int(impl.conf.n_heads)
                 heads.add((impl._kv_heads() // self.tp, H // self.tp,
                            int(impl.conf.n_out) // H))
@@ -3872,7 +4086,7 @@ class DecodeScheduler:
                 "fed": seq.fed, "written": seq.written,
                 "tokens_out": len(h.tokens),
                 "max_new_tokens": h.max_new_tokens,
-                "blocks": len(seq.block_ids),
+                "blocks": seq.blocks_held,
                 "resumed": seq.resumed,
             })
         out = {

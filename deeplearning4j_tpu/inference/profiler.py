@@ -11,7 +11,8 @@ always-on, floor-gated overhead). Three pieces:
 **Step-phase profiler** (:class:`StepPhaseProfiler`). The scheduler loop
 names each phase of an iteration as it BEGINS (:data:`PHASES`: batch
 assembly ``admit``, the prefill chunk's ``prefill_launch``, draft
-rounds, pool ops + candidate assembly ``pool``, ``decode_launch``,
+rounds, pool ops + candidate assembly ``pool``, a closed window's pages
+going back to the pool ``roll`` (`nested`), ``decode_launch``,
 host-side acceptance ``accept``, speculative ``verify``, the
 metric/trace ``flush``). Naming rule: a phase in which the scheduler's
 thread is blocked until the device is done ends in ``_wait``; a phase
@@ -90,8 +91,8 @@ def burn_verdict(fast: float, slow: float, fast_burn: float = 6.0,
 # result is copied device -> host. Readers (benchmark/metrics/) go by
 # those two suffixes, so a new phase that blocks or copies takes one.
 PHASES = ("admit", "prefill_launch", "prefill_wait", "prefill_read",
-          "draft", "pool", "decode_launch", "decode_wait", "decode_read",
-          "accept", "verify", "flush")
+          "draft", "pool", "roll", "decode_launch", "decode_wait",
+          "decode_read", "accept", "verify", "flush")
 _READ_OF = {p: p[:-len("_wait")] + "_read" for p in PHASES
             if p.endswith("_wait")}
 _NO_SPAN = contextlib.nullcontext()
@@ -413,6 +414,19 @@ class StepPhaseProfiler:
         self._phase = phase
         self._t_phase = now
         self._annotate(phase)
+
+    @contextlib.contextmanager
+    def nested(self, phase: str):
+        """Host work that happens INSIDE another phase and is booked under
+        its own name (``roll``: a closed window's pages going back to the
+        pool, wherever a block is claimed): the open phase is suspended and
+        opens again after."""
+        outer = self._phase
+        self.begin(phase)
+        try:
+            yield
+        finally:
+            self.begin(outer)
 
     def ready(self) -> None:
         """The device is done: the open ``*_wait`` phase ends and its

@@ -98,6 +98,10 @@ class RnnOutputLayer(FeedForwardLayer):
     """Per-timestep output layer (reference nn/conf/layers/RnnOutputLayer.java)."""
 
     loss: str = "mcxent"
+    # dtype the logits (and what follows them) are computed in, whatever
+    # the net's compute dtype: "float32" under a bfloat16 net is a small
+    # vocabulary's `fp32_logits`. None = the net's compute dtype
+    logits_dtype: Optional[str] = None
 
     def get_output_type(self, input_type: InputType) -> InputType:
         ts = input_type.timesteps if isinstance(input_type, RecurrentInputType) else None
@@ -205,9 +209,15 @@ class LayerNormalization(FeedForwardLayer):
     """Layer norm over the trailing feature axis (no 0.4-era reference
     counterpart — added alongside SelfAttentionLayer as the transformer
     building block; normalizes each example independently, so it is
-    batch-size- and sequence-parallel-friendly on TPU)."""
+    batch-size- and sequence-parallel-friendly on TPU).
+
+    ``rms``: RMSNorm — no mean is subtracted and there is no bias, only the
+    gain. ``unit_offset``: the stored gain is an offset from one (the layer
+    multiplies by ``1 + gain`` and the gain starts at zero)."""
 
     eps: float = 1e-5
+    rms: bool = False
+    unit_offset: bool = False
 
     def set_n_in(self, input_type: InputType) -> None:
         if self.n_in is None:
@@ -331,6 +341,21 @@ class SelfAttentionLayer(FeedForwardLayer):
     def get_output_type(self, input_type: InputType) -> InputType:
         ts = input_type.timesteps if isinstance(input_type, RecurrentInputType) else None
         return InputType.recurrent(self.n_out, ts)
+
+
+@register
+@dataclass
+class EvaAttentionLayer(SelfAttentionLayer):
+    """EVA chunked linearized attention (Zheng et al. 2023,
+    arXiv:2302.04542) — see nn/layers/attention.py. A query attends exactly
+    over its own window of ``window_size`` positions and, in the same
+    softmax, over one summary key/value per completed chunk of
+    ``chunk_size`` positions of every earlier window. Causal, no biases;
+    ``window_size`` must be a multiple of ``chunk_size``."""
+
+    causal: bool = True
+    window_size: int = 2048
+    chunk_size: int = 16
 
 
 @dataclass

@@ -170,19 +170,23 @@ class SelfAttentionLayerImpl(BaseRecurrentImpl):
             o = o * mask[:, :, None, None].astype(o.dtype)
         return self._out(params, o, B, T), variables or {}
 
-    def _grouped_attention(self, q, k, v, *, causal, qpos0=0):
+    def _grouped_attention(self, q, k, v, *, causal, qpos0=0, valid=None):
         """Dense attention with q grouped over compact KV heads — THE single
         contraction for both the full forward (qpos0=0, L==T) and the
         KV-cached decode step (qpos0=cache position, L=cache capacity).
         q: [B, T, H, Dh]; k, v: [B, L, Hkv, Dh] -> [B, T, H, Dh].
         ``qpos0`` scalar, or [B] for per-row decode depths (slot scheduling:
-        each row's causal horizon is its own cache position)."""
+        each row's causal horizon is its own cache position). ``valid``
+        ([B or 1, T, L] bool) replaces the causal rule with the caller's
+        own (a layer whose rows are not one per position)."""
         B, T, H, Dh = q.shape
         L, Hkv = k.shape[1], k.shape[2]
         qg = q.reshape(B, T, Hkv, H // Hkv, Dh)
         s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k) / jnp.sqrt(
             jnp.asarray(Dh, q.dtype))
-        if causal:
+        if valid is not None:
+            valid = valid[:, None, None]
+        elif causal:
             qp = jnp.asarray(qpos0)
             if qp.ndim:  # [B] -> valid [B, T, L] -> [B, 1, 1, T, L]
                 valid = (jnp.arange(L)[None, None, :]
@@ -191,6 +195,7 @@ class SelfAttentionLayerImpl(BaseRecurrentImpl):
             else:
                 valid = (jnp.arange(L)[None, :]
                          <= qp + jnp.arange(T)[:, None])[None, None, None]
+        if valid is not None:
             s = jnp.where(valid, s.astype(jnp.float32),
                           jnp.finfo(jnp.float32).min)
         p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
@@ -260,6 +265,32 @@ class SelfAttentionLayerImpl(BaseRecurrentImpl):
                              pos + T)
         return y, {"k": kc, "v": vc, "pos": next_pos}
 
+    # -- what the serving engine asks of a layer that carries a paged cache
+    def blocks_needed(self, depth: int, block: int) -> int:
+        """Pool blocks a request holds once ``depth`` positions are cached:
+        one row a position, for as long as the request lives."""
+        return -(-depth // block)
+
+    def page_recycling(self):
+        """None: every page a request is given it keeps to its end, so its
+        prompt's pages may be shared through the prefix trie. A layer that
+        hands pages back while the request lives (`EvaAttentionLayerImpl`)
+        returns its geometry here, and the trie stands aside."""
+        return None
+
+    @staticmethod
+    def _page_of(table, p, Bk, wmask=None):
+        """(page, offset) of the rows ``p`` ([B, T] row indices; positions
+        here) through ``table`` ([B, nb]: logical block -> page) at ``Bk``
+        rows a page. Lanes ``wmask`` holds off, and rows beyond the table,
+        go to the scratch page."""
+        nb = table.shape[1]
+        blk = jnp.take_along_axis(table, jnp.minimum(p // Bk, nb - 1),
+                                  axis=1)
+        if wmask is not None:
+            blk = jnp.where(wmask, blk, 0)
+        return jnp.where(p // Bk < nb, blk, 0), p % Bk
+
     def _paged_step(self, params, x, state0, *, mask=None):
         """Paged-KV inference step (inference/kvpool.py, the ISSUE 6
         layout): K/V rows live in pool-wide page arrays
@@ -315,11 +346,9 @@ class SelfAttentionLayerImpl(BaseRecurrentImpl):
         overflow = (pos + T) > L
         q, k_new, v_new = self._qkv(params, x, pos0=pos)
         p = pos[:, None] + jnp.arange(T, dtype=pos.dtype)[None, :]  # [B, T]
-        blk = jnp.take_along_axis(table, jnp.minimum(p // Bk, nb - 1),
-                                  axis=1)
+        blk, off = self._page_of(table, p, Bk, wmask)
         if wmask is not None:
-            blk = jnp.where(wmask, blk, 0)  # masked lanes -> scratch page
-            # ...and ZERO their values: a masked row deeper than this
+            # ZERO the masked lanes' values: a masked row deeper than this
             # step's table bucket is output-poisoned (overflow NaN), and
             # the next layer's K/V projection of that NaN would land in
             # the scratch page — where `softmax_prob(0) * NaN = NaN`
@@ -329,8 +358,6 @@ class SelfAttentionLayerImpl(BaseRecurrentImpl):
             keep = wmask[..., None, None]
             k_new = jnp.where(keep, k_new, 0)
             v_new = jnp.where(keep, v_new, 0)
-        blk = jnp.where(p // Bk < nb, blk, 0)  # beyond-table -> scratch
-        off = p % Bk
         ks2 = vs2 = None
         if quantized:
             kq, ksc = quantize_kv_rows(k_new)   # ops/kvquant.py — the
@@ -378,3 +405,199 @@ class SelfAttentionLayerImpl(BaseRecurrentImpl):
             out_state["k_scales"] = ks2
             out_state["v_scales"] = vs2
         return y, out_state
+
+
+@register_impl("EvaAttentionLayer")
+class EvaAttentionLayerImpl(SelfAttentionLayerImpl):
+    """EVA chunked linearized attention (Zheng et al. 2023): the cache is
+    NOT one row per position. With ``W(t) = t // window`` and chunk ``c``
+    the positions ``[c*chunk, (c+1)*chunk)``, the query at ``t`` attends,
+    in ONE softmax, over
+
+      - the exact rotated keys/values of its own window, ``window*W(t) <=
+        n <= t``;
+      - one summary key and one summary value per chunk of every EARLIER
+        window, ``c < (window/chunk) * W(t)``: softmax-pooled over the
+        chunk's rows by two learned vectors per K/V head (`_summarize`).
+
+    No biases. Projections, RoPE and the grouped softmax are the parent's.
+
+    Serving (`_paged_step`): summary rows have the shape of K/V rows and
+    live in the same ``k_pages``/``v_pages`` under a table of their own
+    (``summary_table``: [B, blocks/chunk] page ids, carried in the state
+    and written by the engine when it claims a summary page). A page holds
+    ``block`` chunk summaries, i.e. ``chunk`` exact blocks' worth of
+    positions, so a closed window keeps ``window/(block*chunk)`` pages where
+    it held ``window/block``: the engine hands the exact pages back at the
+    window's roll (`blocks_needed`, `page_recycling`). The program's shape
+    is still the logical table's bucket ``nb``: the exact view is the
+    ``window/block`` blocks from block ``(window/block)*W`` and the summary
+    view the first ``ceil(nb/chunk)`` summary pages, both static in ``nb``.
+    A step never branches on a window or chunk boundary: the chunks its rows
+    fall in are summarised again from the pages after the write (at T=1 the
+    current chunk's <= ``chunk`` rows) and overwritten. T > 1 must be a
+    multiple of ``chunk`` starting on a chunk boundary and inside one
+    window: the engine's chunk rule (`DecodeScheduler._pick_chunk`)."""
+
+    def init_params(self, key, dtype=jnp.float32):
+        p = super().init_params(key, dtype)
+        del p["b"]
+        Dh = self.conf.n_out // self.conf.n_heads
+        for i, name in enumerate(("mu", "phi")):
+            p[name] = (jax.random.normal(jax.random.fold_in(key, i),
+                                         (self._kv_heads(), Dh), jnp.float32)
+                       * Dh ** -0.5).astype(dtype)
+        return p
+
+    def _out(self, params, o, B, T):
+        return self.activation_fn()(jnp.einsum(
+            "btm,mn->btn", o.reshape(B, T, self.conf.n_out), params["Wo"]))
+
+    def _geometry(self):
+        Wn, C = int(self.conf.window_size), int(self.conf.chunk_size)
+        if C < 1 or Wn % C:
+            raise ValueError(f"window_size={Wn} must be a multiple of "
+                             f"chunk_size={C}")
+        return Wn, C
+
+    def page_recycling(self):
+        return self._geometry()
+
+    def blocks_needed(self, depth: int, block: int) -> int:
+        """Exact blocks of the open window, plus a summary page per
+        ``block`` chunks begun (the open window's included)."""
+        if depth <= 0:
+            return 0
+        Wn, C = self._geometry()
+        w0 = (depth - 1) // Wn * Wn
+        return -(-(depth - w0) // block) + -(-depth // (C * block))
+
+    def _summarize(self, params, k, v, ok):
+        """Chunk summaries. k, v: [B, chunks, chunk, Hkv, Dh] rotated rows;
+        ok: [B, chunks, chunk] rows that exist. Per head, with s = 1/sqrt(Dh):
+        k~ = sum_n softmax_n(s mu.k_n) k_n, v~ = sum_n softmax_n(s phi.k_n)
+        v_n -> [B, chunks, Hkv, Dh] each. (EVA's control variate with one
+        deterministic proposal per head; the two logits as recalled from
+        the EvaByte release, `benchmark/configs/evabyte-d16.json`.)"""
+        s = 1.0 / jnp.sqrt(jnp.float32(k.shape[-1]))
+        off = jnp.where(ok, 0.0, jnp.finfo(jnp.float32).min)[..., None]
+
+        def pool(w, rows):
+            lg = jnp.einsum("bcnhd,hd->bcnh", k, params[w])
+            pr = jax.nn.softmax(lg.astype(jnp.float32) * s + off, axis=2)
+            return jnp.einsum("bcnh,bcnhd->bchd", pr.astype(k.dtype), rows)
+
+        with jax.named_scope("eva_summarize"):
+            return pool("mu", k), pool("phi", v)
+
+    def forward(self, params, x, *, train=False, rng=None, variables=None,
+                mask=None):
+        """The full-sequence function (training, scoring, the tests'
+        comparison with the plain reference)."""
+        x = self._dropout(x, train, rng)
+        B, T, _ = x.shape
+        Wn, C = self._geometry()
+        q, k, v = self._qkv(params, x)
+        nC = -(-T // C)
+
+        def chunked(a):
+            a = jnp.pad(a, ((0, 0), (0, nC * C - T), (0, 0), (0, 0)))
+            return a.reshape((B, nC, C) + a.shape[2:])
+
+        ok = (jnp.arange(nC * C) < T).reshape(1, nC, C)
+        ks, vs = self._summarize(params, chunked(k), chunked(v),
+                                 jnp.broadcast_to(ok, (B, nC, C)))
+        t = jnp.arange(T)
+        w0 = t // Wn * Wn
+        exact = (t[None, :] <= t[:, None]) & (t[None, :] >= w0[:, None])
+        summary = jnp.arange(nC)[None, :] < (w0 // C)[:, None]
+        with jax.named_scope("eva_attention"):
+            o = self._grouped_attention(
+                q, jnp.concatenate([k, ks], 1), jnp.concatenate([v, vs], 1),
+                causal=True,
+                valid=jnp.concatenate([exact, summary], 1)[None])
+        if mask is not None:
+            o = o * mask[:, :, None, None].astype(o.dtype)
+        return self._out(params, o, B, T), variables or {}
+
+    def forward_with_state(self, params, x, state0, *, train=False, rng=None,
+                           mask=None):
+        if not train and state0 is not None and "k_pages" not in state0:
+            raise NotImplementedError(
+                "EvaAttentionLayer streams through the paged pool only "
+                "(DecodeScheduler(kv_pool_mb=...)): a contiguous stripe "
+                "has no rows for the chunk summaries")
+        return super().forward_with_state(params, x, state0, train=train,
+                                          rng=rng, mask=mask)
+
+    def _paged_step(self, params, x, state0, *, mask=None):
+        """See the class docstring. ``table`` [B, nb] maps the logical
+        blocks of the OPEN window to pages (closed windows' entries are
+        scratch); ``wmask`` as in the parent."""
+        Wn, C = self._geometry()
+        B, T, _ = x.shape
+        pos, table = state0["pos"], state0["table"]
+        kp, vp = state0["k_pages"], state0["v_pages"]
+        Bk, nb = kp.shape[1], table.shape[1]
+        if Wn % Bk or Bk % C or (T > 1 and T % C):
+            raise ValueError(
+                f"EVA paging needs chunk_size={C} | kv_block={Bk} | "
+                f"window_size={Wn}, and chunks of a multiple of {C} "
+                f"tokens (got {T})")
+        ns = -(-nb // C)
+        stable = state0["summary_table"]
+        wmask = state0.get("wmask")
+        if wmask is None:
+            wmask = jnp.ones((B, T), bool)
+        overflow = (pos + T) > nb * Bk
+        q, k_new, v_new = self._qkv(params, x, pos0=pos)
+        i32 = lambda n: jnp.arange(n, dtype=pos.dtype)
+        p = pos[:, None] + i32(T)[None, :]                        # [B, T]
+        blk, off = self._page_of(table, p, Bk, wmask)
+        keep = wmask[..., None, None]  # pages hold finite rows only
+        kp2 = kp.at[blk, off].set(jnp.where(keep, k_new, 0))
+        vp2 = vp.at[blk, off].set(jnp.where(keep, v_new, 0))
+        # the chunks these rows fall in, summarised from the pages
+        nC = max(1, T // C)
+        last = pos + jnp.sum(wmask, axis=1, dtype=pos.dtype) - 1  # [B]
+        chunk = pos[:, None] // C + i32(nC)[None, :]              # [B, nC]
+        rows = chunk[:, :, None] * C + i32(C)[None, None, :]      # [B,nC,C]
+        rblk, roff = self._page_of(table, rows.reshape(B, nC * C), Bk)
+        tail = kp.shape[2:]
+        ks, vs = self._summarize(
+            params, kp2[rblk, roff].reshape((B, nC, C) + tail),
+            vp2[rblk, roff].reshape((B, nC, C) + tail),
+            rows <= last[:, None, None])
+        # a chunk is written where this step gave it a real row: never by a
+        # masked slot, whose table may name pages that are another's by now
+        begun = (chunk * C <= last[:, None]) & (last >= pos)[:, None]
+        sblk, soff = self._page_of(stable[:, :ns], chunk, Bk, begun)
+        kp2 = kp2.at[sblk, soff].set(jnp.where(begun[..., None, None], ks, 0))
+        vp2 = vp2.at[sblk, soff].set(jnp.where(begun[..., None, None], vs, 0))
+        # one gather: the open window's blocks, then the summary pages
+        E = min(Wn // Bk, nb)
+        W = pos // Wn                                             # [B]
+        epages = jnp.take_along_axis(
+            table, jnp.minimum(W[:, None] * (Wn // Bk) + i32(E)[None, :],
+                               nb - 1), axis=1)
+        pages = jnp.concatenate([epages, stable[:, :ns]], axis=1)
+        L = (E + ns) * Bk
+        kc = kp2[pages].reshape((B, L) + tail)
+        vc = vp2[pages].reshape((B, L) + tail)
+        exact = (W[:, None] * Wn + i32(E * Bk)[None, :])[:, None, :] \
+            <= p[:, :, None]                                      # [B,T,E*Bk]
+        summary = i32(ns * Bk)[None, :] < (W * (Wn // C))[:, None]
+        valid = jnp.concatenate(
+            [exact, jnp.broadcast_to(summary[:, None, :], (B, T, ns * Bk))],
+            axis=-1)
+        with jax.named_scope("eva_attention"):
+            o = self._grouped_attention(q, kc, vc, causal=True, valid=valid)
+        if mask is not None:
+            o = o * mask[:, :, None, None].astype(o.dtype)
+        y = self._out(params, o, B, T)
+        y = jnp.where(overflow[:, None, None],
+                      jnp.asarray(jnp.nan, y.dtype), y)
+        next_pos = jnp.where(overflow, jnp.asarray(1 << 30, jnp.int32),
+                             pos + T)
+        return y, {"k_pages": kp2, "v_pages": vp2, "pos": next_pos,
+                   "summary_table": stable}

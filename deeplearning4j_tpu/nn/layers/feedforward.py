@@ -72,7 +72,10 @@ class RnnOutputLayerImpl(_LinearLayer):
     def forward_with_preout(self, params, x, *, train=False, rng=None,
                             variables=None, mask=None):
         x = self._dropout(x, train, rng)
-        z = jnp.einsum("btf,fo->bto", x, params["W"]) + params["b"]
+        dt = getattr(self.conf, "logits_dtype", None)
+        z = jnp.einsum("btf,fo->bto", x, params["W"],
+                       preferred_element_type=dt and jnp.dtype(dt))
+        z = z + params["b"].astype(z.dtype)
         y = self.activation_fn()(z)
         if mask is not None:
             y = y * mask[..., None].astype(y.dtype)

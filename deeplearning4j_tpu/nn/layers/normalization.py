@@ -118,18 +118,29 @@ class LayerNormalizationImpl(LayerImpl):
     gain/bias (transformer building block — see conf LayerNormalization)."""
 
     def init_params(self, key, dtype=jnp.float32):
-        n = self.conf.n_out or self.conf.n_in
-        return {"gain": jnp.ones((n,), dtype),
-                "beta": jnp.zeros((n,), dtype)}
+        conf = self.conf
+        n = conf.n_out or conf.n_in
+        gain = (jnp.zeros if getattr(conf, "unit_offset", False)
+                else jnp.ones)((n,), dtype)
+        if getattr(conf, "rms", False):
+            return {"gain": gain}
+        return {"gain": gain, "beta": jnp.zeros((n,), dtype)}
 
     def forward(self, params, x, *, train=False, rng=None, variables=None,
                 mask=None):
         conf = self.conf
         x = self._dropout(x, train, rng)
-        mean = jnp.mean(x, axis=-1, keepdims=True)
-        var = jnp.var(x, axis=-1, keepdims=True)
-        y = (x - mean) * jax.lax.rsqrt(var + jnp.asarray(conf.eps, x.dtype))
-        y = y * params["gain"] + params["beta"]
+        eps = jnp.asarray(conf.eps, x.dtype)
+        gain = params["gain"]
+        if getattr(conf, "unit_offset", False):
+            gain = 1 + gain
+        if getattr(conf, "rms", False):
+            y = x * jax.lax.rsqrt(
+                jnp.mean(x * x, axis=-1, keepdims=True) + eps) * gain
+        else:
+            mean = jnp.mean(x, axis=-1, keepdims=True)
+            var = jnp.var(x, axis=-1, keepdims=True)
+            y = (x - mean) * jax.lax.rsqrt(var + eps) * gain + params["beta"]
         if conf.activation not in (None, "identity", "linear"):
             y = self.activation_fn()(y)
         return y, variables or {}

@@ -1,0 +1,140 @@
+"""The benchmark's family loader, in tier-1 (ISSUE 28 asked for it, ISSUE 30
+brings it with the second real family): every configuration of
+`BENCHMARK.json` resolves to its family's four files, an unknown `model_type`
+names the directory to add, a family lacking a file or a function fails at
+load and not in mid-run, and `evabyte-d16.json` is the published
+configuration key for key but for what it lists as reduced."""
+import json
+import sys
+from pathlib import Path
+
+import pytest
+
+REPO = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(REPO))
+
+from benchmark.harness import family  # noqa: E402
+
+BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
+EVABYTE = REPO / "benchmark" / "families" / "evabyte"
+
+
+@pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
+def test_every_configuration_resolves_to_its_family(entry):
+    cfg = json.loads((REPO / entry["file"]).read_text())
+    fam = family.load(REPO, cfg)
+    assert fam.name == cfg["model_type"]
+    assert fam.path == REPO / "benchmark" / "families" / cfg["model_type"]
+    for part, functions in family.PARTS.items():
+        for f in functions:
+            assert callable(getattr(getattr(fam, part), f)), (part, f)
+
+
+def test_the_benchmark_has_two_families():
+    types = {json.loads((REPO / c["file"]).read_text())["model_type"]
+             for c in BENCH["configs"]}
+    assert types == {"starcoder2", "evabyte"}
+    assert {p.name for p in (REPO / "benchmark" / "families").iterdir()
+            if p.is_dir() and p.name != "__pycache__"} == types
+
+
+def test_an_unknown_model_type_names_the_directory_to_add():
+    with pytest.raises(KeyError, match="benchmark/families/afmoe/"):
+        family.load(REPO, {"model_type": "afmoe"})
+    with pytest.raises(KeyError, match="model_type"):
+        family.load(REPO, {"hidden_size": 64})
+
+
+@pytest.mark.parametrize("part", sorted(family.PARTS))
+def test_a_family_lacking_a_file_or_a_function_fails_at_load(tmp_path, part):
+    here = tmp_path / "benchmark" / "families" / "half"
+    here.mkdir(parents=True)
+    for p in family.PARTS:
+        if p != part:
+            (here / f"{p}.py").write_text((EVABYTE / f"{p}.py").read_text())
+    with pytest.raises(KeyError, match=f"families/half/{part}.py"):
+        family.load(tmp_path, {"model_type": "half"})
+    gone = family.PARTS[part][-1]
+    (here / f"{part}.py").write_text(
+        (EVABYTE / f"{part}.py").read_text().replace(
+            f"def {gone}(", f"def _{gone}("))
+    with pytest.raises(KeyError, match=gone):
+        family.load(tmp_path, {"model_type": "half"})
+
+
+def test_evabyte_d16_is_the_published_configuration_but_for_reduced():
+    entry = next(c for c in BENCH["configs"] if c["name"] == "evabyte-d16")
+    path = REPO / entry["file"]
+    cfg = json.loads(path.read_text())
+    source = json.loads(path.with_suffix(".published.json").read_text())
+    assert source.pop("source") == entry["source"] == cfg["source"]
+    assert cfg["reduced"] == entry["reduced"] == \
+        ["num_hidden_layers", "num_pred_heads"]
+    assert cfg["published"] == {"num_hidden_layers": 32, "num_pred_heads": 8}
+    assert (cfg["num_hidden_layers"], cfg["num_pred_heads"]) == (16, 1)
+    for key, value in source.items():
+        if key in cfg["reduced"]:
+            assert cfg["published"][key] == value and cfg[key] < value, key
+        else:
+            assert cfg[key] == value, key
+    # no width among them, and the widths themselves
+    assert (cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"],
+            cfg["num_attention_heads"], cfg["window_size"],
+            cfg["chunk_size"]) == (4096, 11008, 320, 32, 2048, 16)
+    assert cfg["deployment"].startswith("the first stage of a two-chip")
+    assert any("mu_h" in a and "phi_h" in a for a in cfg["assumed"])
+    assert any("fp32_skip_add" in d for d in cfg["departures"])
+
+
+def test_evabyte_s_work_counts_what_a_query_attends_over():
+    """48 pages where a full cache holds 256 is the point of the cell: the
+    work counted per decode byte is the compressed read, not the depth."""
+    cfg = json.loads((REPO / "benchmark/configs/evabyte-d16.json").read_text())
+    work = family.load(REPO, cfg).work
+    assert work.rows_attended(cfg, 0) == (1, 0)
+    assert work.rows_attended(cfg, 2047) == (2048, 0)
+    assert work.rows_attended(cfg, 2048) == (1, 128)
+    assert work.rows_attended(cfg, 16383) == (2048, 7 * 128)
+    assert work.kv_bytes_per_position(cfg) == 16 * 2 * 4096 * 2
+    assert work.param_count(cfg) == pytest.approx(3.24e9, rel=0.01)
+    kvb = work.kv_bytes_per_position(cfg)
+    _, b0 = work.decode_step(cfg, [])
+    f1, b1 = work.decode_step(cfg, [16384])
+    assert b1 - b0 == (2048 + 896 + 16 + 2) * kvb + 4096 * 2
+    # a chunk's queries, each at its own depth; the cache read once
+    fc, bc = work.prefill_chunk(cfg, 256, 2048, False)
+    per_tok = 2 * 16 * work.layer_matmul_params(cfg)
+    attn = sum(4 * 16 * 4096 * (i + 1 + 128) for i in range(256))
+    assert fc == 256 * per_tok + attn + 16 * 8 * 4096 * 256
+
+
+def test_a_toy_evabyte_cell_runs_to_a_correct_line(tmp_path):
+    """The command itself, on the CPU at the tests' small size, in a scratch
+    root (`benchmark/tests/util.py`, the recipe a new cell follows): the
+    second real family through `run.py` to a `correct` line, with the three
+    readers this PR adds finding what they read and `kv_pool_peak_pct`, which
+    would count a full cache's blocks, not reported."""
+    import os
+    import subprocess
+    from benchmark.tests.util import make_root
+    sys.path.insert(0, str(Path(__file__).resolve().parent))
+    from eva_util import CFG
+    root = make_root(tmp_path, config="tiny-evabyte", cell="toy.eva",
+                     config_keys=CFG, like="evabyte-d16.longdoc-complete")
+    p = subprocess.run(
+        [sys.executable, str(root / "benchmark" / "run.py"), "--bench-root",
+         str(root), "--workload", "toy.eva", "--seconds", "3", "--seed",
+         "3000000007", "--rehearse-cpu", "--trace", "1"],
+        capture_output=True, text=True, cwd=str(root), timeout=900,
+        env={**os.environ, "PYTHONPATH": str(REPO)})
+    assert p.returncode == 0, p.stderr[-3000:]
+    line = json.loads(p.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["failed"] == 0
+    for name, (value, limit) in line["checks"].items():
+        assert value <= limit, name
+    m = {k: v["value"] for k, v in line["metrics"].items()}
+    assert 0 < m["eva_pool_peak_pct"] <= 100
+    assert m["eva_roll_ms"] > 0
+    assert 0 < m["eva_summary_row_share"] < 100
+    assert "kv_pool_peak_pct" not in m and "sched_iter_ms" in m
+    assert line["harness"]["phase_ms_per_iter"]["roll"] > 0

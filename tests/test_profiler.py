@@ -459,8 +459,8 @@ def test_step_phase_profiler_unit():
     dec = prof.decomposition()
     assert set(dec) == set(
         ("admit", "prefill_launch", "prefill_wait", "prefill_read",
-         "draft", "pool", "decode_launch", "decode_wait", "decode_read",
-         "accept", "verify", "flush"))
+         "draft", "pool", "roll", "decode_launch", "decode_wait",
+         "decode_read", "accept", "verify", "flush"))
     assert abs(sum(p["share"] for p in dec.values()) - 1.0) < 0.01
     assert prof.family_dispatches == {"decode": 4, "prefill": 4}
     assert prof.flops_total == pytest.approx(4 * 1100.0)

@@ -150,8 +150,11 @@ def test_phases_partition_the_iteration(monkeypatch):
         for phase in ("prefill_launch", "prefill_wait"):
             prof.begin(phase)
         prof.ready()                         # prefill_wait -> prefill_read
-        for phase in ("accept", "draft", "pool", "decode_launch",
-                      "decode_wait"):
+        for phase in ("accept", "draft", "pool"):
+            prof.begin(phase)
+        with prof.nested("roll"):            # pool -> roll -> pool again
+            pass
+        for phase in ("decode_launch", "decode_wait"):
             prof.begin(phase)
         prof.ready()                         # decode_wait -> decode_read
         for phase in ("accept", "verify", "flush"):
@@ -160,8 +163,9 @@ def test_phases_partition_the_iteration(monkeypatch):
         walls += clock.now - t_begin         # iter_end read the clock once
     ph = prof.phase_seconds
     assert sum(ph.values()) == pytest.approx(walls, rel=1e-9)
-    assert ph["accept"] == pytest.approx(0.006)      # twice an iteration
-    assert all(ph[p] == pytest.approx(0.003) for p in ph if p != "accept")
+    twice = ("accept", "pool")                       # twice an iteration
+    assert all(ph[p] == pytest.approx(0.006) for p in twice)
+    assert all(ph[p] == pytest.approx(0.003) for p in ph if p not in twice)
     # the benchmark's three readers go by the two suffixes
     assert {p for p in ph if p.endswith("_wait")} == {"prefill_wait",
                                                       "decode_wait"}
