@@ -1195,6 +1195,19 @@ class DecodeScheduler:
                 "eva_rows_summary_total",
                 help="chunk-summary rows of closed windows attended by "
                      "decode tokens")
+            # pages a decode dispatch names (its bucket's page list, every
+            # slot) and pages it reads: equal unless the fused read engages
+            self._m_eva_pages_bucket = m.counter(
+                "eva_pages_bucket_total",
+                help="pages in the page lists of decode dispatches: slots "
+                     "x (open-window pages + summary pages of the bucket)")
+            self._m_eva_pages_read = m.counter(
+                "eva_pages_read_total",
+                help="pages decode dispatches read: those holding a row a "
+                     "fed slot attends over where the fused read engages, "
+                     "else the bucket's")
+            self._eva_fused = self._attn_impl.fused_read_engages(
+                self.paged_kernel, 1, self._dtype, self.mesh)
             self._m_publish_skipped = m.counter(
                 "prefix_publish_skipped_total",
                 help="finished prompts the prefix trie did not adopt: "
@@ -3521,6 +3534,14 @@ class DecodeScheduler:
                         sum(t % window + 1 for t in at))
                     self._m_eva_rows_summary.inc(
                         sum(t // window for t in at) * (window // chunk))
+                    nb, bk = table.shape[1], self.kv_block
+                    named = self.n_slots * (min(window // bk, nb)
+                                            + -(-nb // chunk))
+                    self._m_eva_pages_bucket.inc(named)
+                    self._m_eva_pages_read.inc(sum(
+                        -(-(s.written % window + 1) // bk)
+                        + -(-(s.written // window * (window // chunk)) // bk)
+                        for _, s in fed) if self._eva_fused else named)
                 if mstate is not None:
                     probs, new_states = self._jstep_m(
                         self._params, self._variables,
@@ -4007,6 +4028,17 @@ class DecodeScheduler:
                "buckets": {}, "refused": {}, "declined": None,
                "execution": None}
         if not self.paged:
+            return out
+        if self._eva is not None:
+            # this layer's T=1 read is `ops.paged_read`, engaged by the
+            # layer's static rule and not through the seam's registry
+            out["engaged"] = self._eva_fused
+            out["buckets"] = {nb: "paged_read" if self._eva_fused else False
+                              for nb in self.table_buckets}
+            if self._eva_fused:
+                out["execution"] = ("compiled" if jax.default_backend()
+                                    == "tpu" else "interpreted")
+            self._m_paged_kernel.set(1 if self._eva_fused else 0)
             return out
         from ..ops import helpers as ophelpers
         if (self.paged_kernel == "off"

@@ -435,7 +435,12 @@ class EvaAttentionLayerImpl(SelfAttentionLayerImpl):
     view the first ``ceil(nb/chunk)`` summary pages, both static in ``nb``.
     A step never branches on a window or chunk boundary: the chunks its rows
     fall in are summarised again from the pages after the write (at T=1 the
-    current chunk's <= ``chunk`` rows) and overwritten. T > 1 must be a
+    current chunk's <= ``chunk`` rows) and overwritten. The read is over one
+    page list, ``[exact view | summary view]``, with the count of rows each
+    page holds for the query: at T=1 on a TPU a fused walk over the pages
+    whose count is above 0 (`fused_read_engages`,
+    `ops.paged_read.paged_read_attention`), else the list gathered whole at
+    the bucket's width with the counts as a mask. T > 1 must be a
     multiple of ``chunk`` starting on a chunk boundary and inside one
     window: the engine's chunk rule (`DecodeScheduler._pick_chunk`)."""
 
@@ -471,6 +476,19 @@ class EvaAttentionLayerImpl(SelfAttentionLayerImpl):
         Wn, C = self._geometry()
         w0 = (depth - 1) // Wn * Wn
         return -(-(depth - w0) // block) + -(-depth // (C * block))
+
+    @staticmethod
+    def fused_read_engages(mode, T, dtype, mesh=None) -> bool:
+        """Whether a paged step of ``T`` tokens reads its pages through
+        `ops.paged_read.paged_read_attention` and not through the gather at
+        the bucket's width: one query row a slot, bfloat16 or float32, no
+        ``tp`` mesh, ``paged_kernel`` not ``"off"``, and a TPU to compile
+        the kernel for (``"on"`` takes it anywhere, interpreted off the
+        TPU: the tests' way in). Asked by `_paged_step` when it is traced
+        and by the engine for `eva_pages_read_total`."""
+        return (mode != "off" and T == 1 and mesh is None
+                and jnp.dtype(dtype) in (jnp.bfloat16, jnp.float32)
+                and (mode == "on" or jax.default_backend() == "tpu"))
 
     def _summarize(self, params, k, v, ok):
         """Chunk summaries. k, v: [B, chunks, chunk, Hkv, Dh] rotated rows;
@@ -574,24 +592,41 @@ class EvaAttentionLayerImpl(SelfAttentionLayerImpl):
         sblk, soff = self._page_of(stable[:, :ns], chunk, Bk, begun)
         kp2 = kp2.at[sblk, soff].set(jnp.where(begun[..., None, None], ks, 0))
         vp2 = vp2.at[sblk, soff].set(jnp.where(begun[..., None, None], vs, 0))
-        # one gather: the open window's blocks, then the summary pages
+        # one page list: the open window's blocks, then the summary pages
         E = min(Wn // Bk, nb)
         W = pos // Wn                                             # [B]
         epages = jnp.take_along_axis(
             table, jnp.minimum(W[:, None] * (Wn // Bk) + i32(E)[None, :],
                                nb - 1), axis=1)
         pages = jnp.concatenate([epages, stable[:, :ns]], axis=1)
-        L = (E + ns) * Bk
-        kc = kp2[pages].reshape((B, L) + tail)
-        vc = vp2[pages].reshape((B, L) + tail)
-        exact = (W[:, None] * Wn + i32(E * Bk)[None, :])[:, None, :] \
-            <= p[:, :, None]                                      # [B,T,E*Bk]
-        summary = i32(ns * Bk)[None, :] < (W * (Wn // C))[:, None]
-        valid = jnp.concatenate(
-            [exact, jnp.broadcast_to(summary[:, None, :], (B, T, ns * Bk))],
-            axis=-1)
-        with jax.named_scope("eva_attention"):
-            o = self._grouped_attention(q, kc, vc, causal=True, valid=valid)
+        if self.fused_read_engages(state0.get("paged_kernel", "auto"), T,
+                                   q.dtype, state0.get("mesh")):
+            # rows each page holds for this query: the open window up to
+            # pos, a summary row per closed chunk, nothing for a lane off
+            erows = pos[:, None] + 1 - (W[:, None] * Wn + i32(E)[None, :] * Bk)
+            srows = (W * (Wn // C))[:, None] - i32(ns)[None, :] * Bk
+            rows = jnp.where(wmask[:, :1], jnp.clip(
+                jnp.concatenate([erows, srows], axis=1), 0, Bk), 0)
+            # imported where it is used: Pallas costs a second to import
+            from ...ops.paged_read import paged_read_attention
+            with jax.named_scope("eva_attention"):
+                o = paged_read_attention(
+                    q, kp2, vp2, pages, rows,
+                    interpret=jax.default_backend() != "tpu")
+        else:
+            L = (E + ns) * Bk
+            kc = kp2[pages].reshape((B, L) + tail)
+            vc = vp2[pages].reshape((B, L) + tail)
+            exact = (W[:, None] * Wn + i32(E * Bk)[None, :])[:, None, :] \
+                <= p[:, :, None]                                  # [B,T,E*Bk]
+            summary = i32(ns * Bk)[None, :] < (W * (Wn // C))[:, None]
+            valid = jnp.concatenate(
+                [exact,
+                 jnp.broadcast_to(summary[:, None, :], (B, T, ns * Bk))],
+                axis=-1)
+            with jax.named_scope("eva_attention"):
+                o = self._grouped_attention(q, kc, vc, causal=True,
+                                            valid=valid)
         if mask is not None:
             o = o * mask[:, :, None, None].astype(o.dtype)
         y = self._out(params, o, B, T)

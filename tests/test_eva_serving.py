@@ -78,13 +78,21 @@ def _ref_logprobs(fam, params, prompt, tokens):
 TOL = 2e-5
 
 
-def test_chunked_prefill_then_paged_decode_is_the_reference(small):
+# the T = 1 read both ways: the gather body ("off", what "auto" is on the
+# CPU) and the fused paged read (ISSUE 31), interpreted here
+BOTH_READS = pytest.mark.parametrize("paged_kernel", ["off", "on"])
+
+
+@BOTH_READS
+def test_chunked_prefill_then_paged_decode_is_the_reference(small,
+                                                            paged_kernel):
     """107 prompt bytes in chunks of 16 (boundaries at 16, 48, 80 inside a
     window; 32, 64, 96 on one; the last chunk is 11 bytes), then 60 decoded:
     the request crosses the window boundaries at 32, 64 and 96 while
     prefilling and 128 and 160 while decoding."""
     fam, params, net = small
-    s = Served(net, blocks=40)
+    s = Served(net, blocks=40, paged_kernel=paged_kernel)
+    assert s.eng.paged_kernel_status()["engaged"] == (paged_kernel == "on")
     try:
         prompt = _prompt(107, 0)
         h = s.eng.submit(prompt, 60)
@@ -119,11 +127,12 @@ def test_chunked_prefill_then_paged_decode_is_the_reference(small):
     assert s.held[-1] == (0, 0) and s.eng.pool.used_blocks == 0
 
 
-def test_two_slots_at_different_depths_in_one_step(small):
+@BOTH_READS
+def test_two_slots_at_different_depths_in_one_step(small, paged_kernel):
     """A request deep in its fourth window and one in its first decode in
     the same steps: per-slot windows, per-slot summary tables."""
     fam, params, net = small
-    s = Served(net, blocks=40)
+    s = Served(net, blocks=40, paged_kernel=paged_kernel)
     try:
         a, b = _prompt(100, 1), _prompt(9, 2)
         ha, hb = s.eng.submit(a, 40), s.eng.submit(b, 40)
@@ -137,6 +146,74 @@ def test_two_slots_at_different_depths_in_one_step(small):
     assert both and any(hi // WINDOW >= 3 and lo // WINDOW == 0
                         for lo, hi in both)
     assert all(used == want for used, want in s.held), s.held
+
+
+@BOTH_READS
+def test_pages_named_and_pages_read_are_counted(small, paged_kernel):
+    """`eva_pages_bucket_total` is slots x (open-window pages + summary
+    pages of the table bucket) a decode dispatch; `eva_pages_read_total` the
+    pages holding a row a fed slot attends over where the fused read
+    engages, the bucket's where it does not. 32 prompt bytes are two whole
+    chunks, so every decode dispatch feeds one sampling slot: positions 32
+    to 50, the second window, table bucket 8 (4 exact + 2 summary pages)."""
+    _, _, net = small
+    s = Served(net, blocks=40, paged_kernel=paged_kernel)
+    try:
+        assert len(s.eng.submit(_prompt(32, 5), 20).result(300)) == 20
+        c = s.eng.metrics.snapshot()["counters"]
+    finally:
+        s.eng.stop()
+    at = np.arange(32, 51)
+    # depths seen after each iteration: 32 once the prompt is in, then one
+    # more a decode dispatch, each fed at the depth before it
+    seen = [d[0] for d in s.depths if d]
+    assert seen[:19] == at.tolist() and len(seen) <= 20
+    named = len(at) * 2 * (WINDOW // BLOCK + 8 // CFG["chunk_size"])
+    summaries = at // WINDOW * (WINDOW // CFG["chunk_size"])
+    read = int((-(-(at % WINDOW + 1) // BLOCK) + -(-summaries // BLOCK)).sum())
+    assert (named, read) == (228, 52)
+    assert c["eva_pages_bucket_total"] == named
+    assert c["eva_pages_read_total"] == (read if paged_kernel == "on"
+                                         else named)
+
+
+@pytest.mark.parametrize("seam", ["unregistered", "registered"])
+def test_a_bfloat16_one_row_per_position_step_is_untouched(seam):
+    """The StarCoder2 cells' decode program is the parent's: a bfloat16
+    `SelfAttentionLayer` step (grouped heads, RoPE) lowers to the same text,
+    with no kernel call in it, whether or not the `paged_decode_attention`
+    seam is registered (it declines bfloat16), and knows nothing of
+    `ops/paged_read`."""
+    from deeplearning4j_tpu.nn.conf.layers import SelfAttentionLayer
+    from deeplearning4j_tpu.nn.layers.base import impl_for
+    from deeplearning4j_tpu.ops import helpers, pallas_kernels
+    impl = impl_for(SelfAttentionLayer(n_in=64, n_out=64, n_heads=4,
+                                       n_kv_heads=2, rope=True,
+                                       activation="identity"))
+    dt = jnp.bfloat16
+    params = jax.tree_util.tree_map(
+        lambda a: a.astype(dt), impl.init_params(jax.random.PRNGKey(0)))
+    state = {"k_pages": jnp.zeros((9, BLOCK, 2, 16), dt),
+             "v_pages": jnp.zeros((9, BLOCK, 2, 16), dt),
+             "pos": jnp.asarray([3, 20], jnp.int32),
+             "table": jnp.arange(1, 9, dtype=jnp.int32).reshape(2, 4),
+             "wmask": jnp.ones((2, 1), bool)}
+
+    def text():
+        return jax.jit(lambda p, x, st: impl._paged_step(
+            p, x, {**st, "paged_kernel": "auto", "mesh": None})).lower(
+                params, jnp.zeros((2, 1, 64), dt), state).as_text()
+
+    assert helpers.get_helper("paged_decode_attention") is None
+    plain = text()
+    if seam == "registered":
+        pallas_kernels.enable_paged_decode(interpret=True)
+        try:
+            assert text() == plain
+        finally:
+            pallas_kernels.disable()
+    assert "custom_call" not in plain and "paged_read" not in plain
+    assert "gather" in plain
 
 
 def test_preempt_and_resume_reproduces_the_tokens(small):
