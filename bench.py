@@ -365,15 +365,15 @@ def bench_decode_prefill(prompt_len=256, new_tokens=16, chunk=64,
 
 
 def bench_prefix_reuse(prompt_len=256, new_tokens=16, chunk=64, vocab=64,
-                       kv_block=16, cache_mb=8.0) -> dict:
+                       kv_block=16, pool_mb=1.0) -> dict:
     """Prefix-KV-reuse A/B on the decode scheduler (ISSUE 4 acceptance):
-    the SAME 256-token prompt served twice through a prefix-cached engine
-    (inference/kvpool.py) vs a cold engine. The first pass publishes the
-    prompt's K/V blocks into the pool; the repeat restores the cached
-    prefix in ONE block-gather program and only prefills the cold tail,
-    so TTFT-in-engine-steps must drop to <= 1/4 of the cold path while
-    greedy outputs stay token-identical to the no-pool engine and solo
-    decoding, and pool bytes stay under the configured budget.
+    the SAME 256-token prompt served twice through the paged pool
+    (inference/kvpool.py) vs a contiguous, pool-less engine. The first
+    pass hands the prompt's K/V blocks to the pool's trie; the repeat
+    points its block table at them (no copy) and re-feeds only the last
+    token, so TTFT-in-engine-steps must drop to <= 1/4 of the cold path
+    while greedy outputs stay token-identical to the no-pool engine and
+    solo decoding, and pool bytes stay under the configured budget.
     Standalone-runnable:
         python -c "import bench, json; print(json.dumps(bench.bench_prefix_reuse()))"
     """
@@ -405,17 +405,17 @@ def bench_prefix_reuse(prompt_len=256, new_tokens=16, chunk=64, vocab=64,
 
     m = MetricsRegistry()
     eng = DecodeScheduler(net, vocab, n_slots=2, prefill_chunk=chunk,
-                          prefix_cache_mb=cache_mb, kv_block=kv_block,
+                          kv_pool_mb=pool_mb, kv_block=kv_block,
                           metrics=m).start()
     try:
         first = eng.submit(prompt, new_tokens)
         first_tokens = first.result(600)  # cold pass: publishes blocks
-        eng.submit(prompt, new_tokens).result(600)  # compiles the restore
+        eng.submit(prompt, new_tokens).result(600)  # compiles setpos + COW
         hit0 = m.counter("prefix_cache_hit_tokens_total").value
         h_warm = eng.submit(prompt, new_tokens)
         warm_tokens = h_warm.result(600)  # repeat: restores the prefix
         pool = eng.pool
-        budget = int(cache_mb * (1 << 20))
+        budget = int(pool_mb * (1 << 20))
         pool_bytes = (pool.capacity_blocks + 1) * pool.bytes_per_block
         within = pool_bytes <= budget and pool.used_bytes <= budget
         hit_tokens = m.counter("prefix_cache_hit_tokens_total").value - hit0
@@ -428,7 +428,7 @@ def bench_prefix_reuse(prompt_len=256, new_tokens=16, chunk=64, vocab=64,
         "new_tokens": new_tokens,
         "prefill_chunk": chunk,
         "kv_block": kv_block,
-        "prefix_cache_mb": cache_mb,
+        "kv_pool_mb": pool_mb,
         "ttft_steps_cold": steps_cold,
         "ttft_steps_warm": steps_warm,
         "ttft_steps_ratio": round(steps_warm / steps_cold, 4),
@@ -442,7 +442,7 @@ def bench_prefix_reuse(prompt_len=256, new_tokens=16, chunk=64, vocab=64,
                               == first_tokens == solo),
         "note": f"same {prompt_len}-token prompt twice, 2-block d64 "
                 "transformer LM (RoPE); warm = radix-trie prefix hit "
-                f"restored via one block-gather (block {kv_block}), cold "
+                f"served by block-table remap (block {kv_block}), cold "
                 "= full chunked prefill on a pool-less engine",
     }
 
@@ -1855,7 +1855,7 @@ def bench_fleet_router(n_prompts=8, prompt_len=48, new_tokens=8,
     argv = lm_spec_argv(vocab=vocab, d_model=32, n_heads=4, n_blocks=2,
                         cache=prompt_len + new_tokens + 16) + [
         "--slots", "4", "--prefill-chunk", "16",
-        "--prefix-cache-mb", "16", "--kv-block", "8"]
+        "--kv-pool-mb", "0.5", "--kv-block", "8"]
     rng = np.random.default_rng(3)
     bodies = [json.dumps(
         {"prompt": rng.integers(0, vocab, prompt_len).tolist(),

@@ -20,7 +20,7 @@ exits from explicit `raise` — driven by the declarative
 
   trie pins       ``KVPool.match`` -> ``release`` (engine slot pins)
   pool blocks     ``alloc`` -> ``free_block``; ownership transfers out
-                  via ``adopt``/``insert`` (publish/COW)
+                  via ``adopt`` (publish/COW)
   mask rows       ``MaskPool.acquire`` -> ``release``/``evict``
   journal records ``accept`` -> exactly one terminal ``finish``/``fail``
   engine slots    admit -> free (index stores; runtime-ledger tracked)
@@ -120,10 +120,10 @@ REGISTRY: Tuple[ResourceSpec, ...] = (
     ResourceSpec(
         kind="pool_block",
         acquire=("alloc",), release=("free_block",),
-        transfer=("adopt", "insert"),
+        transfer=("adopt",),
         owners=("block_ids",), receivers=("pool",),
         doc="KVPool.alloc claims one page; free_block returns it; "
-            "adopt/insert transfer ownership to the trie at publish "
+            "adopt transfers ownership to the trie at publish "
             "(the caller must NOT free adopted ids)."),
     ResourceSpec(
         kind="mask_row",
@@ -863,7 +863,7 @@ class _FnWalk:
                 h.pending = False
 
     def _apply_transfer(self, call: ast.Call, s: _State) -> None:
-        """adopt/insert: any tracked handle named ANYWHERE in the args
+        """adopt: any tracked handle named ANYWHERE in the args
         (including inside list literals / slices) moves to the pool."""
         for arg in list(call.args) + [kw.value for kw in call.keywords]:
             for n in ast.walk(arg):

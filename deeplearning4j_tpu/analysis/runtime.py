@@ -144,10 +144,7 @@ class CompileCounter:
         """Budgets for a DecodeScheduler.
 
         Contiguous mode: 1 decode program, <=1 prefill program per pow2
-        chunk bucket (0 when chunking is off), 1 slot-reset program, and
-        — when the prefix KV pool is enabled — <=1 restore and <=1
-        publish program per pow2 block-chain bucket
-        (kvpool.gather_blocks / scatter_blocks).
+        chunk bucket (0 when chunking is off) and 1 slot-reset program.
 
         Paged mode (engine.paged): block tables are padded to pow2
         bucket widths like every other shape, so decode is <=1 program
@@ -166,14 +163,6 @@ class CompileCounter:
         jzero = getattr(scheduler, "_jzero", None)
         if jzero is not None:
             c.track("admit_reset", jzero, budget=1)
-        jrestore = getattr(scheduler, "_jrestore", None)
-        if jrestore is not None:
-            c.track("prefix_restore", jrestore,
-                    budget=len(scheduler.restore_buckets))
-        jpublish = getattr(scheduler, "_jpublish", None)
-        if jpublish is not None:
-            c.track("prefix_publish", jpublish,
-                    budget=len(scheduler.restore_buckets))
         jsetpos = getattr(scheduler, "_jsetpos", None)
         if jsetpos is not None:
             c.track("restore_setpos", jsetpos, budget=1)

@@ -14,8 +14,7 @@ Usage:
                    [--batch-window-ms MS] [--queue-size N] [--timeout-ms MS]
                    [--trace-buffer N]
                    [--generate [--vocab-size V] [--decode-slots N]
-                    [--prefill-chunk C] [--kv-pool-mb MB]
-                    [--prefix-cache-mb MB] [--kv-block B]
+                    [--prefill-chunk C] [--kv-pool-mb MB] [--kv-block B]
                     [--kv-dtype int8] [--paged-kernel auto|on|off]
                     [--host-cache-mb MB] [--disk-cache-mb MB]
                     [--tier-dir DIR]
@@ -127,7 +126,6 @@ def cmd_serve(args) -> int:
               default_timeout_ms=args.timeout_ms,
               decode_slots=args.decode_slots,
               prefill_chunk=args.prefill_chunk,
-              prefix_cache_mb=args.prefix_cache_mb,
               kv_block=args.kv_block,
               kv_pool_mb=args.kv_pool_mb,
               kv_dtype=args.kv_dtype,
@@ -209,7 +207,6 @@ def cmd_serve(args) -> int:
     # disables it (with a RuntimeWarning) when the model has no KV cache
     # or the budget cannot fit two blocks
     decoder = getattr(server, "_decoder", None)
-    pool_on = getattr(decoder, "pool", None) is not None
     paged_on = bool(getattr(decoder, "paged", False))
     # mesh topology: the ENGINE's actual tp (the scheduler disables
     # sharding with a RuntimeWarning when heads don't divide), not the
@@ -254,9 +251,6 @@ def cmd_serve(args) -> int:
                          if args.disk_cache_mb else "")
                       if getattr(decoder, "tier", None) is not None
                       else ""))
-    elif pool_on:
-        kv_mode = (f", prefix cache {args.prefix_cache_mb}MB "
-                   f"(block {args.kv_block})")
     else:
         kv_mode = ", prefix cache OFF"
     slo_mode = (f", SLO p99<={args.slo_p99_ms:g}ms (burn-rate fed to "
@@ -426,19 +420,14 @@ def build_parser() -> argparse.ArgumentParser:
                    help="max prompt tokens prefilled per engine step "
                         "(pow2 chunk buckets; TTFT/decode-latency knob; "
                         "<=1 = token-by-token prefill)")
-    s.add_argument("--prefix-cache-mb", type=float, default=0.0,
-                   help="byte budget (MiB) for the prefix KV cache: "
-                        "completed prompts' K/V blocks are pooled and "
-                        "repeated prefixes restored instead of "
-                        "re-prefilled (0 = disabled)")
     s.add_argument("--kv-pool-mb", type=float, default=0.0,
                    help="byte budget (MiB) for the PAGED live-decode KV "
                         "pool: all slots share one block pool (capacity "
                         "is pool bytes, not slots x max_cache_len), "
                         "prefix restore is a zero-copy block-table "
                         "remap, and cold slots preempt-and-resume under "
-                        "pressure; supersedes --prefix-cache-mb "
-                        "(0 = contiguous per-slot caches)")
+                        "pressure (0 = contiguous per-slot caches, no "
+                        "prefix cache)")
     s.add_argument("--tp", type=int, default=0,
                    help="shard the decode engine tensor-parallel over N "
                         "devices (attention heads/FFN split over a 'tp' "
@@ -447,9 +436,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "device; CPU test meshes via XLA_FLAGS="
                         "--xla_force_host_platform_device_count=N)")
     s.add_argument("--kv-block", type=int, default=16,
-                   help="positions per KV block, paged pool and prefix "
-                        "cache alike (only full blocks of a prompt are "
-                        "shared)")
+                   help="positions per KV pool block (only full "
+                        "blocks of a prompt are shared)")
     s.add_argument("--host-cache-mb", type=float, default=0.0,
                    help="hierarchical KV tiering (paged mode only): "
                         "evicted-but-unreferenced prefix blocks demote "

@@ -7,7 +7,7 @@ padded batches; `DecodeScheduler` continuously batches generative decode
 over the attention KV cache — paged (`kv_pool_mb`: all slots share one
 `KVPool` block pool through per-slot block tables, with zero-copy prefix
 restore/publish and preempt-and-swap under pool pressure) or contiguous
-per-slot stripes with a `KVPool` side prefix cache; `MetricsRegistry`
+per-slot stripes with no prefix cache; `MetricsRegistry`
 records queue depth, batch occupancy, hit rates, pool occupancy, and
 latency percentiles, exported at
 `GET /metrics`; the `FlightRecorder` span flight recorder (`trace.py`)

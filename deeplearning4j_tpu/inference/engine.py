@@ -42,20 +42,6 @@ are masked out of the decode step *inside* the jitted program — their
 recurrent state and cache position are frozen by a `live` mask, so the
 shared-batch step cannot corrupt a half-prefilled slot.
 
-Prefix KV reuse (the ISSUE 4 tentpole, `inference/kvpool.py`): with
-``prefix_cache_mb > 0`` the engine keeps a block pool + radix-trie prefix
-index over completed prompts' prefill-written K/V. Admission walks the
-trie over the prompt's full ``kv_block``-sized blocks, restores the
-longest cached prefix into the slot's contiguous cache rows with ONE
-jitted block-gather program (bucketed by chain length, same pow2 compile
-discipline as prefill) and advances ``pos`` past the hit — chunked
-prefill then only runs the cold suffix, so a repeated prompt reaches its
-first token in ~1 engine step instead of O(prompt/C). When a sequence
-finishes, its prompt's full blocks are published back into the pool
-(copy out of the slot cache, functional scatter into pool storage) and
-indexed; cached keys are stored pre-rotated at absolute positions, so a
-pos-0-anchored prefix is bit-identical across requests.
-
 Paged KV decode (the ISSUE 6 tentpole, ``kv_pool_mb > 0``): the live
 decode cache itself becomes the block pool. Per-layer K/V moves from
 ``[n_slots, max_cache_len]`` stripes into pool-wide page arrays
@@ -71,7 +57,15 @@ the one shared block a full-prompt hit's refeed writes), and under pool
 pressure the latest-submitted slot is preempted — blocks released,
 sequence requeued at the front, resumed later by re-prefilling prompt +
 generated-so-far (host RNG untouched, so the resumed output is
-token-identical to an unpreempted run).
+token-identical to an unpreempted run). The pool is also the only prefix
+cache (`inference/kvpool.py`): admission walks its radix trie over the
+prompt's full ``kv_block``-sized blocks, points the slot's table at the
+longest cached chain and advances ``pos`` past the hit, so chunked
+prefill only runs the cold suffix; a finished prompt's full blocks are
+adopted by the trie in place. Cached keys are stored pre-rotated at
+absolute positions, so a pos-0-anchored prefix is bit-identical across
+requests. Contiguous ``[n_slots, max_cache_len]`` stripes (the default)
+carry no prefix cache.
 
 Token selection reuses `models/sampling.sample_logits`, so greedy engine
 output is token-identical to solo `generate_transformer(use_cache=True)`
@@ -86,7 +80,6 @@ its slot's rows).
 from __future__ import annotations
 
 import contextlib
-import functools
 import threading
 import time
 import warnings
@@ -106,14 +99,12 @@ from ..nn.layers.recurrent import (BaseRecurrentImpl,
 from ..nn.multilayer import _compute_dtype_of
 from . import failpoints
 from .batcher import QueueFullError, bucket_for, pow2_buckets
-from .kvpool import (PAGE_KEYS, SCRATCH_BLOCK, KVPool, gather_blocks,
-                     scatter_blocks)
+from .kvpool import PAGE_KEYS, SCRATCH_BLOCK, KVPool
 from .logitproc import CompiledGrammar, LogitState, MaskPool
 from .metrics import MetricsRegistry, default_registry
 from .profiler import StepPhaseProfiler, program_costs
 from .sharding import (TP_AXIS, decode_mesh, kv_heads_shardable,
-                       shard_decode_params, state_shardings,
-                       storage_shardings)
+                       shard_decode_params, state_shardings)
 from .speculative import ForkGroup, accept_tokens, build_shallow_draft
 from .trace import FlightRecorder, default_recorder, new_request_id
 
@@ -428,13 +419,10 @@ class DecodeScheduler:
     the pool only, and the prefix trie stands aside for it
     (docs/serving.md, "The EVA cache").
 
-    ``prefix_cache_mb``: byte budget (MiB) for the CONTIGUOUS-mode side
-    prefix pool (ignored when ``kv_pool_mb`` is set — the paged pool is
-    its own prefix cache); 0 disables prefix reuse. ``kv_block``:
-    positions per pool block in either mode — only full blocks of a
+    ``kv_block``: positions per pool block — only full blocks of a
     prompt are shared, so smaller blocks match more but cost more
-    metadata. Pools only engage for attention nets (pos-0-anchored KV
-    prefixes; recurrent h/c state has no position-addressed rows).
+    metadata. The pool only engages for attention nets (pos-0-anchored
+    KV prefixes; recurrent h/c state has no position-addressed rows).
 
     ``tracer``: span flight recorder (`inference/trace.py`, default the
     process-wide one). Every request's lifecycle is recorded — queued /
@@ -450,9 +438,9 @@ class DecodeScheduler:
     is used as-is. Attention heads and FFN hidden dims shard across the
     axis (Megatron pairing, output head replicated), the KV cache —
     contiguous stripes and paged ``k_pages``/``v_pages`` alike — shards
-    on its Hkv head axis (``kv_pool_mb``/``prefix_cache_mb`` budgets
-    become PER-DEVICE bytes: at fixed per-device HBM the pool holds
-    ``tp×`` the blocks), and everything host-authoritative (block
+    on its Hkv head axis (the ``kv_pool_mb`` budget becomes PER-DEVICE
+    bytes: at fixed per-device HBM the pool holds ``tp×`` the blocks),
+    and everything host-authoritative (block
     tables, ids, masks, ``pos``) replicates — so paged attention,
     prefix restore, COW, and preemption run unchanged per shard. The
     per-token program's only collectives are the two Megatron
@@ -518,7 +506,7 @@ class DecodeScheduler:
 
     def __init__(self, net, vocab_size: int, *, n_slots: int = 4,
                  max_queue: int = 64, prefill_chunk: int = 64,
-                 prefix_cache_mb: float = 0.0, kv_block: int = 16,
+                 kv_block: int = 16,
                  kv_pool_mb: float = 0.0, kv_dtype: Optional[str] = None,
                  paged_kernel: str = "auto",
                  host_cache_mb: float = 0.0, disk_cache_mb: float = 0.0,
@@ -710,15 +698,14 @@ class DecodeScheduler:
             # that a retrained net's rebound params are picked up
             self._sharded_params, self._sharded_variables = \
                 shard_decode_params(net, self.mesh)
-        # KV memory layout (kvpool.py) — attention nets only: both modes
-        # manage position-addressed K/V rows, which recurrent h/c state
-        # does not have.
-        #   kv_pool_mb > 0  -> PAGED: the pool IS the live decode cache
-        #     (per-layer page arrays in self._states, per-slot block
-        #     tables, zero-copy prefix restore/publish, preempt-and-swap)
-        #   prefix_cache_mb -> contiguous slots + a side prefix pool
-        #     restored by jitted block-gather (the ISSUE 4 layout, kept
-        #     as the token-identity reference)
+        # KV memory layout (kvpool.py):
+        #   kv_pool_mb > 0 -> PAGED, attention nets only (the pool manages
+        #     position-addressed K/V rows, which recurrent h/c state does
+        #     not have): the pool IS the live decode cache (per-layer page
+        #     arrays in self._states, per-slot block tables, zero-copy
+        #     prefix restore/publish, preempt-and-swap)
+        #   otherwise      -> contiguous [n_slots, max_cache_len] stripes
+        #     and no prefix cache (the token-identity reference)
         self.kv_block = int(kv_block)
         self.kv_dtype: Optional[str] = None  # set when int8 KV engages
         # fused Pallas decode-kernel mode (ISSUE 15): injected into the
@@ -728,17 +715,14 @@ class DecodeScheduler:
         self.paged_kernel = paged_kernel
         self.pool: Optional[KVPool] = None
         self.paged = False
-        self.restore_buckets: List[int] = []
         self.table_buckets: List[int] = []
-        self._jrestore = None
-        self._jpublish = None
         self._jsetpos = None
         self._jcow = None
         self._table: Optional[np.ndarray] = None
         if kv_pool_mb and kv_pool_mb > 0:
             if self._chunk_dense and attn_keys and self.kv_block >= 1:
                 attn = {key: abstract_states[key] for key in attn_keys}
-                pool = KVPool(attn, block=self.kv_block, paged=True,
+                pool = KVPool(attn, block=self.kv_block,
                               budget_bytes=int(kv_pool_mb * (1 << 20)),
                               shard_factor=self.tp, cache_dtype=kv_dtype,
                               metrics=self.metrics, tracer=self.tracer)
@@ -827,67 +811,11 @@ class DecodeScheduler:
                        else "the byte budget is smaller than two "
                             f"{self.kv_block}-position blocks"),
                     RuntimeWarning, stacklevel=2)
-            elif prefix_cache_mb and prefix_cache_mb > 0:
-                warnings.warn(
-                    "prefix_cache_mb is ignored when kv_pool_mb is set: "
-                    "the paged pool IS the prefix cache (finished "
-                    "prompts' blocks are adopted by the trie in place, "
-                    "zero-copy)", RuntimeWarning, stacklevel=2)
         if kv_dtype and not self.kv_dtype:
             warnings.warn(
                 "kv_dtype='int8' requested but the paged KV pool did not "
                 "engage (int8 KV quantization lives in the pool's page "
                 "arrays); serving with the model-dtype cache instead",
-                RuntimeWarning, stacklevel=2)
-        # NOT elif: when kv_pool_mb was requested but paged could not
-        # engage, a configured prefix_cache_mb must still buy the
-        # contiguous side pool — silently dropping BOTH knobs would
-        # leave the operator with no prefix cache and no warning
-        if (not self.paged and prefix_cache_mb and prefix_cache_mb > 0
-                and self._chunk_dense
-                and self._cache_cap is not None
-                and self.kv_block >= 1
-                and self._cache_cap >= self.kv_block):
-            attn = {key: abstract_states[key] for key in attn_keys}
-            pool = KVPool(attn, block=self.kv_block,
-                          budget_bytes=int(prefix_cache_mb * (1 << 20)),
-                          shard_factor=self.tp,
-                          metrics=self.metrics, tracer=self.tracer)
-            if attn and pool.capacity_blocks > 0:
-                self.pool = pool
-                # one restore/publish program per pow2 block-chain bucket;
-                # every bucket satisfies bucket*kv_block <= cache capacity,
-                # so the fused row write always fits the slot's cache
-                self.restore_buckets = pow2_buckets(
-                    self._cache_cap // self.kv_block)
-                self._jrestore = jax.jit(functools.partial(
-                    gather_blocks, block=self.kv_block),
-                    donate_argnames=("states",))
-                # storage is donated: publish updates the pool in place
-                # instead of re-materializing the whole budget's worth of
-                # arrays per call; the caller rebinds pool.storage to the
-                # result immediately, so the consumed buffers are never
-                # touched again
-                self._jpublish = jax.jit(functools.partial(
-                    scatter_blocks, block=self.kv_block),
-                    donate_argnums=(4,))
-        if (not self.paged
-                and prefix_cache_mb and prefix_cache_mb > 0
-                and self.pool is None):
-            # the knob was set but the pool could not engage — without
-            # this the operator sees a phantom cache (banner/flags say
-            # on, every prompt still pays full prefill, no prefix_*
-            # instruments in /metrics)
-            warnings.warn(
-                f"prefix_cache_mb={prefix_cache_mb} requested but the "
-                "prefix KV pool is DISABLED: "
-                + ("the model has no attention KV cache to share"
-                   if not self._chunk_dense or self._cache_cap is None
-                   else f"kv_block={kv_block} exceeds "
-                        f"max_cache_len={self._cache_cap}"
-                   if self._cache_cap < max(self.kv_block, 1)
-                   else "the byte budget is smaller than two "
-                        f"{self.kv_block}-position blocks"),
                 RuntimeWarning, stacklevel=2)
         if self._states is None:
             # contiguous layouts (and the LSTM fallback) materialize the
@@ -906,18 +834,12 @@ class DecodeScheduler:
             # definition single-chip-scale state
             self._states = jax.device_put(
                 self._states, state_shardings(self._states, self.mesh))
-            if self.pool is not None and self.pool.storage:
-                # contiguous-mode side pool storage splits on the same
-                # head axis, so restore's block gather never reshards
-                self.pool.storage = jax.device_put(
-                    self.pool.storage,
-                    storage_shardings(self.pool.storage, self.mesh))
         # THE donation rule: a program that takes the carried state and
         # returns it donates it (the pool is updated in place: no copy of
         # the page arrays on the device, no fresh output buffers from the
         # allocator on the host), and every caller rebinds from the
         # result; a program that only reads the state does not
-        # (_jtier_spill, _jpublish's argument 0)
+        # (_jtier_spill)
         self._jstep = jax.jit(
             self._step_paged_fn if self.paged else self._step_fn,
             donate_argnames=("states",))
@@ -1217,7 +1139,7 @@ class DecodeScheduler:
             self._m_spec_accepted = m.counter("spec_tokens_accepted_total")
             m.ratio("spec_acceptance_rate", self._m_spec_accepted,
                     self._m_spec_proposed)
-        if self.pool is not None:
+        if self.paged:
             self._m_prefix_lookups = m.counter("prefix_cache_lookups_total")
             self._m_prefix_hits = m.counter("prefix_cache_hits_total")
             self._m_prefix_lookup_tokens = m.counter(
@@ -1870,37 +1792,6 @@ class DecodeScheduler:
             self._draft_states = self._jdraft_zero(  # graftlint: disable=CC005
                 self._draft_states, self._dev_index(slot))
 
-    # -- prefix KV reuse (kvpool.py) ---------------------------------------
-    def _try_restore(self, slot: int, seq: _ActiveSeq) -> None:
-        """Walk the prefix trie for the admitted prompt and restore the
-        longest cached block chain into the freshly-zeroed slot, advancing
-        ``seq.fed``/``pos`` past the hit so chunked prefill only runs the
-        cold suffix. The hit is capped one token short of the prompt: the
-        LAST prompt token must always run through the model to produce
-        the first output token's distribution."""
-        B = self.pool.block
-        max_hit = (len(seq.prompt) - 1) // B
-        self._m_prefix_lookups.inc()
-        self._m_prefix_lookup_tokens.inc(len(seq.prompt))
-        if max_hit < 1:
-            return
-        n_blk, ids, node = self.pool.match(seq.prompt, max_hit)
-        seq.pool_node = node  # holds one reference until the slot frees
-        if node is not None:
-            ledger_note("trie_pin", seq.handle.request_id, +1)
-        if not n_blk:
-            return
-        bucket = bucket_for(n_blk, self.restore_buckets)
-        idx = np.full((bucket,), SCRATCH_BLOCK, np.int32)
-        idx[:n_blk] = ids
-        self._states = self._jrestore(
-            self._states, self._dev_index(slot), self._dev_array(idx),
-            self._dev_index(n_blk), self.pool.storage)
-        seq.fed = n_blk * B
-        seq.written = seq.fed  # host pos mirror (speculation's fixpos)
-        self._m_prefix_hits.inc()
-        self._m_prefix_hit_tokens.inc(seq.fed)
-
     def _release_pool(self, seq: _ActiveSeq) -> None:
         """Drop the sequence's prefix-trie reference (every slot-freeing
         path — finish, cancel, stop — must come through here, or the
@@ -1909,33 +1800,6 @@ class DecodeScheduler:
             self.pool.release(seq.pool_node)
             seq.pool_node = None
             ledger_note("trie_pin", seq.handle.request_id, -1)
-
-    def _publish_prompt(self, slot: int, seq: _ActiveSeq) -> None:
-        """Index a finished sequence's prompt: insert its full blocks into
-        the trie (allocating pool blocks, LRU-evicting unreferenced ones
-        when full) and copy the slot's prefill-written cache rows into the
-        new storage rows. The missing part is always a contiguous suffix
-        of the prompt's block chain, covered by a greedy descending walk
-        over the pow2 buckets — so publish compiles the same bounded
-        program family as restore."""
-        B = self.pool.block
-        n_full = len(seq.prompt) // B
-        if n_full < 1:
-            return
-        # the pool is scheduler-thread-only past start() (same protocol
-        # as _states above; stop() touches it only after the join)
-        start, new_ids = self.pool.insert(seq.prompt[:n_full * B])  # graftlint: disable=CC005
-        off = 0
-        while off < len(new_ids):
-            b = max(k for k in self.restore_buckets
-                    if k <= len(new_ids) - off)
-            idx = np.zeros((b,), np.int32)
-            idx[:] = new_ids[off:off + b]
-            self.pool.storage = self._jpublish(
-                self._states, self._dev_index(slot),
-                self._dev_index(start + off), self._dev_array(idx),
-                self.pool.storage)
-            off += b
 
     # -- paged mode: block tables, lazy alloc, COW, preempt-and-swap -------
     def _blocks_for(self, positions: int) -> int:
@@ -2178,7 +2042,7 @@ class DecodeScheduler:
         seq.rolled = 0
         self._table[slot, :] = SCRATCH_BLOCK
 
-    def _try_restore_paged(self, slot: int, seq: _ActiveSeq) -> None:
+    def _try_restore(self, slot: int, seq: _ActiveSeq) -> None:
         """Paged prefix restore = block-table remap: point the slot's
         table at the cached blocks (refcounted via the trie pin) and set
         ``pos`` past the hit. ZERO K/V copies — the pages are referenced
@@ -2188,7 +2052,7 @@ class DecodeScheduler:
         distribution, and its write copy-on-writes the final shared
         block (`_ensure_writable`)."""
         if self._eva is not None:
-            return  # nothing was published (`_publish_paged`): no lookup
+            return  # nothing was published (`_publish_prompt`): no lookup
         B = self.pool.block
         self._m_prefix_lookups.inc()
         self._m_prefix_lookup_tokens.inc(len(seq.prompt))
@@ -2246,7 +2110,7 @@ class DecodeScheduler:
                     args={"request": seq.handle.request_id,
                           "role": "attach", "blocks": n_blk})
 
-    def _publish_paged(self, slot: int, seq: _ActiveSeq) -> frozenset:
+    def _publish_prompt(self, slot: int, seq: _ActiveSeq) -> frozenset:
         """Zero-copy publish: the finished sequence's full prompt blocks
         are ADOPTED by the trie in place (ownership transfer — the pages
         already hold the prefill-written K/V). Returns the transferred
@@ -2578,10 +2442,9 @@ class DecodeScheduler:
         # writer) has been joined above
         for i, seq in enumerate(self._slots):  # graftlint: disable=CC004
             if seq is not None:
-                if self.pool is not None:
+                if self.paged:
                     self._release_pool(seq)
-                    if self.paged:
-                        self._release_slot_blocks(i, seq)
+                    self._release_slot_blocks(i, seq)
                 self._release_mask(seq)
                 seq.handle._finish(RuntimeError("scheduler stopped"))
                 self._trace_done("cancel", seq, slot=i)
@@ -2639,14 +2502,13 @@ class DecodeScheduler:
         for i, seq in enumerate(self._slots):
             if seq is not None and seq.handle.cancelled():
                 self._m_cancelled.inc()
-                if self.pool is not None:
+                if self.paged:
                     # a cancel during prefill still holds the restored
                     # prefix's trie reference — releasing here is what
                     # keeps refcounts leak-free (nothing is published:
                     # the prompt may be half-written)
                     self._release_pool(seq)
-                    if self.paged:
-                        self._release_slot_blocks(i, seq)
+                    self._release_slot_blocks(i, seq)
                 # a cancel (incl. the streaming layer's client-
                 # disconnect path) releases the grammar mask pin too
                 self._release_mask(seq)
@@ -2743,7 +2605,7 @@ class DecodeScheduler:
             self._m_queue_depth.set(len(self._queue))
             self._m_active.set(sum(s is not None for s in self._slots))
         # device work happens OUTSIDE the condvar: the slot-reset and
-        # prefix-restore dispatches (and a restore bucket's first-call
+        # prefix-restore dispatches (and a cold program's first-call
         # compile, which can take seconds) must not stall every submit()
         # caller blocked on _cond. _slots/_states/pool are scheduler-
         # thread-only, so no lock is needed past the queue handoff.
@@ -2762,11 +2624,8 @@ class DecodeScheduler:
                        args={"request": rid})
             tr.begin("prefix_restore", req=rid)
             self._reset_slot_state(i)
-            if self.pool is not None:
-                if self.paged:
-                    self._try_restore_paged(i, seq)
-                else:
-                    self._try_restore(i, seq)
+            if self.paged:
+                self._try_restore(i, seq)
             # grammar mask upload rides the admission window too (a
             # preempted-and-resumed request re-acquires here — its rows
             # are usually still cached, so this is a refcount bump)
@@ -2806,7 +2665,7 @@ class DecodeScheduler:
 
     def _fork_publish(self, slot: int, seq: _ActiveSeq) -> None:
         """Best-of-n early publish: the fork group's PRIMARY just
-        finished prefill — run the SAME `_publish_paged` ownership
+        finished prefill — run the SAME `_publish_prompt` ownership
         transfer finish-time publish uses, just earlier, so queued
         sibling candidates restore the prompt blocks as zero-copy
         block-table remaps instead of each re-prefilling. The adopted
@@ -2815,7 +2674,7 @@ class DecodeScheduler:
         COW), and the slot takes a trie pin so eviction cannot free
         rows it still reads."""
         group = seq.fork
-        adopted = self._publish_paged(slot, seq)
+        adopted = self._publish_prompt(slot, seq)
         if adopted:
             for j, bid in enumerate(seq.block_ids):
                 if bid in adopted:
@@ -2916,19 +2775,13 @@ class DecodeScheduler:
         if now is None:
             now = time.monotonic()
         h = seq.handle
-        if self.pool is not None:
+        if self.paged:
             # retain the prompt's prefill-written blocks for the next
-            # request sharing this prefix, then drop our own pin.
-            # Paged: pure ownership transfer (trie adopts the pages
-            # in place); contiguous: jitted scatter into the side
-            # pool's storage
-            if self.paged:
-                adopted = self._publish_paged(slot, seq)
-                self._release_pool(seq)
-                self._release_slot_blocks(slot, seq, keep=adopted)
-            else:
-                self._publish_prompt(slot, seq)
-                self._release_pool(seq)
+            # request sharing this prefix (pure ownership transfer: the
+            # trie adopts the pages in place), then drop our own pin
+            adopted = self._publish_prompt(slot, seq)
+            self._release_pool(seq)
+            self._release_slot_blocks(slot, seq, keep=adopted)
         self._release_mask(seq)
         h._finish()
         self._trace_done("finish", seq, slot=slot)
@@ -3718,10 +3571,9 @@ class DecodeScheduler:
             self._trace_done("cancel", seq)
         for i, seq in enumerate(self._slots):  # graftlint: disable=CC004
             if seq is not None:
-                if self.pool is not None:
+                if self.paged:
                     self._release_pool(seq)
-                    if self.paged:
-                        self._release_slot_blocks(i, seq)
+                    self._release_slot_blocks(i, seq)
                 self._release_mask(seq)
                 seq.handle._finish(exc)
                 self._trace_done("cancel", seq, slot=i)
@@ -3767,9 +3619,9 @@ class DecodeScheduler:
         on live data (all-masked ``live``, scratch table, chunks of no
         real token, scratch -> scratch copy-on-write and tier round
         trip, ``nomask`` fixpos). ``_jzero(slot0)``, ``_jsetpos(slot0,
-        0)`` and, in the contiguous layout, the chunk's padded rows and
-        the restore into slot 0 are NOT the identity: they write, then
-        zero, slot 0's rows. That is harmless only because warm-up runs
+        0)`` and, in the contiguous layout, the chunk's padded rows are
+        NOT the identity: they write, then zero, slot 0's rows. That is
+        harmless only because warm-up runs
         with no slot admitted (construction / recovery / drain-swap
         windows the supervisor owns) and admission zeroes a slot before
         its first use.
@@ -3800,7 +3652,6 @@ class DecodeScheduler:
         # rebound state holds what it held
         live = self._dev_array(np.zeros((self.n_slots,), bool))
         slot0 = self._dev_index(0)
-        one = self._dev_index(1)
         # the warm-up chunks have NO real token (n_real = 0, a traced
         # value: same programs): every lane is padding, so a paged chunk
         # writes zeros to the scratch page and leaves ``pos`` where it
@@ -3857,20 +3708,6 @@ class DecodeScheduler:
                     params, variables, slot0,
                     self._dev_array(np.zeros((b,), np.int32)),
                     no_real, self._states)
-            if self.pool is not None:
-                for b in self.restore_buckets:
-                    idx = np.full((b,), SCRATCH_BLOCK, np.int32)
-                    self._states = self._jrestore(
-                        self._states, slot0, self._dev_array(idx),
-                        one, self.pool.storage)
-                    # publish donates its storage argument too. Writing
-                    # slot 0's rows into unallocated block 0 is
-                    # harmless: any future insert() scatters real data
-                    # over it.
-                    self.pool.storage = self._jpublish(
-                        self._states, slot0, self._dev_index(0),
-                        self._dev_array(np.zeros((b,), np.int32)),
-                        self.pool.storage)
         self._states = self._jzero(self._states, slot0)
         if masks is None:
             masks = (self.maskpool is not None
@@ -4147,7 +3984,7 @@ class DecodeScheduler:
             except Exception:
                 pk["autotune"] = {}
             out["paged_kernel"] = pk
-        if self.pool is not None:
+        if self.paged:
             try:
                 out["pool"] = self.pool.stats()
             except RuntimeError:

@@ -2,20 +2,22 @@
 
 Real serving traffic is dominated by shared prompt prefixes (system
 prompts, few-shot templates, chat history) and wildly mixed prompt
-lengths, yet the decode scheduler used to hand every slot a contiguous
+lengths, yet a contiguous decode cache hands every slot a
 ``max_cache_len`` stripe of K/V — HBM cost ``slots × max_cache_len``
 regardless of actual lengths. This module is the block-level KV
 management of modern inference engines (vLLM's PagedAttention block
-tables, SGLang's RadixAttention prefix tree), in two modes:
+tables, SGLang's RadixAttention prefix tree).
 
-**Paged mode** (``paged=True`` — the ISSUE 6 tentpole): the pool IS the
-live decode cache. The engine owns one pool-wide page array per layer
-(``k_pages``/``v_pages``: ``[capacity+1, block, Hkv, Dh]``) and gives
-each slot an int32 *block table* mapping logical block index → page row;
-the jitted decode/prefill programs read and write K/V through the table
-(`nn/layers/attention.py` paged step). The pool object holds only the
-host-side metadata: the free list, the trie, and per-node refcounts.
-Consequences that fall out of the layout:
+The pool IS the live decode cache (``DecodeScheduler(kv_pool_mb=...)``).
+The engine owns one pool-wide page array per layer
+(``k_pages``/``v_pages``: ``[capacity+1, block, Hkv, Dh]``, index 0 a
+scratch page that absorbs padded writes and is never handed out) and
+gives each slot an int32 *block table* mapping logical block index →
+page row; the jitted decode/prefill programs read and write K/V through
+the table (`nn/layers/attention.py` paged step). :class:`KVPool`
+allocates nothing on the device — it is the host-side metadata: the free
+list, the trie, and per-node refcounts. Consequences that fall out of
+the layout:
 
   - slot capacity is bounded by total pool bytes, not
     ``slots × max_cache_len`` — dozens of short sequences share the
@@ -29,40 +31,20 @@ Consequences that fall out of the layout:
   - under pool pressure the scheduler preempts the latest-submitted slot
     (blocks released, sequence requeued) and resumes it later.
 
-**Contiguous mode** (``paged=False`` — the ISSUE 4 layout, kept as the
-token-identity reference and for nets the paged path cannot serve): a
-side pool caching completed prompts' K/V, restored into the slot's
-contiguous stripe by a jitted block-gather:
-
-  - :class:`KVPool` — per-layer K/V storage carved into fixed-size blocks
-    of ``block`` positions, preallocated under a byte budget (index 0 is a
-    scratch block that absorbs padded writes and is never handed out).
-    Blocks are refcounted through the trie nodes that own them and
-    LRU-evicted (unreferenced leaves first) when the free list runs dry.
-  - a **radix/trie prefix index**: one node per full block of token ids,
-    children keyed by the block's token tuple, so a prefix lookup walks
-    the trie in O(prompt/block) dict hops and returns the longest chain
-    of cached blocks. Only COMPLETE blocks are indexed — a partial tail
-    block is never shared (its K/V would depend on tokens the next
-    request may not send).
-  - :func:`gather_blocks` / :func:`scatter_blocks` — the pure program
-    bodies the engine jits: restore gathers a block chain out of pool
-    storage into one slot's contiguous cache rows ``[0, n*block)`` via a
-    single fused take + ``dynamic_update_slice`` (bucketed by chain
-    length, same pow2 compile discipline as chunked prefill) and advances
-    the slot's ``pos`` past the hit; publish slices a finished prompt's
-    rows back out of the slot cache into pool blocks.
+The **radix/trie prefix index** has one node per full block of token
+ids, children keyed by the block's token tuple, so a prefix lookup walks
+the trie in O(prompt/block) dict hops and returns the longest chain of
+cached blocks. Only COMPLETE blocks are indexed — a partial tail block
+is never shared (its K/V would depend on tokens the next request may not
+send). Blocks are refcounted through the trie nodes that own them and
+LRU-evicted (unreferenced leaves first) when the free list runs dry.
 
 Soundness: reuse is only valid for **pos-0-anchored prefixes**. Cached
 keys are stored pre-rotated at their absolute positions (RoPE commutes
 with the cache — nn/layers/attention.py), so a prefix starting at
-position 0 is bit-identical across requests and can be copied instead of
-recomputed; a mid-sequence match would need re-rotation and is not
-attempted. Restored rows are *copies* into the slot's private cache, so a
-slot never aliases pool storage — and pool writes go through functional
-``.at[idx].set`` updates, so a restore gather issued against the previous
-storage array still reads consistent data (structural copy-on-write: a
-live reader is never aliased by a writer).
+position 0 is bit-identical across requests and can be referenced
+instead of recomputed; a mid-sequence match would need re-rotation and
+is not attempted.
 
 Threading: the pool's host-side metadata (trie, free list, refcounts) is
 owned by the engine's scheduler thread — every mutation happens between
@@ -71,20 +53,18 @@ engine steps on that single thread, the same single-writer discipline
 """
 from __future__ import annotations
 
-import heapq
 import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
-import jax
 import jax.numpy as jnp
 
 from . import failpoints
 from .metrics import MetricsRegistry
 from .trace import FlightRecorder
 
-# storage index 0 is the scratch block: padded restore lanes gather from
-# it and padded publish lanes scatter into it, so bucketed programs never
-# need a mask — real blocks are numbered from 1
+# page 0 is the scratch block: padded table lanes point at it and masked
+# writes land in it, so bucketed programs never need a mask — real blocks
+# are numbered from 1
 SCRATCH_BLOCK = 0
 
 # every pool-wide page-array key a paged attention state may carry: K/V
@@ -123,15 +103,13 @@ class KVPool:
 
     ``attn_states``: the engine's attention state entries
     (``{key: {"k": [n_slots, L, Hkv, Dh], "v": ..., "pos": ...}}``) —
-    only shapes/dtypes are read; storage is allocated fresh. The byte
-    budget covers EVERYTHING the pool allocates (scratch block included):
-    ``capacity_blocks`` usable blocks cost
-    ``(capacity_blocks + 1) * bytes_per_block <= budget_bytes``.
-
-    ``paged=True``: the engine owns the page arrays (they live inside
-    its jitted state pytree, where the programs scatter/gather them);
-    this object allocates NOTHING on device and becomes pure metadata —
-    free list, trie, refcounts — plus the ``kv_pool_*`` gauges.
+    only shapes/dtypes are read. The engine owns the page arrays (they
+    live inside its jitted state pytree, where the programs
+    scatter/gather them); this object allocates NOTHING on device and is
+    pure metadata — free list, trie, refcounts — plus the ``kv_pool_*``
+    gauges. The byte budget covers EVERYTHING the engine allocates for
+    the pool (scratch block included): ``capacity_blocks`` usable blocks
+    cost ``(capacity_blocks + 1) * bytes_per_block <= budget_bytes``.
 
     ``shard_factor``: tensor-parallel device count when the K/V head
     axis is sharded over a mesh (`inference/sharding.py`). Each device
@@ -143,7 +121,7 @@ class KVPool:
     """
 
     def __init__(self, attn_states: Dict, *, block: int, budget_bytes: int,
-                 paged: bool = False, shard_factor: int = 1,
+                 shard_factor: int = 1,
                  cache_dtype: Optional[str] = None,
                  metrics: Optional[MetricsRegistry] = None,
                  tracer: Optional[FlightRecorder] = None):
@@ -152,12 +130,7 @@ class KVPool:
         if cache_dtype not in (None, "int8"):
             raise ValueError(f"cache_dtype must be None or 'int8', got "
                              f"{cache_dtype!r}")
-        if cache_dtype and not paged:
-            raise ValueError("cache_dtype='int8' requires paged mode "
-                             "(the contiguous side pool stores the "
-                             "model's own K/V dtype)")
         self.block = int(block)
-        self.paged = bool(paged)
         self.cache_dtype = cache_dtype
         self.shard_factor = max(1, int(shard_factor))
         # flight recorder (trace.py): eviction/publish instants on the
@@ -165,11 +138,8 @@ class KVPool:
         self._tracer = tracer
         self.budget_bytes = int(budget_bytes)
         per_block = 0
-        shapes = {}
-        for key, st in attn_states.items():
+        for st in attn_states.values():
             row_shape = tuple(st["k"].shape[2:])  # (Hkv, Dh)
-            dtype = st["k"].dtype
-            shapes[key] = (row_shape, dtype)
             if cache_dtype == "int8":
                 # int8 KV pages + one f32 dequant scale per (position,
                 # head) row: Hkv*Dh bytes of values + Hkv*4 of scales
@@ -178,7 +148,7 @@ class KVPool:
                 row_bytes = int(math.prod(row_shape)) \
                     + int(row_shape[0]) * 4
             else:
-                row_bytes = int(jnp.dtype(dtype).itemsize) \
+                row_bytes = int(jnp.dtype(st["k"].dtype).itemsize) \
                     * int(math.prod(row_shape))
             per_block += 2 * self.block * row_bytes
         # per-DEVICE block cost: the head axis splits evenly over the
@@ -189,13 +159,6 @@ class KVPool:
         total = self.budget_bytes // per_block if per_block else 0
         # one block of the budget is the scratch row
         self.capacity_blocks = max(0, int(total) - 1)
-        self.storage: Dict = {}
-        if self.capacity_blocks > 0 and not self.paged:
-            n = self.capacity_blocks + 1
-            self.storage = {
-                key: {"k": jnp.zeros((n, self.block) + row_shape, dtype),
-                      "v": jnp.zeros((n, self.block) + row_shape, dtype)}
-                for key, (row_shape, dtype) in shapes.items()}
         self._free: List[int] = list(range(1, self.capacity_blocks + 1))
         self._root = _Node((), SCRATCH_BLOCK, None)
         self._root.hash = ""
@@ -210,29 +173,22 @@ class KVPool:
         if metrics is not None:
             self._m_evicted = metrics.counter(
                 "prefix_cache_evicted_blocks_total")
-            if self.paged:
-                # unified-pool occupancy: live = every allocated block
-                # (slot-owned + trie-cached), free = the free list. The
-                # utilization ratio is derived at snapshot time so it can
-                # never go stale between scrapes.
-                self._g_live = metrics.gauge("kv_pool_blocks_live")
-                self._g_free = metrics.gauge("kv_pool_blocks_free")
-                cap_g = metrics.gauge("kv_pool_blocks_capacity")
-                cap_g.set(self.capacity_blocks)
-                metrics.ratio("kv_pool_utilization", self._g_live, cap_g)
-                # per-DEVICE pool footprint (scratch included): under a
-                # tp mesh each device holds its head slice of every
-                # page, so used bytes track utilization per device
-                metrics.gauge("kv_pool_device_bytes").set(
-                    (self.capacity_blocks + 1) * self.bytes_per_block)
-                self._g_dev_used = metrics.gauge(
-                    "kv_pool_device_used_bytes")
-                self._sync_gauges()
-            else:
-                self._m_used = metrics.gauge("prefix_cache_used_bytes")
-                cap = metrics.gauge("prefix_cache_capacity_bytes")
-                cap.set((self.capacity_blocks + 1) * per_block
-                        if self.capacity_blocks else 0)
+            # pool occupancy: live = every allocated block (slot-owned
+            # + trie-cached), free = the free list. The utilization
+            # ratio is derived at snapshot time so it can never go stale
+            # between scrapes.
+            self._g_live = metrics.gauge("kv_pool_blocks_live")
+            self._g_free = metrics.gauge("kv_pool_blocks_free")
+            cap_g = metrics.gauge("kv_pool_blocks_capacity")
+            cap_g.set(self.capacity_blocks)
+            metrics.ratio("kv_pool_utilization", self._g_live, cap_g)
+            # per-DEVICE pool footprint (scratch included): under a tp
+            # mesh each device holds its head slice of every page, so
+            # used bytes track utilization per device
+            metrics.gauge("kv_pool_device_bytes").set(
+                (self.capacity_blocks + 1) * self.bytes_per_block)
+            self._g_dev_used = metrics.gauge("kv_pool_device_used_bytes")
+            self._sync_gauges()
 
     # -- host-side bookkeeping ---------------------------------------------
     def _tick(self) -> int:
@@ -257,7 +213,7 @@ class KVPool:
         if self._g_live is not None:
             self._g_live.set(self.used_blocks)
             self._g_free.set(len(self._free))
-            self._g_dev_used.set(self.used_blocks * self.bytes_per_block)
+            self._g_dev_used.set(self.used_bytes)
 
     @property
     def free_blocks(self) -> int:
@@ -321,7 +277,7 @@ class KVPool:
         """Descend the deepest cached prefix of ``tokens`` (full blocks
         only, capped at ``max_blocks``), ticking ``last_access`` on the
         path — the single definition of the trie walk shared by
-        :meth:`match` / :meth:`insert` / :meth:`adopt`. Returns the
+        :meth:`match` / :meth:`adopt`. Returns the
         deepest node and the block ids along the path."""
         node, ids = self._root, []
         B = self.block
@@ -353,7 +309,7 @@ class KVPool:
             raise AssertionError("release() without a matching reference")
         node.lock -= 1
 
-    # -- paged mode: the pool as the live decode cache ----------------------
+    # -- the pool as the live decode cache ----------------------------------
     def alloc(self) -> Optional[int]:
         """One free block for a slot's table (lazy allocation as ``pos``
         crosses a block boundary), LRU-evicting unreferenced cached
@@ -420,154 +376,34 @@ class KVPool:
         return len(self._free) + sum(
             1 for n in self._walk() if id(n) not in pinned)
 
-    # -- insertion / eviction ----------------------------------------------
-    def insert(self, tokens: Sequence[int]) -> Tuple[int, List[int]]:
-        """Index ``tokens`` (length a multiple of ``block``): walk the
-        existing prefix, then allocate blocks for the missing suffix.
-        Returns ``(start_block, new_block_ids)`` — the caller must copy
-        the slot's cache rows ``[start*block, (start+len(ids))*block)``
-        into those storage rows *before* the next admission can match
-        them (trivially true on the single scheduler thread). Allocation
-        is best-effort: when eviction cannot free a block (everything
-        referenced), the suffix is simply not cached."""
-        B = self.block
-        n_total = len(tokens) // B
-        node, matched = self._walk_prefix(tokens, n_total)
-        start, new_ids, pinned = len(matched), [], []
-        if node is not self._root:
-            node.lock += 1  # pin the extension point against eviction
-            pinned.append(node)
-        try:
-            # amortized: free everything this publish needs in ONE trie
-            # walk instead of one walk per allocated block
-            need = (n_total - start) - len(self._free)
-            if need > 0:
-                self._evict_lru(need)
-            for j in range(start, n_total):
-                bid = self._alloc()
-                if bid is None:
-                    break
-                key = tuple(int(t) for t in tokens[j * B:(j + 1) * B])
-                child = _Node(key, bid, node)
-                node.children[key] = child
-                self._hash_and_publish(child)
-                node = child
-                node.last_access = self._tick()
-                node.lock += 1  # keep the fresh chain out of eviction
-                pinned.append(node)
-                new_ids.append(bid)
-        finally:
-            for n in pinned:
-                n.lock -= 1
-        if self._metrics is not None:
-            if self.paged:
-                self._sync_gauges()
-            else:
-                self._m_used.set(self.used_bytes)
-        if new_ids and self._tracer is not None:
-            self._tracer.instant("pool_publish", track="kvpool",
-                                 args={"blocks": len(new_ids),
-                                       "used_blocks": self.used_blocks})
-        return start, new_ids
-
+    # -- eviction -----------------------------------------------------------
     def _alloc(self) -> Optional[int]:
         if not self._free:
             self._evict_lru()
         return self._free.pop() if self._free else None
 
-    def _evict_lru(self, want: int = 1) -> None:
-        """Free up to ``want`` blocks, least-recently-used unreferenced
-        LEAVES first, in one trie walk (a heap over the candidates;
-        a parent whose last child goes becomes a candidate itself).
-        Interior nodes are never evicted directly — their children would
-        become unreachable prefixes."""
-        heap = [(n.last_access, id(n), n) for n in self._walk()
-                if not n.children and not n.lock]
-        heapq.heapify(heap)
-        freed = 0
-        while heap and freed < want:
-            _, _, victim = heapq.heappop(heap)
-            parent = victim.parent
-            del parent.children[victim.key]
-            if self.tier is not None:
-                # demotion interception: capture the page row BEFORE the
-                # id returns to the free list (the captured device
-                # snapshot has buffers of its own and is dispatched
-                # before any later write of the pool, so the reused id
-                # can be rewritten immediately)
-                self.tier.offer_spill(victim.hash, victim.block_id)
-            self._free.append(victim.block_id)
-            freed += 1
-            if parent is not self._root and not parent.children \
-                    and not parent.lock:
-                heapq.heappush(heap,
-                               (parent.last_access, id(parent), parent))
-        if freed and self._metrics is not None:
-            self._m_evicted.inc(freed)
-            if self.paged:
-                self._sync_gauges()
-            else:
-                self._m_used.set(self.used_bytes)
-        if freed and self._tracer is not None:
+    def _evict_lru(self) -> None:
+        """Free one block: the least-recently-used unreferenced LEAF, if
+        there is one. Interior nodes are never evicted directly — their
+        children would become unreachable prefixes (a parent whose last
+        child went is a leaf for the next call)."""
+        victim = min((n for n in self._walk()
+                      if not n.children and not n.lock),
+                     key=lambda n: n.last_access, default=None)
+        if victim is None:
+            return
+        del victim.parent.children[victim.key]
+        if self.tier is not None:
+            # demotion interception: capture the page row BEFORE the id
+            # returns to the free list (the captured device snapshot has
+            # buffers of its own and is dispatched before any later write
+            # of the pool, so the reused id can be rewritten immediately)
+            self.tier.offer_spill(victim.hash, victim.block_id)
+        self._free.append(victim.block_id)
+        if self._metrics is not None:
+            self._m_evicted.inc()
+            self._sync_gauges()
+        if self._tracer is not None:
             self._tracer.instant("pool_evict", track="kvpool",
-                                 args={"blocks": freed,
+                                 args={"blocks": 1,
                                        "used_blocks": self.used_blocks})
-
-
-# -- jitted program bodies (the engine jits these once per pow2 bucket) ----
-def gather_blocks(states, slot1, idx, nblk1, storage, *, block):
-    """Restore a cached prefix into one slot's contiguous cache rows.
-
-    ``idx``: int32 [bucket] pool block ids, padded past ``nblk1[0]`` with
-    :data:`SCRATCH_BLOCK` — the padded rows land at ``[nblk*block,
-    bucket*block)``, beyond the restored ``pos``, so they are causally
-    invisible and overwritten by the cold-suffix prefill exactly like
-    chunked-prefill padding. ``slot1``/``nblk1`` are 1-element int32
-    arrays (explicit transfers, the engine's transfer-guard contract).
-    One XLA program per idx-length bucket; returns the updated states.
-    """
-    slot = slot1[0]
-    nblk = nblk1[0]
-    out = dict(states)
-    for key, store in storage.items():
-        st = states[key]
-        nb = idx.shape[0]
-        rows_k = store["k"][idx].reshape((1, nb * block) + st["k"].shape[2:])
-        rows_v = store["v"][idx].reshape((1, nb * block) + st["v"].shape[2:])
-        kc = jax.lax.dynamic_update_slice(st["k"], rows_k, (slot, 0, 0, 0))
-        vc = jax.lax.dynamic_update_slice(st["v"], rows_v, (slot, 0, 0, 0))
-        pos = jax.lax.dynamic_update_slice(
-            st["pos"], jnp.reshape(nblk * block, (1,)).astype(st["pos"].dtype),
-            (slot,))
-        out[key] = {**st, "k": kc, "v": vc, "pos": pos}
-    return out
-
-
-def scatter_blocks(states, slot1, start1, idx, storage, *, block):
-    """Publish one slot's prompt rows ``[start*block, (start+nb)*block)``
-    into pool storage rows ``idx`` (int32 [nb], exact — no padding: the
-    engine covers the new-block suffix with a greedy descending-bucket
-    walk, so every id is real). The update is functional ``.at[idx].set``
-    (copy-on-write semantics: a reader of the input arrays is never
-    aliased by the write); the engine jits this with the storage argument
-    DONATED so XLA updates the pool in place instead of re-materializing
-    the whole byte budget per call — safe because all restore gathers
-    against the old buffers were dispatched earlier on the same thread
-    and XLA orders them before the donated write. Returns the updated
-    storage pytree."""
-    slot = slot1[0]
-    start = start1[0]
-    new_storage = {}
-    for key, store in storage.items():
-        st = states[key]
-        nb = idx.shape[0]
-        tail = st["k"].shape[2:]
-        rows_k = jax.lax.dynamic_slice(
-            st["k"], (slot, start * block, 0, 0), (1, nb * block) + tail)
-        rows_v = jax.lax.dynamic_slice(
-            st["v"], (slot, start * block, 0, 0), (1, nb * block) + tail)
-        new_storage[key] = {
-            "k": store["k"].at[idx].set(rows_k.reshape((nb, block) + tail)),
-            "v": store["v"].at[idx].set(rows_v.reshape((nb, block) + tail)),
-        }
-    return new_storage

@@ -6,7 +6,7 @@ the *serving* hot path so model size and KV-pool capacity scale past one
 chip's HBM. Everything is pure annotation: params, variables, and the
 engine's carried state pytree are placed with `NamedSharding`s on a 1-D
 ``tp`` mesh, and GSPMD partitions the existing jitted decode / prefill /
-restore / COW program families — no program body changes.
+COW program families — no program body changes.
 
 Sharding plan (the weight-update-sharding / array-redistribution papers,
 arxiv 2004.13336 / 2112.01075: pick shardings so the steady-state loop
@@ -143,14 +143,6 @@ def state_shardings(states, mesh: Mesh, axis: str = TP_AXIS):
         else:
             out[key] = jax.tree_util.tree_map(lambda _: repl, st)
     return out
-
-
-def storage_shardings(storage, mesh: Mesh, axis: str = TP_AXIS):
-    """Shardings for the contiguous-mode side prefix pool's storage
-    (``{layer: {"k"/"v": [n_blocks, block, Hkv, Dh]}}``): same head-axis
-    split as the live cache, so restore's block gather never reshards."""
-    head = NamedSharding(mesh, P(None, None, axis))
-    return jax.tree_util.tree_map(lambda _: head, storage)
 
 
 def paged_kernel_shard_specs(axis: str = TP_AXIS) -> Dict[str, P]:

@@ -565,7 +565,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--prefill-chunk", type=int, default=16)
     ap.add_argument("--kv-block", type=int, default=16)
     ap.add_argument("--kv-pool-mb", type=float, default=0.0)
-    ap.add_argument("--prefix-cache-mb", type=float, default=0.0)
     ap.add_argument("--kv-dtype", default=None)
     ap.add_argument("--paged-kernel", choices=["auto", "on", "off"],
                     default="auto")
@@ -609,7 +608,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         net=net, port=args.port, decode_vocab=vocab,
         decode_slots=args.slots, prefill_chunk=args.prefill_chunk,
         kv_block=args.kv_block, kv_pool_mb=args.kv_pool_mb,
-        prefix_cache_mb=args.prefix_cache_mb, kv_dtype=args.kv_dtype,
+        kv_dtype=args.kv_dtype,
         paged_kernel=args.paged_kernel,
         host_cache_mb=args.host_cache_mb,
         disk_cache_mb=args.disk_cache_mb, tier_dir=args.tier_dir,

@@ -25,9 +25,8 @@ continuous-batching decode with chunked prefill — behind `POST /generate`.
 layout (`inference/kvpool.py`): all slots share one block pool, so slot
 capacity is bounded by pool bytes instead of ``slots × max_cache_len``,
 prompt prefixes restore as zero-copy block-table remaps, and cold slots
-are preempted-and-resumed under pool pressure. ``prefix_cache_mb``
-(`--prefix-cache-mb MB`) is the contiguous-mode side prefix cache,
-ignored when the paged pool is on. The scheduler's metrics (TTFT,
+are preempted-and-resumed under pool pressure; the default contiguous
+layout carries no prefix cache. The scheduler's metrics (TTFT,
 prefill tokens, chunk sizes, prefix hit rate, pool occupancy,
 preemptions, cancellations) land in the same registry as the
 request-path metrics, so `GET /metrics` and the UI `/serving` page show
@@ -216,7 +215,7 @@ class InferenceServer:
                  default_timeout_ms: Optional[float] = None,
                  decode_vocab: Optional[int] = None, decode_slots: int = 4,
                  prefill_chunk: int = 64, decode_queue: int = 64,
-                 prefix_cache_mb: float = 0.0, kv_block: int = 16,
+                 kv_block: int = 16,
                  kv_pool_mb: float = 0.0, kv_dtype: Optional[str] = None,
                  paged_kernel: str = "auto",
                  host_cache_mb: float = 0.0, disk_cache_mb: float = 0.0,
@@ -251,7 +250,6 @@ class InferenceServer:
         self.decode_slots = int(decode_slots)
         self.prefill_chunk = int(prefill_chunk)
         self.decode_queue = int(decode_queue)
-        self.prefix_cache_mb = float(prefix_cache_mb)
         self.kv_block = int(kv_block)
         self.kv_pool_mb = float(kv_pool_mb)
         self.kv_dtype = kv_dtype
@@ -412,7 +410,6 @@ class InferenceServer:
             self.net, self.decode_vocab, n_slots=self.decode_slots,
             max_queue=self.decode_queue,
             prefill_chunk=self.prefill_chunk,
-            prefix_cache_mb=self.prefix_cache_mb,
             kv_block=self.kv_block,
             kv_pool_mb=self.kv_pool_mb,
             kv_dtype=self.kv_dtype,

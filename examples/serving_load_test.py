@@ -325,9 +325,9 @@ def main_generate(n_threads=4, reqs_each=4, prompt_len=48, new_tokens=12,
                   zipf=0, zipf_s=1.1, prefix_len=None,
                   host_cache_mb=0.0):
     """Drive POST /generate and show where each request's time went.
-    ``mesh`` > 1: tensor-parallel decode over that many devices, paged
-    KV pool (per-device budget) instead of the contiguous prefix
-    cache.
+    Served from the paged KV pool (its trie is the prefix cache);
+    ``mesh`` > 1: tensor-parallel decode over that many devices, the
+    pool's budget per device.
 
     Fleet telemetry (ISSUE 12): every request carries a propagated
     ``X-Graft-Trace`` context and records a CLIENT-side span (send ->
@@ -343,14 +343,11 @@ def main_generate(n_threads=4, reqs_each=4, prompt_len=48, new_tokens=12,
 
     vocab = 32
     net = _make_lm(vocab, cache=prompt_len + new_tokens)
-    kw = (dict(kv_pool_mb=4.0, decode_tp=mesh) if mesh and mesh > 1
-          else dict(prefix_cache_mb=16))
-    if host_cache_mb and host_cache_mb > 0:
-        # KV tiering needs the paged pool; a deliberately tight HBM
-        # budget makes the host ring actually absorb evictions
-        kw = dict(kv_pool_mb=kw.get("kv_pool_mb", 1.0),
-                  decode_tp=mesh if mesh and mesh > 1 else 0,
-                  host_cache_mb=host_cache_mb)
+    tp = mesh if mesh and mesh > 1 else 0
+    # one device: a deliberately tight HBM budget, so that with
+    # --host-cache-mb the host ring actually absorbs evictions
+    kw = dict(kv_pool_mb=4.0 if tp else 1.0, decode_tp=tp,
+              host_cache_mb=host_cache_mb or 0.0)
     srv = InferenceServer(net=net, decode_vocab=vocab, decode_slots=4,
                           prefill_chunk=16, kv_block=8, **kw).start()
     rng = np.random.default_rng(0)
@@ -515,7 +512,7 @@ def main_fleet(n_replicas=2, n_threads=4, reqs_each=8, prompt_len=48,
     argv = lm_spec_argv(vocab=vocab, d_model=32, n_heads=4, n_blocks=2,
                         cache=prompt_len + new_tokens + 16) + [
         "--slots", "4", "--prefill-chunk", "16",
-        "--prefix-cache-mb", "16", "--kv-block", "8"]
+        "--kv-pool-mb", "0.5", "--kv-block", "8"]
     print(f"spawning {n_replicas} replica process(es) + router "
           "(each replica pays a JAX import + warmup)...")
     sup = ReplicaSupervisor(

@@ -30,6 +30,27 @@ def test_every_configuration_resolves_to_its_family(entry):
             assert callable(getattr(getattr(fam, part), f)), (part, f)
 
 
+WORKLOADS = sorted((REPO / "benchmark" / "workloads").glob("*.json")) + [
+    REPO / "benchmark" / "tests" / "data" / "tiny" / "workload.json"]
+
+
+@pytest.mark.parametrize("path", WORKLOADS,
+                         ids=lambda p: p.parent.name + "/" + p.stem)
+def test_the_scheduler_takes_every_workloads_engine_block_whole(path):
+    """A workload file's `engine` block goes to `DecodeScheduler` as
+    keywords, whole: an option leaves the constructor only after a
+    `benchmark` PR has taken it out of these files, or every cell fails
+    at set-up (ROADMAP Design 5: `paged_kernel`, `mask_rows`). And a
+    cell is served from the paged pool."""
+    import inspect
+
+    from deeplearning4j_tpu.inference import DecodeScheduler
+    engine = json.loads(path.read_text())["engine"]
+    inspect.signature(DecodeScheduler.__init__).bind(
+        None, "net", 320, **engine)
+    assert engine["kv_pool_mb"] > 0
+
+
 def test_the_benchmark_has_two_families():
     types = {json.loads((REPO / c["file"]).read_text())["model_type"]
              for c in BENCH["configs"]}

@@ -51,7 +51,7 @@ def _replica_argv():
     return lm_spec_argv(vocab=V, d_model=16, n_heads=2, n_blocks=2,
                         cache=96) + [
         "--slots", "2", "--prefill-chunk", "16",
-        "--prefix-cache-mb", "8", "--kv-block", str(KV_BLOCK),
+        "--kv-pool-mb", "0.125", "--kv-block", str(KV_BLOCK),
         "--hang-timeout", "5", "--retry-budget", "6"]
 
 

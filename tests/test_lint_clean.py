@@ -189,7 +189,7 @@ def test_runtime_lock_orders_match_static_graph_on_live_serving():
         net = ComputationGraph(conf).init()
         m = MetricsRegistry()
         eng = DecodeScheduler(net, V, n_slots=2, prefill_chunk=16,
-                              prefix_cache_mb=1.0, kv_block=8,
+                              kv_pool_mb=1.0, kv_block=8,
                               metrics=m).start()
         audit = CompileCounter.for_scheduler(eng)
         try:
@@ -200,12 +200,11 @@ def test_runtime_lock_orders_match_static_graph_on_live_serving():
                                   list(rng.integers(0, V, 4))])]
             for h in handles:
                 h.result(120)
-            eng.submit(repeat, 3).result(120)  # prefix hit -> restore
+            eng.submit(repeat, 3).result(120)  # prefix hit -> table remap
         finally:
             eng.stop()
         audit.assert_within_budget()
-        assert audit.count("prefix_restore") >= 1
-        assert audit.count("prefix_publish") >= 1
+        assert audit.count("restore_setpos") == 1
         assert m.counter("prefix_cache_hits_total").value >= 1
         mb = MicroBatcher(lambda a: a * 2, max_batch=8, metrics=m).start()
         try:
@@ -255,7 +254,7 @@ def test_runtime_happens_before_checker_clean_on_live_serving():
         net = ComputationGraph(conf).init()
         m = MetricsRegistry()
         eng = DecodeScheduler(net, V, n_slots=2, prefill_chunk=16,
-                              prefix_cache_mb=1.0, kv_block=8,
+                              kv_pool_mb=1.0, kv_block=8,
                               metrics=m).start()
         det.watch(eng, ["_states", "_prefill_next", "_emitted_this_iter"],
                   label="engine")
@@ -270,7 +269,7 @@ def test_runtime_happens_before_checker_clean_on_live_serving():
                                   list(rng.integers(0, V, 4))])]
             for h in handles:
                 h.result(120)
-            eng.submit(repeat, 3).result(120)  # prefix hit -> restore
+            eng.submit(repeat, 3).result(120)  # prefix hit -> table remap
         finally:
             eng.stop()  # joins the scheduler thread: orders the reads below
         assert hist.count > 0 and hist.snapshot()["count"] > 0

@@ -205,22 +205,30 @@ def test_only_a_prompts_last_chunk_waits_and_reads(eng):
 
 
 class _Span:
-    made, stopped = [], []
+    """Stands in for the profiler's annotations and records those of the
+    test's own thread. The patch is the module's, so the module-scoped
+    `eng`'s live scheduler thread comes through here too: it idles every
+    0.1 s, and its `sched/idle` is not this test's."""
+    made, stopped, thread = [], [], None
 
     def __init__(self, name, **kwargs):
         self.name = name
-        _Span.made.append(name)
+        self.mine = threading.get_ident() == _Span.thread
+        if self.mine:
+            _Span.made.append(name)
 
     def __enter__(self):
         return self
 
     def __exit__(self, *exc):
-        _Span.stopped.append(self.name)
+        if self.mine:
+            _Span.stopped.append(self.name)
 
 
 @pytest.fixture
 def spans(monkeypatch):
     _Span.made, _Span.stopped = [], []
+    _Span.thread = threading.get_ident()
     monkeypatch.setattr(profiler_mod, "TraceAnnotation", _Span)
     monkeypatch.setattr(profiler_mod, "StepTraceAnnotation", _Span)
     return _Span

@@ -115,17 +115,16 @@ def test_seeded_sampling_prefix_restore_and_cow_identical(net):
 
 
 @pytest.mark.slow
-def test_contiguous_mode_and_prefix_pool_sharded(net, solo):
-    """The contiguous layout (per-slot stripes + side prefix pool with
-    head-sharded storage) runs the mesh too: cold decode and the
-    gather-restored repeat match solo."""
+def test_contiguous_mode_sharded(net, solo):
+    """The contiguous layout (per-slot stripes, head-sharded, no pool)
+    runs the mesh too: a decode and its repeat in the reused slot match
+    solo."""
     prompts, expect = solo
     eng = DecodeScheduler(net, V, n_slots=2, prefill_chunk=16,
-                          prefix_cache_mb=_pool_mb(32, 8, 2), kv_block=8,
                           mesh=2, metrics=MetricsRegistry(),
                           transfer_guard="disallow").start()
     try:
-        assert eng.tp == 2 and not eng.paged and eng.pool is not None
+        assert eng.tp == 2 and not eng.paged and eng.pool is None
         assert eng.generate(prompts[2], 6, timeout=120) == expect[2]
         assert eng.generate(prompts[2], 6, timeout=120) == expect[2]
     finally:

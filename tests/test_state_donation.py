@@ -56,7 +56,7 @@ ENGINES = {
     "int8": dict(kv_pool_mb=_pool_mb(32), kv_block=B, kv_dtype="int8"),
     "spec": dict(kv_pool_mb=_pool_mb(32), kv_block=B, speculate=3),
     "tiered": dict(kv_pool_mb=_pool_mb(32), kv_block=B, host_cache_mb=4.0),
-    "contiguous": dict(prefix_cache_mb=1.0, kv_block=B),
+    "contiguous": dict(),
     "contiguous_spec": dict(speculate=3),
     "tp2": dict(kv_pool_mb=_pool_mb(32, tp=2), kv_block=B, mesh=2),
 }
@@ -147,11 +147,6 @@ def _lowered(eng, family):
     if family == "tier_restore":
         rows = eng._jtier_spill(st, s0)
         return eng._jtier_restore.lower(st, s0, rows), st
-    if family == "restore":
-        bucket = arr(np.full((eng.restore_buckets[0],), SCRATCH_BLOCK,
-                             np.int32))
-        return eng._jrestore.lower(st, s0, bucket, one,
-                                   eng.pool.storage), st
     if family == "verify":
         return eng._jverify.lower(p, v, ids2, live, *table, st), st
     if family == "verify_masked":
@@ -184,7 +179,6 @@ CASES = [
     ("spec", "draft_fixpos"),
     ("contiguous", "step"), ("contiguous", "prefill"),
     ("contiguous", "step_masked"), ("contiguous", "zero"),
-    ("contiguous", "restore"),
     ("contiguous_spec", "verify"), ("contiguous_spec", "fixpos"),
     ("tp2", "step"), ("tp2", "prefill"), ("tp2", "zero"), ("tp2", "cow"),
 ]
@@ -208,20 +202,12 @@ def test_program_aliases_the_carried_state(built, kind, family,
 
 
 def test_readers_of_the_state_do_not_donate(built):
-    """`_jtier_spill` returns slices and `_jpublish` copies a slot's rows
-    into the side pool: the state lives on, so neither may consume it
-    (publish donates the side pool's storage, its argument 4)."""
+    """`_jtier_spill` returns slices of the pool: the state lives on, so
+    it may not consume it."""
     eng = built("tiered")
     spill = eng._jtier_spill.lower(eng._states, eng._dev_index(0))
     assert not any(a.donated for a in
                    jax.tree_util.tree_leaves(spill.args_info))
-    eng = built("contiguous")
-    idx = eng._dev_array(np.zeros((eng.restore_buckets[0],), np.int32))
-    publish = eng._jpublish.lower(eng._states, eng._dev_index(0),
-                                  eng._dev_index(0), idx, eng.pool.storage)
-    (st, _, _, _, storage), _ = publish.args_info
-    assert not any(a.donated for a in jax.tree_util.tree_leaves(st))
-    assert all(a.donated for a in jax.tree_util.tree_leaves(storage))
 
 
 @pytest.mark.parametrize("kind", sorted(ENGINES))
@@ -250,7 +236,7 @@ def test_traffic_consumes_old_state_and_tokens_match_solo(
     eng.start()
     try:
         assert eng.submit(prompt, 6).result(300) == expect
-        # again: a prefix hit (restore / table remap + copy-on-write)
+        # again: in a pool, a prefix hit (table remap + copy-on-write)
         assert eng.submit(prompt, 6).result(300) == expect
     finally:
         eng.stop()

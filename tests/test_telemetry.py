@@ -15,6 +15,7 @@ import bisect
 import json
 import random
 import socket
+import time
 import urllib.error
 import urllib.request
 
@@ -271,9 +272,15 @@ def test_propagated_context_stamps_rpc_span(_server):
     assert b["args"]["hop"] == 0
     assert "net_gap_ms" in b["args"]
     # the matching close on the same request track (end() carries no
-    # context fields — the flow edge lives on the B only)
-    assert any(e["ph"] == "E" and e["name"] == "rpc"
-               and e["track"] == b["track"] for e in evs)
+    # context fields — the flow edge lives on the B only). The handler
+    # writes it in its `finally`, AFTER the response went out: the
+    # client can be here first, so wait for the handler thread
+    deadline = time.monotonic() + 30
+    while not any(e["ph"] == "E" and e["name"] == "rpc"
+                  and e["track"] == b["track"]
+                  for e in _server.tracer.events()):
+        assert time.monotonic() < deadline, "the rpc span never closed"
+        time.sleep(0.005)
 
 
 # --------------------------------------- two-process merge acceptance --
