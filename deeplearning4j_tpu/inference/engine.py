@@ -482,18 +482,23 @@ class DecodeScheduler:
     so a fixed ``kv_pool_mb`` holds 2x+ the blocks. Lossy: decode is
     plausible but not bit-identical to the f32 cache. Paged mode only.
 
-    ``paged_kernel``: fused Pallas decode-kernel mode (ISSUE 15),
-    paged layouts only. ``"auto"`` (default) lets the
-    ops/pallas_kernels per-shape autotune pick the FlashDecoding-style
-    page-walk kernel or the XLA gather per decode table bucket (silent
-    XLA fallback when no kernel is registered — `pallas_kernels.
-    enable()` arms it); ``"on"`` forces the kernel on every supported
-    T=1 decode shape; ``"off"`` pins the XLA gather path. Either way
-    prefill chunks, verify programs, and K/V writes stay in XLA, the
-    decision is trace-time (no extra programs — decode stays <= 1
-    program per table bucket), and outputs are token-identical by the
-    seam contract. `paged_kernel_engaged` gauge + the ``paged_kernel``
-    block of :meth:`debug_snapshot` report the per-bucket verdicts.
+    ``paged_kernel``: how the T=1 decode step reads the paged cache.
+    ``"auto"`` (default): on a TPU, in bfloat16 or float32, off a mesh
+    and with pages that are not int8, the fused paged read
+    (ops/paged_read.py, ISSUE 31/33: a step reads the pages its fed
+    slots hold rows in and no others), by the layer's own static rule
+    (`fused_read_engages`); elsewhere the XLA gather at the table
+    bucket's width, or, for what the rule leaves (int8 pages, a mesh)
+    in float32 with `pallas_kernels.enable()`, the older per-shape
+    autotuned page-walk kernel (ISSUE 15). ``"on"`` takes the fused
+    read on any backend (interpreted off the TPU) and forces the older
+    kernel where only that applies; ``"off"`` pins the XLA gather.
+    Either way prefill chunks, verify programs, and K/V writes stay in
+    XLA and the decision is trace-time (no extra programs — decode
+    stays <= 1 program per table bucket). `paged_kernel_engaged` gauge
+    + the ``paged_kernel`` block of :meth:`debug_snapshot` report the
+    per-bucket verdicts; `kv_pages_read_total` over
+    `kv_pages_bucket_total` says how much of the tables a step read.
 
     ``transfer_guard``: device-residency audit mode. When set (e.g.
     "disallow"), every scheduler iteration runs under that thread-local
@@ -1102,6 +1107,26 @@ class DecodeScheduler:
             # best-of-n COW forks: candidates that attached to a fork
             # group's published prompt blocks (zero-copy remaps)
             self._m_forks = m.counter("decode_forks_total")
+        if self.paged:
+            # per decode table bucket: whether the T=1 read goes through
+            # `ops.paged_read` (the layer's own static rule, asked once)
+            self._fused_read = {nb: self._fused_read_engages(nb)
+                                for nb in self.table_buckets}
+            # pages a decode dispatch names (every slot's page list at
+            # the bucket's width) and pages it reads, from host-side
+            # depths: equal unless the fused read engages. Named for what
+            # a page list is: the block table (`kv_`), or EVA's
+            # open-window pages and summary pages (`eva_`)
+            kind = "kv" if self._eva is None else "eva"
+            self._m_pages_bucket = m.counter(
+                f"{kind}_pages_bucket_total",
+                help="pages in the page lists of decode dispatches: slots "
+                     "x the pages a slot's list names at the table bucket")
+            self._m_pages_read = m.counter(
+                f"{kind}_pages_read_total",
+                help="pages decode dispatches read: those holding a row a "
+                     "fed slot attends over where the fused read engages, "
+                     "else the bucket's")
         if self._eva is not None:
             self._m_eva_rolled = m.counter(
                 "eva_windows_rolled_total",
@@ -1117,19 +1142,6 @@ class DecodeScheduler:
                 "eva_rows_summary_total",
                 help="chunk-summary rows of closed windows attended by "
                      "decode tokens")
-            # pages a decode dispatch names (its bucket's page list, every
-            # slot) and pages it reads: equal unless the fused read engages
-            self._m_eva_pages_bucket = m.counter(
-                "eva_pages_bucket_total",
-                help="pages in the page lists of decode dispatches: slots "
-                     "x (open-window pages + summary pages of the bucket)")
-            self._m_eva_pages_read = m.counter(
-                "eva_pages_read_total",
-                help="pages decode dispatches read: those holding a row a "
-                     "fed slot attends over where the fused read engages, "
-                     "else the bucket's")
-            self._eva_fused = self._attn_impl.fused_read_engages(
-                self.paged_kernel, 1, self._dtype, self.mesh)
             self._m_publish_skipped = m.counter(
                 "prefix_publish_skipped_total",
                 help="finished prompts the prefix trie did not adopt: "
@@ -1821,6 +1833,33 @@ class DecodeScheduler:
             window = self._eva[0]
             held = max(held, self._blocks_held((depth - 1) // window * window))
         return held
+
+    def _pages_listed(self, nb: int) -> int:
+        """Pages a slot's page list names in a T=1 read at table bucket
+        ``nb``: the table itself, or EVA's open-window pages and summary
+        pages of the bucket."""
+        if self._eva is None:
+            return nb
+        window, chunk = self._eva
+        return min(window // self.kv_block, nb) + -(-nb // chunk)
+
+    def _pages_with_rows(self, written: int) -> int:
+        """Of those, the pages holding a row that a slot ``written``
+        positions deep attends over in its next step."""
+        bk = self.kv_block
+        if self._eva is None:
+            return -(-(written + 1) // bk)
+        window, chunk = self._eva
+        return (-(-(written % window + 1) // bk)
+                + -(-(written // window * (window // chunk)) // bk))
+
+    def _fused_read_engages(self, nb: int) -> bool:
+        """The layer's rule for the fused paged read (`ops/paged_read`),
+        on what the engine knows of its decode program at bucket ``nb``."""
+        return self.kv_dtype != "int8" and self._attn_impl.fused_read_engages(
+            self.paged_kernel, 1, self._dtype, self.mesh,
+            slots=self.n_slots, pages=self._pages_listed(nb),
+            block=self.kv_block)
 
     def _table_for(self, max_pos: int) -> np.ndarray:
         """The host table sliced to the pow2 bucket covering ``max_pos``
@@ -3380,6 +3419,12 @@ class DecodeScheduler:
                 table = self._table_for(max(s.written + 1
                                             for _, s in fed))
                 prof.count("decode", table.shape[1])
+                nb = table.shape[1]
+                named = self.n_slots * self._pages_listed(nb)
+                self._m_pages_bucket.inc(named)
+                self._m_pages_read.inc(sum(
+                    self._pages_with_rows(s.written) for _, s in fed)
+                    if self._fused_read[nb] else named)
                 if self._eva is not None:
                     window, chunk = self._eva
                     at = [s.written for _, s in fed if s.sampling]
@@ -3387,14 +3432,6 @@ class DecodeScheduler:
                         sum(t % window + 1 for t in at))
                     self._m_eva_rows_summary.inc(
                         sum(t // window for t in at) * (window // chunk))
-                    nb, bk = table.shape[1], self.kv_block
-                    named = self.n_slots * (min(window // bk, nb)
-                                            + -(-nb // chunk))
-                    self._m_eva_pages_bucket.inc(named)
-                    self._m_eva_pages_read.inc(sum(
-                        -(-(s.written % window + 1) // bk)
-                        + -(-(s.written // window * (window // chunk)) // bk)
-                        for _, s in fed) if self._eva_fused else named)
                 if mstate is not None:
                     probs, new_states = self._jstep_m(
                         self._params, self._variables,
@@ -3866,16 +3903,17 @@ class DecodeScheduler:
                "execution": None}
         if not self.paged:
             return out
-        if self._eva is not None:
-            # this layer's T=1 read is `ops.paged_read`, engaged by the
-            # layer's static rule and not through the seam's registry
-            out["engaged"] = self._eva_fused
-            out["buckets"] = {nb: "paged_read" if self._eva_fused else False
-                              for nb in self.table_buckets}
-            if self._eva_fused:
+        if self._eva is not None or any(self._fused_read.values()):
+            # the T=1 read is `ops.paged_read`, engaged by the layer's
+            # static rule and not through the seam's registry (a bucket
+            # whose page list is beyond the kernel's SMEM gathers)
+            out["buckets"] = {nb: "paged_read" if on else False
+                              for nb, on in self._fused_read.items()}
+            out["engaged"] = any(self._fused_read.values())
+            if out["engaged"]:
                 out["execution"] = ("compiled" if jax.default_backend()
                                     == "tpu" else "interpreted")
-            self._m_paged_kernel.set(1 if self._eva_fused else 0)
+            self._m_paged_kernel.set(1 if out["engaged"] else 0)
             return out
         from ..ops import helpers as ophelpers
         if (self.paged_kernel == "off"

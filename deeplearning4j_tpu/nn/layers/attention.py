@@ -278,6 +278,28 @@ class SelfAttentionLayerImpl(BaseRecurrentImpl):
         returns its geometry here, and the trie stands aside."""
         return None
 
+    def fused_read_engages(self, mode, T, dtype, mesh=None, *, slots=0,
+                           pages=0, block=1) -> bool:
+        """Whether a paged step of ``T`` tokens reads its pages through
+        `ops.paged_read.paged_read_attention` and not through the gather at
+        the bucket's width: one query row a slot, bfloat16 or float32, no
+        ``tp`` mesh, ``paged_kernel`` not ``"off"``, page lists (``slots``
+        of them, ``pages`` entries each, pages of ``block`` rows) that fit
+        the kernel's SMEM, and a TPU to compile the kernel for (``"on"``
+        takes it anywhere, interpreted off the TPU: the tests' way in).
+        Asked by `_paged_step` when it is traced and by the engine for its
+        `*_pages_read_total`."""
+        if not (mode != "off" and T == 1 and mesh is None
+                and jnp.dtype(dtype) in (jnp.bfloat16, jnp.float32)
+                and (mode == "on" or jax.default_backend() == "tpu")):
+            return False
+        # imported where it is used: Pallas costs a second to import
+        from ...ops.paged_read import list_fits
+        conf = self.conf
+        return list_fits(slots, pages, block * self._kv_heads()
+                         * (conf.n_out // conf.n_heads)
+                         * jnp.dtype(dtype).itemsize)
+
     @staticmethod
     def _page_of(table, p, Bk, wmask=None):
         """(page, offset) of the rows ``p`` ([B, T] row indices; positions
@@ -316,19 +338,23 @@ class SelfAttentionLayerImpl(BaseRecurrentImpl):
         returned (the table is host-authoritative; device state carries
         only pages + pos).
 
-        T=1 decode dispatches the attention READ through the
-        ``paged_decode_attention`` helper seam (ops/helpers.py): a
-        registered Pallas kernel (ops/pallas_kernels.py, ISSUE 15)
-        walks the block table page by page with an online softmax
-        instead of materializing the gathered cache, per-shape
-        autotuned with silent XLA fallback. The engine threads its
+        The T=1 READ on a TPU is the fused paged read
+        (`fused_read_engages`, `ops.paged_read.paged_read_attention`):
+        the table is the page list and ``rows = clip(pos + 1 - j * block,
+        0, block)`` the count of each page (0 for a lane ``wmask`` holds
+        off), so a step reads the pages its fed slots hold rows in and no
+        others, whatever the bucket's width. The engine threads its
         ``paged_kernel`` mode ("auto"/"on"/"off") and tp ``mesh`` in as
-        injected trace-time constants next to the table. The gather/
-        einsum body below STAYS the token-identity reference and the
-        fallback — prefill chunks (T > 1), unsupported shapes, and
-        autotune-picks-XLA all run it; K/V WRITES (wmask scratch
-        redirect, int8 quantize) always run here in XLA, the kernel
-        fuses only the read."""
+        injected trace-time constants next to the table. Where the rule
+        does not hold at T=1 (int8 pages, a mesh) the older page-walk
+        kernel behind the ``paged_decode_attention`` seam (ops/helpers.py,
+        ops/pallas_kernels.py, ISSUE 15; float32 only) may still take the
+        read. The gather/einsum body below STAYS the token-identity
+        reference and the fallback: prefill chunks (T > 1), ``"off"``,
+        ``"auto"`` off the TPU, a page list beyond the kernel's SMEM and
+        a seam that declines all run it. K/V WRITES (wmask scratch
+        redirect, int8 quantize) always run here in XLA; a kernel fuses
+        only the read."""
         B, T, _ = x.shape
         pos = state0["pos"]          # [B] int32 (per-slot decode depths)
         table = state0["table"]      # [B, nb] int32, padded with page 0
@@ -370,13 +396,28 @@ class SelfAttentionLayerImpl(BaseRecurrentImpl):
             kp2 = kp.at[blk, off].set(k_new)
             vp2 = vp.at[blk, off].set(v_new)
         o = None
-        if T == 1:
-            # fused page-walk decode kernel, or None = run the XLA
-            # reference below (trace-time decision — see class docstring)
+        mode = state0.get("paged_kernel", "auto")
+        if not quantized and self.fused_read_engages(
+                mode, T, q.dtype, state0.get("mesh"), slots=B, pages=nb,
+                block=Bk):
+            # rows each page holds for this query: positions up to pos,
+            # nothing for a lane off
+            rows = jnp.clip(pos[:, None] + 1
+                            - jnp.arange(nb, dtype=pos.dtype)[None, :] * Bk,
+                            0, Bk)
+            if wmask is not None:
+                rows = jnp.where(wmask[:, :1], rows, 0)
+            from ...ops.paged_read import paged_read_attention
+            with jax.named_scope("paged_attention"):
+                o = paged_read_attention(
+                    q, kp2, vp2, table, rows,
+                    interpret=jax.default_backend() != "tpu")
+        elif T == 1:
+            # the older page-walk kernel behind the seam, or None = run
+            # the XLA reference below (trace-time decision)
             o = ophelpers.paged_decode_attention(
                 q, kp2, vp2, table, pos, k_scales=ks2, v_scales=vs2,
-                mode=state0.get("paged_kernel", "auto"),
-                mesh=state0.get("mesh"))
+                mode=mode, mesh=state0.get("mesh"))
         if o is None:
             dt = q.dtype
             if quantized:
@@ -476,19 +517,6 @@ class EvaAttentionLayerImpl(SelfAttentionLayerImpl):
         Wn, C = self._geometry()
         w0 = (depth - 1) // Wn * Wn
         return -(-(depth - w0) // block) + -(-depth // (C * block))
-
-    @staticmethod
-    def fused_read_engages(mode, T, dtype, mesh=None) -> bool:
-        """Whether a paged step of ``T`` tokens reads its pages through
-        `ops.paged_read.paged_read_attention` and not through the gather at
-        the bucket's width: one query row a slot, bfloat16 or float32, no
-        ``tp`` mesh, ``paged_kernel`` not ``"off"``, and a TPU to compile
-        the kernel for (``"on"`` takes it anywhere, interpreted off the
-        TPU: the tests' way in). Asked by `_paged_step` when it is traced
-        and by the engine for `eva_pages_read_total`."""
-        return (mode != "off" and T == 1 and mesh is None
-                and jnp.dtype(dtype) in (jnp.bfloat16, jnp.float32)
-                and (mode == "on" or jax.default_backend() == "tpu"))
 
     def _summarize(self, params, k, v, ok):
         """Chunk summaries. k, v: [B, chunks, chunk, Hkv, Dh] rotated rows;
@@ -600,14 +628,14 @@ class EvaAttentionLayerImpl(SelfAttentionLayerImpl):
                                nb - 1), axis=1)
         pages = jnp.concatenate([epages, stable[:, :ns]], axis=1)
         if self.fused_read_engages(state0.get("paged_kernel", "auto"), T,
-                                   q.dtype, state0.get("mesh")):
+                                   q.dtype, state0.get("mesh"), slots=B,
+                                   pages=E + ns, block=Bk):
             # rows each page holds for this query: the open window up to
             # pos, a summary row per closed chunk, nothing for a lane off
             erows = pos[:, None] + 1 - (W[:, None] * Wn + i32(E)[None, :] * Bk)
             srows = (W * (Wn // C))[:, None] - i32(ns)[None, :] * Bk
             rows = jnp.where(wmask[:, :1], jnp.clip(
                 jnp.concatenate([erows, srows], axis=1), 0, Bk), 0)
-            # imported where it is used: Pallas costs a second to import
             from ...ops.paged_read import paged_read_attention
             with jax.named_scope("eva_attention"):
                 o = paged_read_attention(

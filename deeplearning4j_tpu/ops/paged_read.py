@@ -1,4 +1,4 @@
-"""Fused paged attention read for one query row a slot (ISSUE 31).
+"""Fused paged attention read for one query row a slot (ISSUE 31, 33).
 
 ``paged_read_attention(q, k_pages, v_pages, pages, rows)``: slot ``b``
 attends, in one softmax, over the first ``rows[b, j]`` rows of page
@@ -6,26 +6,33 @@ attends, in one softmax, over the first ``rows[b, j]`` rows of page
 nor computed; inside a fetched page the rows at or beyond the count are
 masked. Nothing here knows what a page holds: a cache of one row per
 position is the case ``rows = clip(pos + 1 - j * block, 0, block)`` over the
-block table, and `EvaAttentionLayerImpl._paged_step` passes the open
-window's pages and the summary pages with the counts of each.
+block table (`SelfAttentionLayerImpl._paged_step`), and
+`EvaAttentionLayerImpl._paged_step` passes the open window's pages and the
+summary pages with the counts of each.
 
 The kernel is one program over a compacted work list, not a grid of
-slots x pages: the XLA prologue moves the (slot, page, count) triples with a
-count above 0 to the front, in order, and the kernel walks the first ``nv``
-of them, so a step costs what it attends over and an idle slot costs
-nothing. A work item is a WHOLE page of all heads, ``[block, Hkv, Dh]``
-(EvaByte: 64 x 4,096 bfloat16 = 512 KB, one contiguous DMA each for K and
-V), double-buffered by hand so that item i + 1 is in flight, across slots
-too, while item i is computed.
+slots x pages. A work item is up to ``G`` consecutive entries of one slot's
+page list, and ``G`` follows from the page's bytes as the kernel sees them
+(`pages_per_item`: about half a megabyte an item for K and again for V, so
+16 pages of StarCoder2-3B's ``[64, 2, 128]`` bfloat16, 8 of the 7B's, ONE
+of EvaByte's 512 KB): what an item costs beyond its bytes (DMA issue and
+wait, the mask, one online-softmax update) is paid once for ``G`` pages.
+The XLA prologue sorts the groups that hold a row to the front, in (slot, j)
+order; the kernel walks the first ``nv`` of them, so a step costs what it
+attends over and an idle slot costs nothing. An item's pages are not
+neighbours in the pool: ``G`` DMAs (fewer where a page of the group has no
+row) land side by side in one ``[G * block * Hkv, Dh]`` buffer for K and one
+for V, double-buffered by hand so that item i + 1 is in flight, across
+slots too, while item i is computed.
 
 The contraction is the MXU's, on the pages as the pool lays them out: a
 page ``[block, Hkv, Dh]`` is read as the matrix ``[block * Hkv, Dh]`` (a
 free view: no relayout of the pool), every (row, KV head) pair one key.
-``scores = Q @ K^T`` is ``[H, block * Hkv]`` (products exact, float32
-accumulation), a query head keeps the keys of its own KV head and below the
-count (the mask), and ``P @ V`` is the ``[H, Dh]`` output itself. Scores,
-running max, sum and accumulator are float32; the probabilities are rounded
-to the pages' dtype for ``P @ V``, as
+``scores = Q @ K^T`` is ``[H, G * block * Hkv]`` (products exact, float32
+accumulation), a query head keeps the keys of its own KV head and below
+their page's count (the mask), and ``P @ V`` is the ``[H, Dh]`` output
+itself. Scores, running max, sum and accumulator are float32; the
+probabilities are rounded to the pages' dtype for ``P @ V``, as
 `SelfAttentionLayerImpl._grouped_attention` rounds them. A slot with no row
 at all returns zeros, not 0/0."""
 from __future__ import annotations
@@ -38,44 +45,104 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30  # below any score, and finite: exp(_NEG - m) is 0, never NaN
+# what one work item aims to bring in for K (and again for V): the pages an
+# item takes follow from the page's bytes as the kernel sees them
+_ITEM_BYTES = 512 * 1024
+# the work list lives in SMEM (two int32 a page-list entry and one a group);
+# a list padded beyond this many entries is not the kernel's to take
+# (`list_fits`, asked by `SelfAttentionLayerImpl.fused_read_engages`)
+MAX_ENTRIES = 64 * 1024
 
 
-def _kernel(slot_ref, page_ref, rows_ref, nv_ref, q_ref, k_hbm, v_hbm, o_ref,
-            kbuf, vbuf, sem, acc_ref, m_ref, l_ref, *, scale, kv_heads):
-    """Walk the first ``nv`` work items. ``slot/page/rows_ref``: SMEM [N];
-    ``q_ref``: VMEM [B, H, Dh]; ``k/v_hbm``: [pages, block * Hkv, Dh] left
-    in HBM; ``o_ref``: VMEM [B, H, Dh]."""
-    n_items = slot_ref.shape[0]
+def pages_per_item(page_bytes: int) -> int:
+    """``G``: 16 at StarCoder2-3B's 32 KB page, 8 at the 7B's 64 KB, 1 at
+    EvaByte's 512 KB."""
+    return max(1, _ITEM_BYTES // int(page_bytes))
+
+
+def _items(n: int, page_bytes: int):
+    """(``G``, work items a slot) of a page list of ``n`` entries a slot:
+    the list is padded to whole items."""
+    G = max(1, min(n, pages_per_item(page_bytes)))
+    return G, -(-n // G)
+
+
+def list_fits(slots: int, n: int, page_bytes: int) -> bool:
+    """Whether the page lists of ``slots`` x ``n`` entries, padded as
+    `paged_read_attention` pads them, fit the kernel's SMEM."""
+    G, per_slot = _items(n, page_bytes)
+    return slots * per_slot * G <= MAX_ENTRIES
+
+
+def _kernel(order_ref, page_ref, rows_ref, nv_ref, q_ref, k_hbm, v_hbm, o_ref,
+            kbuf, vbuf, sem, acc_ref, m_ref, l_ref, *, scale, kv_heads,
+            group, per_slot):
+    """Walk the first ``nv`` work items. ``order_ref``: SMEM [B * per_slot],
+    the groups with a row first; ``page/rows_ref``: SMEM [B * per_slot *
+    group], every slot's padded list; ``q_ref``: VMEM [B, H, Dh];
+    ``k/v_hbm``: [pages, block * Hkv, Dh] left in HBM; ``o_ref``: VMEM
+    [B, H, Dh]."""
+    n_items = order_ref.shape[0]
     H = o_ref.shape[1]
     keys = kbuf.shape[1]
-    group = H // kv_heads
+    page_keys = keys // group
     nv = nv_ref[0]
     o_ref[...] = jnp.zeros_like(o_ref)  # a slot with no item keeps zeros
+    # which keys a query head may see at all, and where a key sits in its
+    # page: the same for every item
+    key = jax.lax.broadcasted_iota(jnp.int32, (H, keys), 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (H, keys), 0) // (H // kv_heads)
+    own = key % kv_heads == head
+    at = jax.lax.broadcasted_iota(jnp.int32, (1, keys), 1)
+    vrow = jax.lax.broadcasted_iota(jnp.int32, (page_keys, vbuf.shape[2]), 0)
 
-    def copies(i, buf):
-        page = page_ref[i]
-        return (pltpu.make_async_copy(k_hbm.at[page], kbuf.at[buf],
-                                      sem.at[0, buf]),
-                pltpu.make_async_copy(v_hbm.at[page], vbuf.at[buf],
-                                      sem.at[1, buf]))
+    def slot_of(i):
+        return order_ref[i] // per_slot
+
+    # the G pages of an item are walked by loops, not unrolled: what a step
+    # program pays to trace and lower the kernel does not grow with G
+    def pages_of(i, body, init=0):
+        """``body(live, copy, dst, carry)`` over the item's pages: the keys
+        below the page's count, and ``copy(hbm, vmem, j)``, the page's DMA
+        into ``dst`` of the buffer ``i`` takes of the two."""
+        base, buf = order_ref[i] * group, i % 2
+
+        def page(g, carry):
+            dst = pl.ds(pl.multiple_of(g * page_keys, page_keys), page_keys)
+
+            def copy(hbm, vmem, j):
+                return pltpu.make_async_copy(hbm.at[page_ref[base + g]],
+                                             vmem.at[buf, dst],
+                                             sem.at[j, buf])
+
+            return body(rows_ref[base + g] * kv_heads, copy, dst, carry)
+
+        return jax.lax.fori_loop(0, group, page, init)
+
+    def fetch(i):
+        def start(live, copy, dst, carry):
+            @pl.when(live > 0)  # a page with no row is not fetched
+            def _():
+                copy(k_hbm, kbuf, 0).start()
+                copy(v_hbm, vbuf, 1).start()
+            return carry
+
+        pages_of(i, start)
 
     @pl.when(nv > 0)
     def _():
-        for c in copies(0, 0):
-            c.start()
+        fetch(0)
 
     def item(i, carry):
         buf = i % 2
-        b = slot_ref[i]
-        first = jnp.logical_or(i == 0,
-                               slot_ref[jnp.maximum(i - 1, 0)] != b)
+        b = slot_of(i)
+        first = jnp.logical_or(i == 0, slot_of(jnp.maximum(i - 1, 0)) != b)
         last = jnp.logical_or(
-            i == nv - 1, slot_ref[jnp.minimum(i + 1, n_items - 1)] != b)
+            i == nv - 1, slot_of(jnp.minimum(i + 1, n_items - 1)) != b)
 
         @pl.when(i + 1 < nv)
         def _():
-            for c in copies(i + 1, 1 - buf):
-                c.start()
+            fetch(i + 1)
 
         @pl.when(first)
         def _():
@@ -83,15 +150,19 @@ def _kernel(slot_ref, page_ref, rows_ref, nv_ref, q_ref, k_hbm, v_hbm, o_ref,
             l_ref[...] = jnp.zeros_like(l_ref)
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        live = rows_ref[i] * kv_heads    # keys of this page below the count
-        kc, vc = copies(i, buf)
-        kc.wait()
+        def keys_in(live, copy, dst, end):
+            @pl.when(live > 0)
+            def _():
+                copy(k_hbm, kbuf, 0).wait()
+            return jnp.where((at >= dst.start) & (at < dst.start + page_keys),
+                             dst.start + live, end)
+
+        # per key: where its page's count ends
+        end = pages_of(i, keys_in, jnp.zeros((1, keys), jnp.int32))
         s = jax.lax.dot_general(
             q_ref[b], kbuf[buf], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale      # [H, keys]
-        key = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        head = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
-        s = jnp.where((key % kv_heads == head) & (key < live), s, _NEG)
+        s = jnp.where(own & (at < end), s, _NEG)
         m_prev = m_ref[:, 0:1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -99,21 +170,29 @@ def _kernel(slot_ref, page_ref, rows_ref, nv_ref, q_ref, k_hbm, v_hbm, o_ref,
         l_new = l_ref[:, 0:1] * alpha + jnp.sum(p, axis=1, keepdims=True)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-        vc.wait()
 
-        def accumulate(v):
-            acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        def values_in(live, copy, dst, carry):
+            @pl.when(live > 0)
+            def _():
+                copy(v_hbm, vbuf, 1).wait()
+            return carry
 
-        @pl.when(live >= keys)
-        def _():
-            accumulate(vbuf[buf])
+        def short_pages(live, copy, dst, carry):
+            @pl.when(live < page_keys)
+            def _():
+                # 0 * NaN is NaN: what lies beyond the count is not read
+                # (a page not fetched leaves whatever the buffer held)
+                vbuf[buf, dst] = jnp.where(vrow < live, vbuf[buf, dst], 0)
+            return carry
 
-        @pl.when(live < keys)
-        def _():
-            # 0 * NaN is NaN: what lies beyond the count is not read
-            row = jax.lax.broadcasted_iota(jnp.int32, vbuf.shape[1:], 0)
-            accumulate(jnp.where(row < live, vbuf[buf], 0))
+        # the item's copies share a semaphore, and a wait counts bytes, not
+        # copies: only after the last wait has every page landed, so the
+        # short pages are zeroed in a walk of their own
+        pages_of(i, values_in)
+        pages_of(i, short_pages)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p.astype(vbuf.dtype), vbuf[buf],
+            preferred_element_type=jnp.float32)
 
         @pl.when(last)
         def _():
@@ -125,8 +204,7 @@ def _kernel(slot_ref, page_ref, rows_ref, nv_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def paged_read_attention(q, k_pages, v_pages, pages, rows, *,
-                         interpret=False):
+def paged_read_attention(q, k_pages, v_pages, pages, rows, *, interpret=False):
     """q: [B, 1, H, Dh]; k/v_pages: [P, block, Hkv, Dh] (H a multiple of
     Hkv, query head h on KV head h // (H/Hkv), `_grouped_attention`'s
     order); pages, rows: [B, n] int32 -> [B, 1, H, Dh] in q's dtype.
@@ -138,31 +216,33 @@ def paged_read_attention(q, k_pages, v_pages, pages, rows, *,
         raise ValueError(f"one query row a slot and H % Hkv == 0, got "
                          f"T={T}, H={H}, Hkv={Hkv}")
     n = pages.shape[1]
-    N = B * n
-    # the work list: items with a row to read first, in (slot, j) order
-    count = jnp.clip(rows.reshape(N).astype(jnp.int32), 0, block)
-    order = jnp.argsort(count == 0, stable=True)
-    page = jnp.clip(pages.reshape(N).astype(jnp.int32), 0, P - 1)[order]
-    slot = (jnp.arange(N, dtype=jnp.int32) // n)[order]
-    nv = jnp.sum(count > 0, dtype=jnp.int32).reshape(1)
+    G, per_slot = _items(n, block * Hkv * Dh * k_pages.dtype.itemsize)
+    pad = ((0, 0), (0, per_slot * G - n))
+    count = jnp.pad(jnp.clip(rows.astype(jnp.int32), 0, block), pad)
+    page = jnp.pad(jnp.clip(pages.astype(jnp.int32), 0, P - 1), pad)
+    # the work list: groups with a row to read first, in (slot, j) order
+    empty = jnp.all(count.reshape(B * per_slot, G) == 0, axis=1)
+    order = jnp.argsort(empty, stable=True).astype(jnp.int32)
+    nv = jnp.sum(~empty, dtype=jnp.int32).reshape(1)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
-        partial(_kernel, scale=float(Dh) ** -0.5, kv_heads=Hkv),
+        partial(_kernel, scale=float(Dh) ** -0.5, kv_heads=Hkv, group=G,
+                per_slot=per_slot),
         out_shape=jax.ShapeDtypeStruct((B, H, Dh), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(1,),
             in_specs=[vmem, hbm, hbm], out_specs=vmem,
             scratch_shapes=[
-                pltpu.VMEM((2, block * Hkv, Dh), k_pages.dtype),
-                pltpu.VMEM((2, block * Hkv, Dh), v_pages.dtype),
+                pltpu.VMEM((2, G * block * Hkv, Dh), k_pages.dtype),
+                pltpu.VMEM((2, G * block * Hkv, Dh), v_pages.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.VMEM((H, Dh), jnp.float32),
                 pltpu.VMEM((H, 128), jnp.float32),
                 pltpu.VMEM((H, 128), jnp.float32)]),
         name="paged_read_attention",
         interpret=interpret,
-    )(slot, page, count[order], nv, q.reshape(B, H, Dh),
+    )(order, page.reshape(-1), count.reshape(-1), nv, q.reshape(B, H, Dh),
       k_pages.reshape(P, block * Hkv, Dh),
       v_pages.reshape(P, block * Hkv, Dh))
     return out.reshape(B, 1, H, Dh)

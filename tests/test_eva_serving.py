@@ -179,11 +179,11 @@ def test_pages_named_and_pages_read_are_counted(small, paged_kernel):
 
 @pytest.mark.parametrize("seam", ["unregistered", "registered"])
 def test_a_bfloat16_one_row_per_position_step_is_untouched(seam):
-    """The StarCoder2 cells' decode program is the parent's: a bfloat16
-    `SelfAttentionLayer` step (grouped heads, RoPE) lowers to the same text,
+    """Off the TPU under "auto" a bfloat16 `SelfAttentionLayer` step
+    (grouped heads, RoPE) is the gather body: it lowers to the same text,
     with no kernel call in it, whether or not the `paged_decode_attention`
-    seam is registered (it declines bfloat16), and knows nothing of
-    `ops/paged_read`."""
+    seam is registered (it declines bfloat16), and `ops/paged_read` (its
+    T = 1 read on a TPU since ISSUE 33) is not traced."""
     from deeplearning4j_tpu.nn.conf.layers import SelfAttentionLayer
     from deeplearning4j_tpu.nn.layers.base import impl_for
     from deeplearning4j_tpu.ops import helpers, pallas_kernels

@@ -133,6 +133,7 @@ def test_greedy_token_identical_kernel_on_off_and_contiguous(net, solo):
     kernel cannot engage even though the helper is registered) all
     match solo decoding bit-for-bit under the residency audit."""
     prompts, expect = solo
+    fused = []
     for build in (lambda: _engine(net, "on"),
                   lambda: _engine(net, "off"),
                   lambda: DecodeScheduler(net, V, n_slots=2,
@@ -146,8 +147,12 @@ def test_greedy_token_identical_kernel_on_off_and_contiguous(net, solo):
         finally:
             eng.stop()
         assert outs == expect
-    # the paged kernel-on engine really did run fused
-    assert any(pk.paged_decode_decisions().values())
+        fused.append(eng.paged_kernel_status()["buckets"])
+    # the paged kernel-on engine really did run fused: since ISSUE 33 the
+    # T=1 read of an un-quantized engine off a mesh is `ops/paged_read`,
+    # ahead of the seam's kernel
+    assert fused[0] and set(fused[0].values()) == {"paged_read"}
+    assert not any(fused[1].values()) and not fused[2]
 
 
 def test_seeded_sampling_token_identical(net):
@@ -224,11 +229,13 @@ def test_forced_modes_and_prefill_fallback(net, solo, monkeypatch):
         return real(q, *a, **k)
 
     monkeypatch.setattr(pk, "_paged_decode_call", spy)
-    eng = _engine(net, "off")
+    # int8 pages: what the seam still stands in front of (since ISSUE 33
+    # an un-quantized T=1 read off a mesh is `ops/paged_read`'s)
+    eng = _engine(net, "off", kv_dtype="int8")
     eng.warmup()
     assert calls == []
     assert not eng.paged_kernel_status()["engaged"]
-    eng2 = _engine(net, "on")
+    eng2 = _engine(net, "on", kv_dtype="int8")
     eng2.warmup()
     # one seam entry per attention layer per decode table bucket; every
     # q is a single-token [n_slots, 1, H, Dh] batch — prefill's T>1
@@ -337,7 +344,9 @@ def test_observability_gauge_costs_and_debug_snapshot(net):
     blk = snap["paged_kernel"]
     assert blk["mode"] == "on" and blk["engaged"]
     assert set(blk["buckets"]) == set(eng.table_buckets)
-    assert all(v == "bh" for v in blk["buckets"].values())
+    # an un-quantized engine off a mesh: `ops/paged_read` (ISSUE 33), by
+    # the layer's static rule, ahead of the seam's autotuned variants
+    assert all(v == "paged_read" for v in blk["buckets"].values())
     assert "autotune" in blk
     from deeplearning4j_tpu.inference.profiler import program_costs
     costs = program_costs(eng)
@@ -363,14 +372,29 @@ def test_unregistered_seam_is_silent_fallback(net, solo):
     prompts, expect = solo
     assert ophelpers.paged_decode_attention(
         None, None, None, None, None, mode="on") is None
-    eng = _engine(net, "on").start()
-    try:
-        outs = [h.result(300) for h in
-                [eng.submit(p, 6) for p in prompts]]
-    finally:
-        eng.stop()
-    assert outs == expect
-    assert not eng.paged_kernel_status()["engaged"]
+
+    def run(eng):
+        eng.start()
+        try:
+            return [h.result(300) for h in
+                    [eng.submit(p, 6) for p in prompts]]
+        finally:
+            eng.stop()
+
+    # un-quantized "on": the seam is not asked since ISSUE 33, the read is
+    # `ops/paged_read` (interpreted), and the tokens are still solo's
+    eng = _engine(net, "on")
+    assert run(eng) == expect
+    assert set(eng.paged_kernel_status()["buckets"].values()) == {
+        "paged_read"}
+    # int8 pages: the read the seam still stands in front of; with no
+    # helper registered "on" is the gather body, as "off" is
+    outs = {}
+    for mode in ("on", "off"):
+        eng = _engine(net, mode, kv_dtype="int8")
+        outs[mode] = run(eng)
+        assert not eng.paged_kernel_status()["engaged"]
+    assert outs["on"] == outs["off"]
 
 
 def test_bad_mode_rejected(net):
