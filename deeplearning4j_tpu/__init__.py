@@ -29,4 +29,5 @@ __all__ = [
 # layer impl registration side effects
 from .nn.layers import (feedforward as _ff, convolution as _conv,  # noqa: E402,F401
                         normalization as _norm, recurrent as _rec,
-                        pretrain as _pre, attention as _attn)
+                        pretrain as _pre, attention as _attn,
+                        experts as _experts)

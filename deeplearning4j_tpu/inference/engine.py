@@ -93,7 +93,9 @@ from ..analysis.runtime import (CompileCounter, device_index, host_read,
                                 ledger_check_request, ledger_check_zero,
                                 ledger_forget, ledger_note)
 from ..models.sampling import sample_logits
-from ..nn.layers.attention import SelfAttentionLayerImpl
+from ..nn.layers.attention import (LatentAttentionLayerImpl,
+                                   SelfAttentionLayerImpl)
+from ..nn.layers.experts import RoutedExpertsLayerImpl
 from ..nn.layers.recurrent import (BaseRecurrentImpl,
                                    _materialize_rnn_states)
 from ..nn.multilayer import _compute_dtype_of
@@ -131,7 +133,7 @@ def _carries_kv_cache(impl) -> bool:
 def _is_paged(st) -> bool:
     """This state entry is a paged attention layer's (pool-wide page arrays
     under `PAGE_KEYS` beside the per-slot leaves)."""
-    return isinstance(st, dict) and "k_pages" in st
+    return isinstance(st, dict) and any(k in st for k in PAGE_KEYS)
 
 
 class _EngineFenced(Exception):
@@ -619,9 +621,8 @@ class DecodeScheduler:
                     if isinstance(impl, BaseRecurrentImpl)]
         self._chunk_dense = bool(stateful) and all(
             _carries_kv_cache(impl) for impl in stateful)
-        attn_keys = [key for key, st in abstract_states.items()
-                     if isinstance(st, dict) and "k" in st and "v" in st
-                     and "pos" in st]
+        attn_keys = [key for key, impl in self._impl_items()
+                     if _carries_kv_cache(impl)]
         # what a request holds in the pool is asked of the layer kind
         # (`blocks_needed`), and so is whether its pages are recycled
         # while it lives (`page_recycling`: EVA's (window, chunk), None
@@ -655,6 +656,40 @@ class DecodeScheduler:
                     f"prefill_chunk={self.prefill_chunk} | window_size: a "
                     "chunk of prompt never straddles a window or splits a "
                     "summary chunk")
+        # a latent layer keeps one leaf of [latent | rotated key] rows, in
+        # the pool alone and in the compute dtype: what is not served yet
+        # for it is refused here, by name
+        latent = next((key for key, impl in self._impl_items()
+                       if isinstance(impl, LatentAttentionLayerImpl)), None)
+        if latent is not None:
+            unserved = [what for what, asked in (
+                ("int8 pages (kv_dtype)", kv_dtype),
+                ("a tp mesh", mesh is not None and mesh != 1),
+                ("speculation", speculate),
+                ("a contiguous cache (kv_pool_mb = 0, as rnn_time_step "
+                 "steps it)", not (kv_pool_mb and kv_pool_mb > 0))) if asked]
+            if unserved:
+                raise ValueError(
+                    f"LatentAttentionLayer {latent!r} is served from the "
+                    "paged pool, one device, in the compute dtype; not "
+                    "served yet for it: " + ", ".join(unserved))
+        # the routed-experts layers whose per-dispatch routing counts ride
+        # back with the probabilities (`_forward`, `_pack_counts`)
+        self._moe = [key for key, impl in self._impl_items()
+                     if isinstance(impl, RoutedExpertsLayerImpl)] \
+            if self._graph else []
+        shares = {(impl._held()[1], int(impl.conf.top_k))
+                  for key, impl in self._impl_items() if key in self._moe}
+        if len(shares) > 1:
+            raise ValueError(
+                "routed-experts layers that hold or choose different "
+                f"numbers of experts ({sorted(shares)}) are not served yet")
+        self._moe_held, self._moe_top_k = shares.pop() if shares else (0, 0)
+        if self._moe and max(n_slots, prefill_chunk) >= 65536:
+            raise ValueError(
+                "routing counts ride back as two base-256 digits "
+                "(`_pack_counts`): a dispatch of 65,536 tokens or more "
+                "is not served for a net with routed experts")
         # -- tensor-parallel mesh (inference/sharding.py, ISSUE 9) --
         # resolved BEFORE the KV layout: pool byte budgets are per-device
         # (each device holds Hkv/tp heads per block), and the pool must
@@ -726,10 +761,15 @@ class DecodeScheduler:
         self._table: Optional[np.ndarray] = None
         if kv_pool_mb and kv_pool_mb > 0:
             if self._chunk_dense and attn_keys and self.kv_block >= 1:
-                attn = {key: abstract_states[key] for key in attn_keys}
-                pool = KVPool(attn, block=self.kv_block,
+                # which page arrays a layer keeps, and the shape of a
+                # page, is asked of the layer
+                impls = dict(self._impl_items())
+                leaves = {key: impls[key].paged_leaves(
+                    self.kv_block, self._dtype, kv_dtype)
+                    for key in attn_keys}
+                pool = KVPool(leaves, block=self.kv_block,
                               budget_bytes=int(kv_pool_mb * (1 << 20)),
-                              shard_factor=self.tp, cache_dtype=kv_dtype,
+                              shard_factor=self.tp,
                               metrics=self.metrics, tracer=self.tracer)
                 if pool.capacity_blocks > 0:
                     self.pool = pool
@@ -758,40 +798,15 @@ class DecodeScheduler:
                         if key not in attn_keys}
                     self.kv_dtype = kv_dtype
                     for key in attn_keys:
-                        st = abstract_states[key]
-                        tail = st["k"].shape[2:]
-                        if kv_dtype == "int8":
-                            # quantized pages (int8 values + f32 per-row
-                            # scales: attention quantizes on write and
-                            # dequantizes on gather — halved-plus pool
-                            # bytes per block, same paged step contract)
-                            self._states[key] = {
-                                "k_pages": zeros(
-                                    (pages, self.kv_block) + tail,
-                                    jnp.int8),
-                                "v_pages": zeros(
-                                    (pages, self.kv_block) + tail,
-                                    jnp.int8),
-                                "k_scales": zeros(
-                                    (pages, self.kv_block) + tail[:-1],
-                                    jnp.float32),
-                                "v_scales": zeros(
-                                    (pages, self.kv_block) + tail[:-1],
-                                    jnp.float32),
-                                "pos": zeros(st["pos"].shape,
-                                             st["pos"].dtype),
-                            }
-                            continue
+                        # int8 pages come with their float32 per-row
+                        # scales (attention quantizes on write and
+                        # dequantizes on gather), a latent layer with
+                        # one leaf: as the layer's `paged_leaves` says
+                        pos = abstract_states[key]["pos"]
                         self._states[key] = {
-                            "k_pages": zeros(
-                                (pages, self.kv_block) + tail,
-                                st["k"].dtype),
-                            "v_pages": zeros(
-                                (pages, self.kv_block) + tail,
-                                st["v"].dtype),
-                            "pos": zeros(st["pos"].shape,
-                                         st["pos"].dtype),
-                        }
+                            **{leaf: zeros((pages,) + tuple(page), dt)
+                               for leaf, (page, dt) in leaves[key].items()},
+                            "pos": zeros(pos.shape, pos.dtype)}
                         if self._eva is not None:
                             # summary block -> page, per slot: carried
                             # on the device and written by `_sumtab_fn`
@@ -874,13 +889,13 @@ class DecodeScheduler:
             self._jcow = jax.jit(self._cow_fn,
                                  donate_argnames=("states",))
         self._jsumtab = None
+        if (self._eva is not None or latent is not None) and not self.paged:
+            raise ValueError(
+                f"kv_pool_mb={kv_pool_mb} holds no two blocks of "
+                f"{self.kv_block} positions: a net with "
+                f"{'EvaAttentionLayer' if latent is None else 'LatentAttentionLayer'}"
+                " is served through the paged pool only")
         if self._eva is not None:
-            if not self.paged:
-                raise ValueError(
-                    f"kv_pool_mb={kv_pool_mb} holds no two blocks of "
-                    f"{self.kv_block} positions: a net with "
-                    "EvaAttentionLayer is served through the paged pool "
-                    "only")
             self._jsumtab = jax.jit(self._sumtab_fn,
                                     donate_argnames=("states",))
         # -- hierarchical KV tiering (ISSUE 19, kvtier.py) ------------------
@@ -1146,6 +1161,29 @@ class DecodeScheduler:
                 "prefix_publish_skipped_total",
                 help="finished prompts the prefix trie did not adopt: "
                      "their pages were recycled while the request lived")
+        if self._moe:
+            # from the routing counts each dispatch hands back
+            # (`_note_routing`): pairs are (token, chosen expert), a layer
+            self._m_moe_routed = m.counter(
+                "moe_pairs_routed_total",
+                help="token-expert pairs the routers chose, over all "
+                     "experts: tokens x top_k x routed layers, of the "
+                     "dispatches whose counts were read")
+            self._m_moe_held = m.counter(
+                "moe_pairs_held_total",
+                help="of those, the pairs on experts this engine holds")
+            self._m_moe_slots = m.counter(
+                "moe_expert_slots_total",
+                help="held experts x routed layers, a decode dispatch")
+            self._m_moe_hit = m.counter(
+                "moe_experts_hit_total",
+                help="of those, the experts at least one token chose")
+        if latent is not None:
+            # rows decode tokens attended over, from host-side depths
+            self._m_mla_rows = m.counter(
+                "mla_rows_read_total",
+                help="latent rows attended by decode tokens: their depths")
+        self._latent = latent is not None
         if self.speculate:
             self._m_spec_proposed = m.counter("spec_tokens_proposed_total")
             self._m_spec_accepted = m.counter("spec_tokens_accepted_total")
@@ -1236,9 +1274,24 @@ class DecodeScheduler:
                                "pos": jnp.zeros((self.n_slots,), jnp.int32)}
         return states
 
-    def _forward(self, params, variables, x, states):
+    def _forward(self, params, variables, x, states, live=None):
         """One forward of [B, T, vocab] one-hots through the net with
-        explicit states: ([B, T, vocab] distributions, new states)."""
+        explicit states: ([B, T, vocab] distributions, new states, routing
+        counts). The counts are None unless the net routes experts; then
+        ``live`` (bool, [B] or [B, T]: the lanes that hold a token) goes in
+        as the feature mask, so that a padded lane routes nowhere, and the counts
+        are the layers' ``routing_counts``, int32 [layers, held]: the pairs
+        that fell on each held expert in this dispatch."""
+        if self._moe:
+            fmasks = None if live is None else {
+                self.net.conf.network_inputs[0]:
+                    live.reshape(x.shape[:2]).astype(self._dtype)}
+            acts, new_vars, new_states = self.net._forward_impl(
+                params, variables, [x], train=False, rng=None, states=states,
+                fmasks=fmasks)
+            return acts[self.net.conf.network_outputs[0]], new_states, \
+                jnp.stack([new_vars[key]["routing_counts"]
+                           for key in self._moe])
         if self._graph:
             acts, _, new_states = self.net._forward_impl(
                 params, variables, [x], train=False, rng=None, states=states)
@@ -1247,7 +1300,33 @@ class DecodeScheduler:
             acts, _, new_states = self.net._forward_impl(
                 params, variables, x, train=False, rng=None, states=states)
             out = acts[-1]
-        return out, new_states
+        return out, new_states, None
+
+    def _pack_counts(self, probs, counts):
+        """The routing counts as further rows under ``probs`` ([rows,
+        vocab]), so that they reach the host in the read the step makes
+        anyway: a layer a row, zero-padded to the vocabulary's width. The
+        graph hands the probabilities back in the compute dtype, and
+        bfloat16 holds whole numbers to 256 only, so a count (at most a
+        dispatch's tokens, ``__init__`` refuses 65,536) goes as two
+        base-256 digits."""
+        if counts is None:
+            return probs
+        digits = jnp.concatenate([counts % 256, counts // 256], axis=1)
+        return jnp.concatenate(
+            [probs, jnp.pad(digits, ((0, 0), (0, probs.shape[1]
+                                              - digits.shape[1]))
+                            ).astype(probs.dtype)], axis=0)
+
+    def _unpack_counts(self, rows: np.ndarray):
+        """`_pack_counts` read back on the host: (the probabilities, the
+        counts as int64 [layers, held]); the rows whole where the net
+        routes no experts."""
+        n = len(self._moe)
+        if not n:
+            return rows, None
+        d, held = rows[-n:].astype(np.int64), self._moe_held
+        return rows[:-n], d[:, :held] + 256 * d[:, held:2 * held]
 
     def _freeze_states(self, new_states, old_states, live):
         """Keep only live slots' state transitions: masked rows (idle or
@@ -1282,8 +1361,10 @@ class DecodeScheduler:
         False rows are batch padding whose state must not advance.
         Returns ([n_slots, vocab] next-token distributions, new states)."""
         x = jax.nn.one_hot(ids, self.vocab_size, dtype=self._dtype)[:, None]
-        out, new_states = self._forward(params, variables, x, states)
-        return out[:, -1, :], self._freeze_states(new_states, states, live)
+        out, new_states, counts = self._forward(params, variables, x, states,
+                                                live)
+        return self._pack_counts(out[:, -1, :], counts), \
+            self._freeze_states(new_states, states, live)
 
     def _inject_paged(self, states, table, wmask):
         """Hand the per-call block table (and write mask) to every paged
@@ -1319,8 +1400,10 @@ class DecodeScheduler:
         not survive sharing). One XLA program per table bucket."""
         x = jax.nn.one_hot(ids, self.vocab_size, dtype=self._dtype)[:, None]
         sts = self._inject_paged(states, table, live[:, None])
-        out, new_states = self._forward(params, variables, x, sts)
-        return out[:, -1, :], self._freeze_states(new_states, states, live)
+        out, new_states, counts = self._forward(params, variables, x, sts,
+                                                live)
+        return self._pack_counts(out[:, -1, :], counts), \
+            self._freeze_states(new_states, states, live)
 
     # -- grammar-mask programs (logitproc.py, ISSUE 14) --------------------
     def _mask_upload_fn(self, masks, start, rows):
@@ -1343,13 +1426,21 @@ class DecodeScheduler:
         admit-everything grammar token-identical to unmasked decode."""
         out, new_states = self._step_fn(params, variables, ids, live,
                                         states)
-        return out + jnp.take(masks, mstate, axis=0), new_states
+        return self._add_masks(out, masks, mstate), new_states
+
+    def _add_masks(self, out, masks, mstate):
+        """The slots' mask rows onto their distributions; rows of routing
+        counts below them (`_pack_counts`) pass."""
+        rows = jnp.take(masks, mstate, axis=0)
+        if not self._moe:
+            return out + rows
+        return out.at[:self.n_slots].add(rows)
 
     def _step_masked_paged_fn(self, params, variables, ids, live, table,
                               mstate, masks, states):
         out, new_states = self._step_paged_fn(params, variables, ids,
                                               live, table, states)
-        return out + jnp.take(masks, mstate, axis=0), new_states
+        return self._add_masks(out, masks, mstate), new_states
 
     def _verify_masked_fn(self, params, variables, ids, live, mstate2,
                           masks, states):
@@ -1446,9 +1537,12 @@ class DecodeScheduler:
         sub = self._slice_slot(states, slot)
         if self._chunk_dense:
             x = jax.nn.one_hot(ids, self.vocab_size, dtype=self._dtype)[None]
-            out, new_sub = self._forward(params, variables, x, sub)
-            probs = jax.lax.dynamic_index_in_dim(out, n_real - 1, axis=1,
-                                                 keepdims=False)[0]
+            out, new_sub, counts = self._forward(
+                params, variables, x, sub,
+                jnp.arange(ids.shape[0], dtype=jnp.int32) < n_real
+                if self._moe else None)
+            probs = self._pack_chunk(jax.lax.dynamic_index_in_dim(
+                out, n_real - 1, axis=1, keepdims=False)[0], counts)
             fixed = {}
             for key, st in new_sub.items():
                 if isinstance(st, dict) and "pos" in st:
@@ -1472,7 +1566,7 @@ class DecodeScheduler:
                 tok, k = inp
                 x = jax.nn.one_hot(tok[None, None], self.vocab_size,
                                    dtype=self._dtype)
-                out, ns = self._forward(params, variables, x, carry)
+                out, ns, _ = self._forward(params, variables, x, carry)
                 nxt = {}
                 for key, st in ns.items():
                     old = carry[key]
@@ -1486,6 +1580,12 @@ class DecodeScheduler:
             new_sub, probs_all = jax.lax.scan(body, sub, (ids, keep))
             probs = probs_all[n_real - 1]
         return probs, self._scatter_slot(states, new_sub, slot)
+
+    def _pack_chunk(self, probs, counts):
+        """A chunk's one distribution [vocab], with the chunk's routing
+        counts under it where the net routes experts."""
+        return probs if counts is None \
+            else self._pack_counts(probs[None], counts)
 
     def _prefill_paged_fn(self, params, variables, slot, ids, n_real,
                           table, states):
@@ -1504,9 +1604,10 @@ class DecodeScheduler:
         wmask = (jnp.arange(ids.shape[0], dtype=jnp.int32) < nr)[None, :]
         sts = self._inject_paged(sub, trow, wmask)
         x = jax.nn.one_hot(ids, self.vocab_size, dtype=self._dtype)[None]
-        out, new_sub = self._forward(params, variables, x, sts)
-        probs = jax.lax.dynamic_index_in_dim(out, nr - 1, axis=1,
-                                             keepdims=False)[0]
+        out, new_sub, counts = self._forward(params, variables, x, sts,
+                                             wmask)
+        probs = self._pack_chunk(jax.lax.dynamic_index_in_dim(
+            out, nr - 1, axis=1, keepdims=False)[0], counts)
         fixed = {}
         for key, st in new_sub.items():
             if _is_paged(st):
@@ -1606,7 +1707,7 @@ class DecodeScheduler:
         by the next real write — the same invariant slot reuse rests
         on). Masked slots are frozen exactly like the decode step."""
         x = jax.nn.one_hot(ids, self.vocab_size, dtype=self._dtype)
-        out, new_states = self._forward(params, variables, x, states)
+        out, new_states, _ = self._forward(params, variables, x, states)
         return out, self._freeze_states(new_states, states, live)
 
     def _verify_paged_fn(self, params, variables, ids, live, table,
@@ -1618,7 +1719,7 @@ class DecodeScheduler:
         truncates the table back after acceptance."""
         x = jax.nn.one_hot(ids, self.vocab_size, dtype=self._dtype)
         sts = self._inject_paged(states, table, live[:, None])
-        out, new_states = self._forward(params, variables, x, sts)
+        out, new_states, _ = self._forward(params, variables, x, sts)
         return out, self._freeze_states(new_states, states, live)
 
     def _fixpos_fn(self, states, posv, mask):
@@ -2896,8 +2997,12 @@ class DecodeScheduler:
             if seq.sampling:  # final chunk: its output is the first token
                 prof = self.profiler
                 prof.begin("prefill_wait")
-                row = host_read(probs, prof.ready)
+                row, routed = self._unpack_counts(
+                    host_read(probs, prof.ready))
                 prof.begin("accept")
+                if routed is not None:
+                    row = row[0]
+                    self._note_routing(routed, n_real, decode=False)
                 self._consume(i, seq, row)
             self.tracer.end("prefill_chunk", track=self._slot_tracks[i])
             self._prefill_next = (i + 1) % self.n_slots
@@ -3432,6 +3537,9 @@ class DecodeScheduler:
                         sum(t % window + 1 for t in at))
                     self._m_eva_rows_summary.inc(
                         sum(t // window for t in at) * (window // chunk))
+                if self._latent:
+                    self._m_mla_rows.inc(
+                        sum(s.written + 1 for _, s in fed if s.sampling))
                 if mstate is not None:
                     probs, new_states = self._jstep_m(
                         self._params, self._variables,
@@ -3458,8 +3566,11 @@ class DecodeScheduler:
                         self._states)
             self._states = new_states
             prof.begin("decode_wait")
-            probs = host_read(probs, prof.ready)
+            probs, routed = self._unpack_counts(
+                host_read(probs, prof.ready))
             prof.begin("accept")
+            if routed is not None:
+                self._note_routing(routed, len(fed), decode=True)
             for i, seq in fed:
                 seq.steps += 1
                 seq.written += 1
@@ -3481,6 +3592,19 @@ class DecodeScheduler:
         self._trace_compiles()
         prof.iter_end(tokens=self._emitted_this_iter)
         return True
+
+    def _note_routing(self, counts: np.ndarray, tokens: int,
+                      decode: bool) -> None:
+        """One dispatch's routing counts (`_unpack_counts`) into the
+        ``moe_*`` counters: ``tokens`` went through every routed layer. A
+        prefill chunk that is not its prompt's last is not read and so not
+        counted; the share of experts hit is the decode dispatches'."""
+        self._m_moe_routed.inc(tokens * self._moe_top_k * len(self._moe))
+        # `counts` is a HOST array (host_read brought it): no device sync
+        self._m_moe_held.inc(int(counts.sum()))  # graftlint: disable=JG006
+        if decode:
+            self._m_moe_slots.inc(counts.size)
+            self._m_moe_hit.inc(int((counts > 0).sum()))  # graftlint: disable=JG006
 
     def _trace_compiles(self) -> None:
         """Instant event per NEW XLA program: the per-family jit-cache

@@ -68,10 +68,13 @@ from .trace import FlightRecorder
 SCRATCH_BLOCK = 0
 
 # every pool-wide page-array key a paged attention state may carry: K/V
-# pages plus (int8 KV mode) their per-row dequantization scales. The
-# single source of truth for "this leaf is SHARED pool storage, not a
-# per-slot row" across the engine's slice/scatter/zero/freeze/COW paths.
-PAGE_KEYS = ("k_pages", "v_pages", "k_scales", "v_scales")
+# pages plus (int8 KV mode) their per-row dequantization scales, or a
+# latent layer's one leaf of [latent | rotated key] rows. Which of them a
+# layer has, and what shape a page of ``block`` positions is, the layer
+# says itself (`SelfAttentionLayerImpl.paged_leaves`). The single source
+# of truth for "this leaf is SHARED pool storage, not a per-slot row"
+# across the engine's slice/scatter/zero/freeze/COW paths.
+PAGE_KEYS = ("k_pages", "v_pages", "k_scales", "v_scales", "c_pages")
 
 
 class _Node:
@@ -101,9 +104,12 @@ class _Node:
 class KVPool:
     """Refcounted block pool + trie prefix index over per-layer K/V.
 
-    ``attn_states``: the engine's attention state entries
-    (``{key: {"k": [n_slots, L, Hkv, Dh], "v": ..., "pos": ...}}``) —
-    only shapes/dtypes are read. The engine owns the page arrays (they
+    ``leaves``: what each attention layer keeps in the pool, as the layer
+    states it (``{layer: impl.paged_leaves(block, dtype, kv_dtype)}``, i.e.
+    ``{layer: {leaf: (page shape, dtype)}}`` with a page what ``block``
+    positions hold: their key rows and value rows, int8 values beside their
+    scales, or their latent rows). A block costs one page of every leaf of
+    every layer. The engine owns the page arrays (they
     live inside its jitted state pytree, where the programs
     scatter/gather them); this object allocates NOTHING on device and is
     pure metadata — free list, trie, refcounts — plus the ``kv_pool_*``
@@ -120,37 +126,21 @@ class KVPool:
     metadata is device-count-agnostic (one logical pool).
     """
 
-    def __init__(self, attn_states: Dict, *, block: int, budget_bytes: int,
+    def __init__(self, leaves: Dict, *, block: int, budget_bytes: int,
                  shard_factor: int = 1,
-                 cache_dtype: Optional[str] = None,
                  metrics: Optional[MetricsRegistry] = None,
                  tracer: Optional[FlightRecorder] = None):
         if block < 1:
             raise ValueError(f"block must be >= 1, got {block}")
-        if cache_dtype not in (None, "int8"):
-            raise ValueError(f"cache_dtype must be None or 'int8', got "
-                             f"{cache_dtype!r}")
         self.block = int(block)
-        self.cache_dtype = cache_dtype
         self.shard_factor = max(1, int(shard_factor))
         # flight recorder (trace.py): eviction/publish instants on the
         # `kvpool` track; None (standalone pool) records nothing
         self._tracer = tracer
         self.budget_bytes = int(budget_bytes)
-        per_block = 0
-        for st in attn_states.values():
-            row_shape = tuple(st["k"].shape[2:])  # (Hkv, Dh)
-            if cache_dtype == "int8":
-                # int8 KV pages + one f32 dequant scale per (position,
-                # head) row: Hkv*Dh bytes of values + Hkv*4 of scales
-                # per position per k-or-v — under half the f32 cost for
-                # any Dh >= 8, so the same budget holds >= 2x the blocks
-                row_bytes = int(math.prod(row_shape)) \
-                    + int(row_shape[0]) * 4
-            else:
-                row_bytes = int(jnp.dtype(st["k"].dtype).itemsize) \
-                    * int(math.prod(row_shape))
-            per_block += 2 * self.block * row_bytes
+        per_block = sum(
+            int(math.prod(page)) * int(jnp.dtype(dtype).itemsize)
+            for layer in leaves.values() for page, dtype in layer.values())
         # per-DEVICE block cost: the head axis splits evenly over the
         # mesh (the engine refuses to shard otherwise), so a block costs
         # each device 1/shard_factor of its total bytes

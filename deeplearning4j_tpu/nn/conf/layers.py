@@ -25,6 +25,13 @@ from .serde import register
 from ..updater.updaters import UpdaterConfig
 
 
+def _require(conf, *names: str) -> None:
+    """Fields that are a model's own widths carry no default."""
+    missing = [n for n in names if getattr(conf, n) is None]
+    if missing:
+        raise ValueError(f"{type(conf).__name__} needs " + ", ".join(missing))
+
+
 def _pair(v) -> Tuple[int, int]:
     if isinstance(v, (list, tuple)):
         return (int(v[0]), int(v[1]))
@@ -356,6 +363,74 @@ class EvaAttentionLayer(SelfAttentionLayer):
     causal: bool = True
     window_size: int = 2048
     chunk_size: int = 16
+
+
+@register
+@dataclass
+class LatentAttentionLayer(SelfAttentionLayer):
+    """Multi-head latent attention (MLA, the DeepSeek-V2/V3 family) — see
+    nn/layers/attention.py. Queries and keys/values go through low-rank
+    projections with an RMSNorm each; a head's query and key are a "nope"
+    part (``qk_nope_head_dim``, rebuilt from the latent) beside a "rope"
+    part (``qk_rope_head_dim``; the key's is ONE rotated row shared by all
+    heads). What is cached a position is the normed latent and that rotated
+    key: ``kv_lora_rank + qk_rope_head_dim`` values, whatever ``n_heads``.
+    Causal, no biases; ``n_out`` is the model width the output projection
+    returns to. ``yarn_factor`` > 1 turns on YaRN (blended frequencies over
+    ``yarn_original_max`` positions, and the ``mscale`` pair). The five
+    widths are the model's and have to be given."""
+
+    causal: bool = True
+    rope: bool = True
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    eps: float = 1e-6
+    yarn_factor: float = 1.0
+    yarn_original_max: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+
+    def __post_init__(self):
+        _require(self, "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                 "qk_rope_head_dim", "v_head_dim")
+
+
+@register
+@dataclass
+class RoutedExpertsLayer(FeedForwardLayer):
+    """One device's share of a routed mixture of gated (SwiGLU) experts —
+    see nn/layers/experts.py. The router scores all ``n_experts`` and picks
+    ``top_k`` a token; this layer holds experts ``held = (first, count)``
+    and returns the part of the result they give (``n_out == n_in``). No
+    capacity factor: no token is dropped. ``scoring``: "sigmoid" over the
+    router's outputs (the one built); ``norm_topk`` divides the chosen
+    scores by their sum (over all chosen, held or not); ``scale`` multiplies
+    the gates. ``held=None`` holds every expert. ``n_experts``, ``top_k``
+    and ``width`` (an expert's hidden width) are the model's and have to be
+    given."""
+
+    n_experts: Optional[int] = None
+    held: Optional[Tuple[int, int]] = None
+    top_k: Optional[int] = None
+    scoring: str = "sigmoid"
+    norm_topk: bool = True
+    scale: float = 1.0
+    width: Optional[int] = None
+
+    def __post_init__(self):
+        _require(self, "n_experts", "top_k", "width")
+        if self.held is not None:  # a JSON round trip hands back a list
+            self.held = (int(self.held[0]), int(self.held[1]))
+
+    def set_n_in(self, input_type: InputType) -> None:
+        super().set_n_in(input_type)
+        if self.n_out is None:
+            self.n_out = self.n_in
 
 
 @dataclass

@@ -30,6 +30,8 @@ they are token-identical.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -277,6 +279,23 @@ class SelfAttentionLayerImpl(BaseRecurrentImpl):
         hands pages back while the request lives (`EvaAttentionLayerImpl`)
         returns its geometry here, and the trie stands aside."""
         return None
+
+    def paged_leaves(self, block, dtype, cache_dtype=None):
+        """The pool-wide page arrays this layer keeps, ``{leaf: (page shape,
+        dtype)}`` with a page what ``block`` positions hold: the pool prices
+        a block from it and the engine allocates ``[pages] + page`` a leaf
+        (`inference/kvpool.py`; every name is one of its ``PAGE_KEYS``).
+        Here a key row and a value row of the compact K/V heads a position;
+        with ``cache_dtype="int8"`` int8 values beside one float32
+        dequantization scale per (position, head)."""
+        page = (int(block), self._kv_heads(),
+                self.conf.n_out // self.conf.n_heads)
+        if cache_dtype == "int8":
+            return {"k_pages": (page, jnp.int8), "v_pages": (page, jnp.int8),
+                    "k_scales": (page[:2], jnp.float32),
+                    "v_scales": (page[:2], jnp.float32)}
+        return {"k_pages": (page, jnp.dtype(dtype)),
+                "v_pages": (page, jnp.dtype(dtype))}
 
     def fused_read_engages(self, mode, T, dtype, mesh=None, *, slots=0,
                            pages=0, block=1) -> bool:
@@ -664,3 +683,319 @@ class EvaAttentionLayerImpl(SelfAttentionLayerImpl):
                              pos + T)
         return y, {"k_pages": kp2, "v_pages": vp2, "pos": next_pos,
                    "summary_table": stable}
+
+
+@register_impl("LatentAttentionLayer")
+class LatentAttentionLayerImpl(SelfAttentionLayerImpl):
+    """Multi-head latent attention (MLA). With ``x`` a row of the input,
+    ``C = kv_lora_rank``, ``dn``/``dr``/``dv`` the nope, rope and value
+    widths of a head and every norm an RMSNorm with a gain:
+
+        c_q = norm_q(x Wdq);   q = c_q Wuq -> heads of [q_n (dn) | q_r (dr)]
+        [c_kv | k_r] = x Wdkv; c = norm_kv(c_kv)
+        q_r, k_r <- RoPE (k_r: one rotated key shared by all heads)
+
+    The cached row of a position is ``[c | k_r]``, ``C + dr`` wide. Two
+    forms of the same function of it, with ``Wukv`` split per head into
+    ``Wuk`` (C x dn) and ``Wuv`` (C x dv):
+
+      expanded  k_h = [c Wuk_h | k_r], v_h = c Wuv_h; softmax(s q.k) v
+                per head (`_expanded`): the full forward (training,
+                scoring), where every row is new and its keys and values
+                are built once;
+      absorbed  q~_h = q_n,h Wuk_h^T (C wide), score s (q~_h.c + q_r.k_r),
+                o_h = (sum p c) Wuv_h (`_absorbed`): every paged step, a
+                decode row or a prefill chunk, which never rebuilds a key
+                or a value. By operations a chunk of 171 queries or more
+                would be cheaper expanded (rebuilding costs `2 C H (dn +
+                dv)` a cached row once, a query saves `2 H (2 C - dn - dv)`
+                on it); on a v5e it is not, at any measured chunk and depth
+                (512 queries over 8,192 rows: 8.0 ms absorbed, 13.4 ms
+                expanded; PERF.md section 6, PR 34): the rebuilt keys and
+                values of a whole table bucket are written and read again.
+
+    ``s = (dn + dr)^-1/2 m^2`` with YaRN's ``m = 0.1 mscale_all_dim ln(factor)
+    + 1``; scores and softmax are float32. No biases.
+
+    Serving (`_paged_step`) is through the paged pool alone, ONE page leaf
+    ``c_pages`` (`paged_leaves`: [pages, block / k, k (C + dr)], the rows of
+    k positions side by side so that a page's last dimension is a multiple
+    of 128 lanes, `_rows_packed`: k = 2 at C + dr = 576), one row a
+    position kept to the request's end (`page_recycling` is None:
+    the prefix trie shares latent pages as it shares K/V pages). The read
+    is XLA's gather of the slot's table at the bucket's width."""
+
+    WEIGHT_KEYS = ("Wdq", "Wuq", "Wdkv", "Wukv", "Wo")
+    # queries of a long chunk attend this many at a time, so that the
+    # float32 scores of 64 heads over a deep table stay a few hundred MB
+    _QBLOCK = 128
+    # slots whose tables a paged step gathers at a time
+    _SLOTS = 16
+
+    def _dims(self):
+        c = self.conf
+        return (c.n_heads, int(c.qk_nope_head_dim), int(c.qk_rope_head_dim),
+                int(c.v_head_dim), int(c.kv_lora_rank))
+
+    def init_params(self, key, dtype=jnp.float32):
+        conf = self.conf
+        H, dn, dr, dv, C = self._dims()
+        dist = conf.dist.spec() if getattr(conf, "dist", None) is not None \
+            else None
+        mk = lambda k, i, o: winit.init_weights(
+            k, (i, o), conf.weight_init or "xavier", dist, dtype)
+        ks = jax.random.split(key, 5)
+        Q = int(conf.q_lora_rank)
+        return {"Wdq": mk(ks[0], conf.n_in, Q),
+                "q_gain": jnp.ones((Q,), dtype),
+                "Wuq": mk(ks[1], Q, H * (dn + dr)),
+                "Wdkv": mk(ks[2], conf.n_in, C + dr),
+                "kv_gain": jnp.ones((C,), dtype),
+                "Wukv": mk(ks[3], C, H * (dn + dv)),
+                "Wo": mk(ks[4], H * dv, conf.n_out)}
+
+    def init_state(self, batch: int, dtype=jnp.float32):
+        _, _, dr, _, C = self._dims()
+        L = int(getattr(self.conf, "max_cache_len", 1024))
+        return {"c": jnp.zeros((batch, L, C + dr), dtype),
+                "pos": jnp.zeros((), jnp.int32)}
+
+    def _rows_packed(self, block: int) -> int:
+        """Positions that share one row of a page: the fewest that make the
+        row a multiple of 128 values (2 for a 576-wide latent row), where
+        ``block`` holds a whole number of such rows, else 1. A page whose
+        last dimension is no multiple of the TPU's 128 lanes is padded, and
+        the compiler then hands the pool back in a layout of its own and
+        copies every layer's whole pool twice a program (2.9 ms a copy at
+        736 MB; PERF.md section 6, PR 34)."""
+        _, _, dr, _, C = self._dims()
+        k = 128 // math.gcd(C + dr, 128)
+        return k if block % k == 0 else 1
+
+    def paged_leaves(self, block, dtype, cache_dtype=None):
+        if cache_dtype is not None:
+            raise ValueError("LatentAttentionLayer keeps its latent rows in "
+                             f"the compute dtype: {cache_dtype} pages are "
+                             "not served yet")
+        _, _, dr, _, C = self._dims()
+        k = self._rows_packed(int(block))
+        return {"c_pages": ((int(block) // k, k * (C + dr)),
+                            jnp.dtype(dtype))}
+
+    def fused_read_engages(self, mode, T, dtype, mesh=None, **_):
+        return False    # no fused latent read yet: the gather serves
+
+    # -- the pieces of the written equations ----------------------------------
+    def _inv_freq(self):
+        """Inverse frequencies of the ``dr / 2`` rotated pairs: plain RoPE,
+        or YaRN's blend of it with the same divided by ``factor``, by a
+        linear ramp between the correction dims of ``beta_fast`` and
+        ``beta_slow`` over ``yarn_original_max`` positions."""
+        conf = self.conf
+        dr = int(conf.qk_rope_head_dim)
+        base = float(conf.rope_base)
+        freq = base ** (-jnp.arange(dr // 2, dtype=jnp.float32) / (dr // 2))
+        factor = float(conf.yarn_factor)
+        if factor <= 1.0:
+            return freq
+
+        def correction_dim(rotations):
+            return dr * math.log(conf.yarn_original_max
+                                 / (rotations * 2 * math.pi)) \
+                / (2 * math.log(base))
+
+        low = max(math.floor(correction_dim(conf.yarn_beta_fast)), 0)
+        high = min(math.ceil(correction_dim(conf.yarn_beta_slow)), dr - 1)
+        ramp = jnp.clip((jnp.arange(dr // 2, dtype=jnp.float32) - low)
+                        / max(high - low, 1e-3), 0.0, 1.0)
+        return freq / factor * ramp + freq * (1.0 - ramp)
+
+    @staticmethod
+    def _mscale(factor: float, m: float) -> float:
+        return 0.1 * m * math.log(factor) + 1.0 if factor > 1.0 else 1.0
+
+    def _scale(self) -> float:
+        """The softmax scale: ``(dn + dr)^-1/2 m^2``."""
+        conf = self.conf
+        _, dn, dr, _, _ = self._dims()
+        m = self._mscale(float(conf.yarn_factor),
+                         float(conf.yarn_mscale_all_dim))
+        return (dn + dr) ** -0.5 * m * m
+
+    def _rope(self, a, pos0):
+        """Rotate-half RoPE on [B, T, H, dr] at the layer's frequencies,
+        cos/sin scaled by ``mscale(factor, mscale) / mscale(factor,
+        mscale_all_dim)``; ``pos0`` a scalar or [B]."""
+        conf = self.conf
+        B, T, H, D = a.shape
+        half = D // 2
+        f = float(conf.yarn_factor)
+        amp = self._mscale(f, float(conf.yarn_mscale)) \
+            / self._mscale(f, float(conf.yarn_mscale_all_dim))
+        pos = jnp.asarray(pos0, jnp.float32).reshape(-1, 1)        # [B|1, 1]
+        ang = (pos + jnp.arange(T, dtype=jnp.float32)[None, :])[..., None] \
+            * self._inv_freq()                                  # [B|1, T, half]
+        cos = (jnp.cos(ang) * amp)[:, :, None, :].astype(a.dtype)
+        sin = (jnp.sin(ang) * amp)[:, :, None, :].astype(a.dtype)
+        a1, a2 = a[..., :half], a[..., half:]
+        return jnp.concatenate([a1 * cos - a2 * sin, a1 * sin + a2 * cos],
+                               axis=-1)
+
+    def _rms(self, x, gain):
+        ms = jnp.mean(jnp.square(x.astype(jnp.float32)), -1, keepdims=True)
+        return x * jax.lax.rsqrt(ms + self.conf.eps).astype(x.dtype) * gain
+
+    def _project(self, params, x, pos0=0):
+        """x [B, T, n_in] at positions from ``pos0`` -> q_n [B, T, H, dn],
+        q_r [B, T, H, dr] (rotated) and the rows to cache [B, T, C + dr]."""
+        H, dn, dr, _, C = self._dims()
+        B, T, _ = x.shape
+        cq = self._rms(jnp.einsum("btf,fo->bto", x, params["Wdq"]),
+                       params["q_gain"])
+        q = jnp.einsum("btq,qo->bto", cq, params["Wuq"]).reshape(
+            B, T, H, dn + dr)
+        ckv = jnp.einsum("btf,fo->bto", x, params["Wdkv"])
+        c = self._rms(ckv[..., :C], params["kv_gain"])
+        kr = self._rope(ckv[..., None, C:], pos0)[:, :, 0]
+        return (q[..., :dn], self._rope(q[..., dn:], pos0),
+                jnp.concatenate([c, kr], axis=-1))
+
+    def _softmax(self, s, valid):
+        s = jnp.where(valid[:, None], s * self._scale(),
+                      jnp.finfo(jnp.float32).min)
+        return jax.nn.softmax(s, axis=-1)
+
+    def _expanded(self, params, q_n, q_r, rows, valid):
+        """Keys and values rebuilt from ``rows`` [B, L, C + dr], once; the
+        queries attend ``_QBLOCK`` at a time. ``valid`` [B, T, L]."""
+        H, dn, dr, dv, C = self._dims()
+        B, L, _ = rows.shape
+        kv = jnp.einsum("blc,co->blo", rows[..., :C], params["Wukv"]
+                        ).reshape(B, L, H, dn + dv)
+        k_n, v, k_r = kv[..., :dn], kv[..., dn:], rows[..., C:]
+        f32 = jnp.float32
+
+        def attend(q_n, q_r, valid):
+            s = jnp.einsum("bthd,blhd->bhtl", q_n, k_n,
+                           preferred_element_type=f32) \
+                + jnp.einsum("bthr,blr->bhtl", q_r, k_r,
+                             preferred_element_type=f32)
+            p = self._softmax(s, valid).astype(v.dtype)
+            return jnp.einsum("bhtl,blhd->bthd", p, v)
+
+        return self._by_query_blocks(attend, q_n, q_r, valid)
+
+    def _absorbed(self, params, q_n, q_r, rows, valid):
+        """``Wuk`` folded into the query, ``Wuv`` applied after the weighted
+        sum of latents: nothing is rebuilt a row."""
+        H, dn, dr, dv, C = self._dims()
+        w = params["Wukv"].reshape(C, H, dn + dv)
+        q = jnp.concatenate(
+            [jnp.einsum("bthd,chd->bthc", q_n, w[..., :dn]), q_r], axis=-1)
+
+        def attend(q, valid):
+            s = jnp.einsum("bthr,blr->bhtl", q, rows,
+                           preferred_element_type=jnp.float32)
+            p = self._softmax(s, valid).astype(rows.dtype)
+            # over the whole row, the rotated key's 64 columns dropped
+            # after: a slice of the rows first is a copy of the gather
+            return jnp.einsum("bhtl,blr->bthr", p, rows)[..., :C]
+
+        oc = self._by_query_blocks(attend, q, valid)
+        return jnp.einsum("bthc,chd->bthd", oc, w[..., dn:])
+
+    def _by_query_blocks(self, attend, *args):
+        """``attend`` over the T axis (axis 1 of every argument) in blocks
+        of ``_QBLOCK`` queries where T is a multiple of it and longer."""
+        T, qb = args[0].shape[1], self._QBLOCK
+        if T <= qb or T % qb:
+            return attend(*args)
+        split = lambda a: jnp.moveaxis(
+            a.reshape((a.shape[0], T // qb, qb) + a.shape[2:]), 1, 0)
+        out = jax.lax.map(lambda blk: attend(*blk), tuple(map(split, args)))
+        out = jnp.moveaxis(out, 0, 1)
+        return out.reshape((out.shape[0], T) + out.shape[3:])
+
+    def _out(self, params, o, B, T):
+        return self.activation_fn()(jnp.einsum(
+            "btm,mn->btn", o.reshape(B, T, -1), params["Wo"]))
+
+    def forward(self, params, x, *, train=False, rng=None, variables=None,
+                mask=None):
+        """The full-sequence function, expanded (training, scoring, the
+        tests' comparison with the plain reference)."""
+        x = self._dropout(x, train, rng)
+        B, T, _ = x.shape
+        q_n, q_r, rows = self._project(params, x)
+        t = jnp.arange(T)
+        with jax.named_scope("latent_attention"):
+            o = self._expanded(params, q_n, q_r, rows,
+                               (t[None, :] <= t[:, None])[None])
+        if mask is not None:
+            o = o * mask[:, :, None, None].astype(o.dtype)
+        return self._out(params, o, B, T), variables or {}
+
+    def forward_with_state(self, params, x, state0, *, train=False, rng=None,
+                           mask=None):
+        if not train and state0 is not None and "c_pages" not in state0:
+            raise NotImplementedError(
+                "LatentAttentionLayer streams through the paged pool only "
+                "(DecodeScheduler(kv_pool_mb=...)): rnn_time_step over a "
+                "contiguous stripe is not served yet")
+        if train or state0 is None:
+            y, _ = self.forward(params, x, train=train, rng=rng, mask=mask)
+            return y, state0
+        return self._paged_step(params, x, state0, mask=mask)
+
+    def _paged_step(self, params, x, state0, *, mask=None):
+        """``table`` [B, nb] and ``wmask`` [B, T] as in the parent: a row
+        ``wmask`` holds off is zeroed and lands in the scratch page. The
+        step attends absorbed, whatever T (the class docstring)."""
+        B, T, _ = x.shape
+        _, _, dr, _, C = self._dims()
+        R = C + dr
+        pos, table = state0["pos"], state0["table"]
+        cp = state0["c_pages"]                     # [pages, Bk / k, k * R]
+        k = cp.shape[2] // R
+        Bk, nb = cp.shape[1] * k, table.shape[1]
+        L = nb * Bk
+        wmask = state0.get("wmask")
+        overflow = (pos + T) > L
+        q_n, q_r, rows_new = self._project(params, x, pos0=pos)
+        p = pos[:, None] + jnp.arange(T, dtype=pos.dtype)[None, :]   # [B, T]
+        blk, off = self._page_of(table, p, Bk, wmask)
+        if wmask is not None:       # pages hold finite rows only
+            rows_new = jnp.where(wmask[..., None], rows_new, 0)
+        # one window of R values a row, at (page, off // k, (off % k) R)
+        cp2 = jax.lax.scatter(
+            cp, jnp.stack([blk, off // k, off % k * R], -1).reshape(-1, 3),
+            rows_new.reshape(-1, R),
+            jax.lax.ScatterDimensionNumbers(
+                update_window_dims=(1,), inserted_window_dims=(0, 1),
+                scatter_dims_to_operand_dims=(0, 1, 2)))
+        def read(table, q_n, q_r, p):
+            rows = cp2[table].reshape(table.shape[0], L, R)
+            valid = jnp.arange(L, dtype=pos.dtype)[None, None, :] \
+                <= p[:, :, None]
+            return self._absorbed(params, q_n, q_r, rows, valid)
+
+        # the gathered rows of `_SLOTS` slots at a time: at 48 slots and a
+        # table of 16,384 positions the gather and its two relayouts are
+        # 2.8 GB of temporaries at once, a third of that in groups
+        G = self._SLOTS
+        with jax.named_scope("latent_attention"):
+            if B <= G or B % G:
+                o = read(table, q_n, q_r, p)
+            else:
+                o = jax.lax.map(lambda a: read(*a), tuple(
+                    a.reshape((B // G, G) + a.shape[1:])
+                    for a in (table, q_n, q_r, p)))
+                o = o.reshape((B,) + o.shape[2:])
+        if mask is not None:
+            o = o * mask[:, :, None, None].astype(o.dtype)
+        y = self._out(params, o, B, T)
+        y = jnp.where(overflow[:, None, None],
+                      jnp.asarray(jnp.nan, y.dtype), y)
+        next_pos = jnp.where(overflow, jnp.asarray(1 << 30, jnp.int32),
+                             pos + T)
+        return y, {"c_pages": cp2, "pos": next_pos}

@@ -1,0 +1,165 @@
+"""One device's share of a routed mixture of experts.
+
+`RoutedExpertsLayer` (conf) is what expert parallelism asks of a layer
+whatever the number of devices: it routes over ALL ``n_experts``, is told
+which experts it holds (``held = (first, count)``), and returns the part of
+the result its own experts give,
+
+    sigma = sigmoid(x Wr)                     all n_experts outputs, float32
+    T     = the top_k largest of sigma
+    g_e   = scale * sigma_e / sum_T sigma     (norm_topk; over ALL chosen)
+    y     = sum_{e in T, e held} g_e E_e(x),  E_e(x) = (silu(x Wg_e) * x Wu_e) Wd_e
+
+What the absent experts would add is left out; summing the shares of every
+device gives the whole layer (tests/test_routed_experts.py). There is no
+capacity factor and no token is dropped. On one device the layer runs
+without its exchange; nothing here stands in for the absent devices
+(`parallel/moe.py` is another thing: top-1, capacity-dropping, `shard_map`).
+
+The grouped path (`_grouped`, inference) sorts the token-expert pairs that
+fall on held experts by expert and walks them in tiles of ``tm`` rows, each
+tile one expert's: a `fori_loop` over the tiles that hold a pair, so an
+expert no token chose is not read and the cost follows the routing, at
+static shapes (``tokens * top_k`` pairs at most). The dense path (`_dense`,
+training: the loop's trip count is data) runs every held expert over every
+token under a mask, and is what the grouped path is tested against.
+
+At inference the layer hands back, as its ``routing_counts`` variable, the
+pairs that fell on each held expert (int32 [count]); the serving engine
+reads them with the step's probabilities (`inference/engine.py`). A feature
+``mask`` ([B, T]; the engine's live lanes) keeps padded lanes out of the
+routing altogether."""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from .base import LayerImpl, register_impl
+from .. import weights as winit
+
+_TILE = 128     # rows of one expert a grouped step multiplies at once
+
+
+@register_impl("RoutedExpertsLayer")
+class RoutedExpertsLayerImpl(LayerImpl):
+    WEIGHT_KEYS = ("Wr", "Wg", "Wu", "Wd")
+
+    def _held(self):
+        conf = self.conf
+        first, count = conf.held if conf.held is not None \
+            else (0, conf.n_experts)
+        if not (0 <= first and count >= 1
+                and first + count <= conf.n_experts):
+            raise ValueError(f"held={conf.held} is not a range of the "
+                             f"{conf.n_experts} experts")
+        if not 1 <= conf.top_k <= conf.n_experts:
+            raise ValueError(f"top_k={conf.top_k} of {conf.n_experts}")
+        return int(first), int(count)
+
+    def init_params(self, key, dtype=jnp.float32):
+        conf = self.conf
+        _, G = self._held()
+        d, f = conf.n_in, int(conf.width)
+        dist = conf.dist.spec() if getattr(conf, "dist", None) is not None \
+            else None
+        init = conf.weight_init or "xavier"
+        kr, kg, ku, kd = jax.random.split(key, 4)
+
+        def stack(k, i, o):
+            return jnp.stack([winit.init_weights(kk, (i, o), init, dist, dtype)
+                              for kk in jax.random.split(k, G)])
+
+        return {"Wr": winit.init_weights(kr, (d, conf.n_experts), init, dist,
+                                         dtype),
+                "Wg": stack(kg, d, f), "Wu": stack(ku, d, f),
+                "Wd": stack(kd, f, d)}
+
+    # -- routing: one function, shared by both paths ----------------------------
+    def route(self, params, x):
+        """x [N, d] -> (experts [N, top_k] int32 over all n_experts, gates
+        [N, top_k] float32): ``topk_method`` none, i.e. no groups and no
+        selection bias, the ``top_k`` largest of all the scores."""
+        conf = self.conf
+        logits = jnp.einsum("nd,de->ne", x, params["Wr"],
+                            preferred_element_type=jnp.float32)
+        if conf.scoring != "sigmoid":
+            raise ValueError(f"scoring {conf.scoring!r} is not built: "
+                             "'sigmoid' is")
+        top, idx = jax.lax.top_k(jax.nn.sigmoid(logits), int(conf.top_k))
+        if conf.norm_topk:
+            top = top / jnp.sum(top, axis=-1, keepdims=True)
+        return idx.astype(jnp.int32), top * float(conf.scale)
+
+    @staticmethod
+    def _expert(params, e, xs):
+        """Expert ``e`` (held index, may be traced) on rows xs [m, d]."""
+        f32 = jnp.float32
+        g = jnp.dot(xs, params["Wg"][e], preferred_element_type=f32)
+        u = jnp.dot(xs, params["Wu"][e], preferred_element_type=f32)
+        h = (jax.nn.silu(g) * u).astype(xs.dtype)
+        return jnp.dot(h, params["Wd"][e], preferred_element_type=f32)
+
+    def _dense(self, params, x, local, gates):
+        """Every held expert over every token, weighted by its gate where
+        the token chose it (0 elsewhere). local [N, k]: held index, or
+        ``count`` for a pair that is not ours."""
+        _, G = self._held()
+        w = jnp.sum(jnp.where(local[..., None] == jnp.arange(G), gates[..., None],
+                              0.0), axis=1)                           # [N, G]
+
+        def one(y, e):
+            return y + w[:, e, None] * self._expert(params, e, x), None
+
+        y, _ = jax.lax.scan(one, jnp.zeros(x.shape, jnp.float32),
+                            jnp.arange(G))
+        return y
+
+    def _grouped(self, params, x, local, gates, counts):
+        """The pairs on held experts, sorted by expert, in tiles of ``tm``
+        rows of one expert each; only tiles that hold a pair run."""
+        _, G = self._held()
+        N, k = local.shape
+        tm = min(_TILE, -(-N // 8) * 8)
+        order = jnp.argsort(local.reshape(-1), stable=True)   # ours first
+        tok = (order // k).astype(jnp.int32)
+        gate = gates.reshape(-1)[order]
+        tiles = -(-counts // tm)                               # [G] per expert
+        tile_end = jnp.cumsum(tiles)
+        row0 = jnp.cumsum(counts) - counts     # an expert's first sorted row
+        lane = jnp.arange(tm, dtype=jnp.int32)
+
+        def tile(t, y):
+            e = jnp.searchsorted(tile_end, t, side="right").astype(jnp.int32)
+            at = (t - (tile_end[e] - tiles[e])) * tm + lane    # rows within e
+            src = jnp.minimum(row0[e] + at, N * k - 1)
+            ours = at < counts[e]
+            rows = tok[src]
+            out = self._expert(params, e, x[rows])
+            return y.at[rows].add(out * jnp.where(ours, gate[src], 0.0)[:, None])
+
+        return jax.lax.fori_loop(0, tile_end[-1], tile,
+                                 jnp.zeros(x.shape, jnp.float32))
+
+    def forward(self, params, x, *, train=False, rng=None, variables=None,
+                mask=None):
+        x = self._dropout(x, train, rng)
+        first, G = self._held()
+        shape = x.shape
+        xf = x.reshape(-1, shape[-1])
+        with jax.named_scope("routed_experts"):
+            idx, gates = self.route(params, xf)
+            local = idx - first
+            ours = (local >= 0) & (local < G)
+            if mask is not None:
+                ours &= (mask.reshape(-1) > 0)[:, None]
+            local = jnp.where(ours, local, G)
+            counts = jnp.sum(local.reshape(-1)[:, None] == jnp.arange(G),
+                             axis=0, dtype=jnp.int32)
+            if train:
+                y = self._dense(params, xf, local, gates)
+            else:
+                y = self._grouped(params, xf, local, gates, counts)
+        y = self.activation_fn()(y.astype(x.dtype).reshape(shape))
+        if train:
+            return y, variables or {}
+        return y, {**(variables or {}), "routing_counts": counts}

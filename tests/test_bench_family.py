@@ -1,5 +1,5 @@
 """The benchmark's family loader, in tier-1 (ISSUE 28 asked for it, ISSUE 30
-brings it with the second real family): every configuration of
+brings it with the second real family, ISSUE 34 the third): every configuration of
 `BENCHMARK.json` resolves to its family's four files, an unknown `model_type`
 names the directory to add, a family lacking a file or a function fails at
 load and not in mid-run, and `evabyte-d16.json` is the published
@@ -52,9 +52,10 @@ def test_the_scheduler_takes_every_workloads_engine_block_whole(path):
 
 
 def test_the_benchmark_has_two_families():
+    """... and since ISSUE 34 a third, `axk1`."""
     types = {json.loads((REPO / c["file"]).read_text())["model_type"]
              for c in BENCH["configs"]}
-    assert types == {"starcoder2", "evabyte"}
+    assert types == {"starcoder2", "evabyte", "axk1"}
     assert {p.name for p in (REPO / "benchmark" / "families").iterdir()
             if p.is_dir() and p.name != "__pycache__"} == types
 
@@ -159,3 +160,120 @@ def test_a_toy_evabyte_cell_runs_to_a_correct_line(tmp_path):
     assert 0 < m["eva_summary_row_share"] < 100
     assert "kv_pool_peak_pct" not in m and "sched_iter_ms" in m
     assert line["harness"]["phase_ms_per_iter"]["roll"] > 0
+
+
+# -- the third family: A.X-K1's share of a 16-chip deployment (ISSUE 34) ----
+
+def _axk1():
+    entry = next(c for c in BENCH["configs"] if c["name"] == "axk1-ep16-d6")
+    path = REPO / entry["file"]
+    return entry, path, json.loads(path.read_text())
+
+
+def test_axk1_ep16_d6_is_the_published_configuration_but_for_reduced():
+    entry, path, cfg = _axk1()
+    source = json.loads(path.with_suffix(".published.json").read_text())
+    assert source.pop("source") == entry["source"] == cfg["source"]
+    assert cfg["reduced"] == entry["reduced"] == \
+        ["num_hidden_layers", "n_routed_experts", "vocab_size"]
+    assert cfg["published"] == {"num_hidden_layers": 61,
+                                "n_routed_experts": 192,
+                                "vocab_size": 163840}
+    assert (cfg["num_hidden_layers"], cfg["n_routed_experts"],
+            cfg["vocab_size"]) == (6, 12, 20480)
+    for key, value in source.items():
+        if key in cfg["reduced"]:
+            assert cfg["published"][key] == value and cfg[key] < value, key
+        else:
+            assert cfg[key] == value, key
+    # the floors of a cut: the dense layer once and >= 4 routed layers,
+    # >= 8 experts, >= an eighth of the vocabulary; every width as published
+    assert cfg["num_hidden_layers"] - cfg["first_k_dense_replace"] >= 4
+    assert cfg["n_routed_experts"] >= 8
+    assert cfg["vocab_size"] * 8 >= cfg["published"]["vocab_size"]
+    assert cfg["router_outputs"] == 192 and cfg["num_experts_per_tok"] == 8
+    assert (cfg["hidden_size"], cfg["intermediate_size"],
+            cfg["moe_intermediate_size"], cfg["q_lora_rank"],
+            cfg["kv_lora_rank"], cfg["qk_nope_head_dim"],
+            cfg["qk_rope_head_dim"], cfg["v_head_dim"]) == \
+        (7168, 18432, 2048, 1536, 512, 128, 64, 128)
+    assert cfg["rope_scaling"]["factor"] == 32
+    assert cfg["deployment"].startswith("one chip's share of a 16-chip")
+    assert any("topk_method" in a and "_route" in a for a in cfg["assumed"])
+    assert any("rotate-half" in d for d in cfg["departures"])
+
+
+def _counters(**kw):
+    return {"window": {"counters": kw}}
+
+
+def test_axk1_s_work_follows_the_routing():
+    """The issue's arithmetic, and a decode step with all twelve held
+    experts hit, and with none."""
+    _, _, cfg = _axk1()
+    work = family.load(REPO, cfg).work
+    assert work.attn_params(cfg) == 101_122_048
+    assert work.expert_params(cfg) == 44_040_192
+    matrices = 4_166_189_056
+    vectors = work.vector_params(cfg) + 7168 + 20480 + 7168
+    assert vectors == 6 * (2 * 7168 + 1536 + 512) + (2 * 18432 + 7168) \
+        + 5 * (2 * 2048 + 7168) + 2 * 7168 + 20480
+    assert work.param_count(cfg) == matrices + vectors
+    assert work.kv_bytes_per_position(cfg) == 6912
+    # no run: even routing (12 of 192 of the pairs), every expert hit
+    assert work.routing(cfg, None) == (12 / 192, 1.0)
+    experts = 5 * 12 * 44_040_192 * 2
+    run_all = _counters(moe_pairs_routed_total=1600, moe_pairs_held_total=100,
+                        moe_expert_slots_total=600, moe_experts_hit_total=600)
+    run_none = _counters(moe_pairs_routed_total=1600, moe_pairs_held_total=0,
+                         moe_expert_slots_total=600, moe_experts_hit_total=0)
+    _, b_all = work.decode_step(cfg, [], run=run_all)
+    _, b_none = work.decode_step(cfg, [], run=run_none)
+    assert b_all - b_none == experts
+    assert b_all == (matrices - 20480 * 7168 + vectors - 7168) * 2
+    f_all, b1 = work.decode_step(cfg, [5000], run=run_all)
+    f_none, _ = work.decode_step(cfg, [5000], run=run_none)
+    # 8 experts a token x 5 layers x a sixteenth of the pairs, 88.08 MFLOP each
+    assert f_all - f_none == pytest.approx(8 * 5 / 16 * 2 * 44_040_192)
+    assert b1 - b_all == 5000 * 6912 + 6912 + 7168 * 2
+    outside = work.matmul_params_outside_experts(cfg)
+    assert f_none == 2 * (outside + 7168 * 20480) \
+        + 5000 * 6 * 2 * 64 * (576 + 512)
+    # a chunk: expanded attention, 320 operations a head a query-key pair;
+    # 4,096 pairs a layer reach every held expert
+    fc, bc = work.prefill_chunk(cfg, 512, 1024, False, run=run_none)
+    assert fc == 512 * 2 * outside \
+        + (512 * 1024 + 512 * 513 // 2) * 6 * 2 * 64 * 320
+    _, bc_all = work.prefill_chunk(cfg, 512, 1024, False)
+    assert bc_all - bc == pytest.approx(experts, rel=1e-6)
+
+
+def test_a_toy_axk1_cell_runs_to_a_correct_line(tmp_path):
+    """The command itself, on the CPU at the tests' small size, in a scratch
+    root: the third family through `run.py` to a `correct` line, with the
+    readers this PR adds finding what they read."""
+    import os
+    import subprocess
+    from benchmark.tests.util import make_root
+    sys.path.insert(0, str(Path(__file__).resolve().parent))
+    from axk1_util import cfg
+    root = make_root(tmp_path, config="tiny-axk1", cell="toy.axk1",
+                     config_keys=cfg(4, 8), like="axk1-ep16-d6.longctx-chat")
+    p = subprocess.run(
+        [sys.executable, str(root / "benchmark" / "run.py"), "--bench-root",
+         str(root), "--workload", "toy.axk1", "--seconds", "3", "--seed",
+         "3000000011", "--rehearse-cpu", "--trace", "1"],
+        capture_output=True, text=True, cwd=str(root), timeout=900,
+        env={**os.environ, "PYTHONPATH": str(REPO)})
+    assert p.returncode == 0, p.stderr[-3000:]
+    line = json.loads(p.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["failed"] == 0
+    for name, (value, limit) in line["checks"].items():
+        assert value <= limit, name
+    m = {k: v["value"] for k, v in line["metrics"].items()}
+    assert 0 < m["moe_held_pair_share"] < 100
+    assert 0 < m["moe_experts_hit_share"] <= 100
+    assert 0 < m["mla_pool_peak_pct"] <= 100
+    assert m["mla_rows_per_decode_token"] > 16
+    assert "kv_pool_peak_pct" not in m and "eva_roll_ms" not in m
+    assert "sched_iter_ms" in m and "longctx.ttft_p95_ms" in m

@@ -43,10 +43,14 @@ def _lm(v=13, cache=96):
     return ComputationGraph(conf).init()
 
 
-def _fake_attn_states(n_layers=2, n_slots=2, L=64, Hkv=2, Dh=8):
-    return {f"l{i}": {"k": jnp.zeros((n_slots, L, Hkv, Dh)),
-                      "v": jnp.zeros((n_slots, L, Hkv, Dh)),
-                      "pos": jnp.zeros((n_slots,), jnp.int32)}
+def _fake_attn_states(n_layers=2, Hkv=2, Dh=8, cache_dtype=None):
+    """What a `KVPool` is handed: each layer's paged leaves, as the layer
+    states them (`SelfAttentionLayerImpl.paged_leaves`)."""
+    from deeplearning4j_tpu.nn.conf.layers import SelfAttentionLayer
+    from deeplearning4j_tpu.nn.layers.base import impl_for
+    impl = impl_for(SelfAttentionLayer(n_in=Hkv * Dh, n_out=Hkv * Dh,
+                                       n_heads=Hkv))
+    return {f"l{i}": impl.paged_leaves(4, jnp.float32, cache_dtype)
             for i in range(n_layers)}
 
 
@@ -89,13 +93,16 @@ def test_pool_capacity_respects_budget_and_reserves_scratch():
 
 def test_kvpool_has_one_mode_and_int8_needs_none():
     """There is no ``paged`` switch to pass, and an int8 pool is asked
-    for by ``cache_dtype`` alone: the same budget holds more blocks."""
+    for by the layers' int8 leaves alone (no ``cache_dtype`` of the pool's
+    own): the same budget holds more blocks."""
     st = _fake_attn_states()
     with pytest.raises(TypeError, match="paged"):
         KVPool(st, block=4, budget_bytes=5 * 1024, paged=True)
+    with pytest.raises(TypeError, match="cache_dtype"):
+        KVPool(st, block=4, budget_bytes=5 * 1024, cache_dtype="int8")
     m = MetricsRegistry()
-    pool = KVPool(st, block=4, budget_bytes=5 * 1024, cache_dtype="int8",
-                  metrics=m)
+    pool = KVPool(_fake_attn_states(cache_dtype="int8"), block=4,
+                  budget_bytes=5 * 1024, metrics=m)
     # 2 layers * (k+v) * block4 * (2*8 int8 + 2 f32 scales) = 384
     assert pool.bytes_per_block == 384
     assert pool.capacity_blocks == 5 * 1024 // 384 - 1
