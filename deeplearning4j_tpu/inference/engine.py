@@ -500,7 +500,8 @@ class DecodeScheduler:
     stays <= 1 program per table bucket). `paged_kernel_engaged` gauge
     + the ``paged_kernel`` block of :meth:`debug_snapshot` report the
     per-bucket verdicts; `kv_pages_read_total` over
-    `kv_pages_bucket_total` says how much of the tables a step read.
+    `kv_pages_bucket_total` (`eva_` / `mla_` for an EVA / a latent net)
+    says how much of the tables a step read.
 
     ``transfer_guard``: device-residency audit mode. When set (e.g.
     "disallow"), every scheduler iteration runs under that thread-local
@@ -1130,9 +1131,11 @@ class DecodeScheduler:
             # pages a decode dispatch names (every slot's page list at
             # the bucket's width) and pages it reads, from host-side
             # depths: equal unless the fused read engages. Named for what
-            # a page list is: the block table (`kv_`), or EVA's
-            # open-window pages and summary pages (`eva_`)
-            kind = "kv" if self._eva is None else "eva"
+            # a page list is: the block table (`kv_`; `mla_` where its
+            # pages hold latent rows), or EVA's open-window pages and
+            # summary pages (`eva_`)
+            kind = ("eva" if self._eva is not None else
+                    "mla" if latent is not None else "kv")
             self._m_pages_bucket = m.counter(
                 f"{kind}_pages_bucket_total",
                 help="pages in the page lists of decode dispatches: slots "

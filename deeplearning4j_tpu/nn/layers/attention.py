@@ -314,10 +314,13 @@ class SelfAttentionLayerImpl(BaseRecurrentImpl):
             return False
         # imported where it is used: Pallas costs a second to import
         from ...ops.paged_read import list_fits
-        conf = self.conf
-        return list_fits(slots, pages, block * self._kv_heads()
-                         * (conf.n_out // conf.n_heads)
+        return list_fits(slots, pages, block * self._position_values()
                          * jnp.dtype(dtype).itemsize)
+
+    def _position_values(self) -> int:
+        """Values a position keeps in one page leaf: what the kernel's DMA
+        of a page brings in, over ``block``."""
+        return self._kv_heads() * (self.conf.n_out // self.conf.n_heads)
 
     @staticmethod
     def _page_of(table, p, Bk, wmask=None):
@@ -331,6 +334,18 @@ class SelfAttentionLayerImpl(BaseRecurrentImpl):
         if wmask is not None:
             blk = jnp.where(wmask, blk, 0)
         return jnp.where(p // Bk < nb, blk, 0), p % Bk
+
+    @staticmethod
+    def _rows_held(pos, nb, Bk, wmask=None):
+        """[B, nb]: the rows each page of a block table holds for the query
+        at ``pos`` (positions up to it; nothing for a lane ``wmask`` holds
+        off): the counts `ops.paged_read` reads a table by."""
+        rows = jnp.clip(pos[:, None] + 1
+                        - jnp.arange(nb, dtype=pos.dtype)[None, :] * Bk,
+                        0, Bk)
+        if wmask is not None:
+            rows = jnp.where(wmask[:, :1], rows, 0)
+        return rows
 
     def _paged_step(self, params, x, state0, *, mask=None):
         """Paged-KV inference step (inference/kvpool.py, the ISSUE 6
@@ -419,17 +434,10 @@ class SelfAttentionLayerImpl(BaseRecurrentImpl):
         if not quantized and self.fused_read_engages(
                 mode, T, q.dtype, state0.get("mesh"), slots=B, pages=nb,
                 block=Bk):
-            # rows each page holds for this query: positions up to pos,
-            # nothing for a lane off
-            rows = jnp.clip(pos[:, None] + 1
-                            - jnp.arange(nb, dtype=pos.dtype)[None, :] * Bk,
-                            0, Bk)
-            if wmask is not None:
-                rows = jnp.where(wmask[:, :1], rows, 0)
             from ...ops.paged_read import paged_read_attention
             with jax.named_scope("paged_attention"):
                 o = paged_read_attention(
-                    q, kp2, vp2, table, rows,
+                    q, kp2, vp2, table, self._rows_held(pos, nb, Bk, wmask),
                     interpret=jax.default_backend() != "tpu")
         elif T == 1:
             # the older page-walk kernel behind the seam, or None = run
@@ -722,14 +730,21 @@ class LatentAttentionLayerImpl(SelfAttentionLayerImpl):
     k positions side by side so that a page's last dimension is a multiple
     of 128 lanes, `_rows_packed`: k = 2 at C + dr = 576), one row a
     position kept to the request's end (`page_recycling` is None:
-    the prefix trie shares latent pages as it shares K/V pages). The read
-    is XLA's gather of the slot's table at the bucket's width."""
+    the prefix trie shares latent pages as it shares K/V pages). The T = 1
+    read on a TPU is the fused paged read in its one-buffer form
+    (`fused_read_engages`, `ops.paged_read.paged_read_attention` with no
+    value pages: a page is brought in once, as that ``[block / k, k (C +
+    dr)]`` matrix, and is key to the absorbed query and value in one; the
+    softmax's scale is this layer's ``s``; ``Wuv`` and ``Wo`` stay in XLA).
+    A prefill chunk, ``"off"`` and ``"auto"`` off the TPU read through XLA's
+    gather of the slot's table at the bucket's width, the body the tests
+    compare the kernel with."""
 
     WEIGHT_KEYS = ("Wdq", "Wuq", "Wdkv", "Wukv", "Wo")
     # queries of a long chunk attend this many at a time, so that the
     # float32 scores of 64 heads over a deep table stay a few hundred MB
     _QBLOCK = 128
-    # slots whose tables a paged step gathers at a time
+    # slots whose tables the gather body of a paged step gathers at a time
     _SLOTS = 16
 
     def _dims(self):
@@ -782,8 +797,9 @@ class LatentAttentionLayerImpl(SelfAttentionLayerImpl):
         return {"c_pages": ((int(block) // k, k * (C + dr)),
                             jnp.dtype(dtype))}
 
-    def fused_read_engages(self, mode, T, dtype, mesh=None, **_):
-        return False    # no fused latent read yet: the gather serves
+    def _position_values(self) -> int:
+        _, _, dr, _, C = self._dims()
+        return C + dr
 
     # -- the pieces of the written equations ----------------------------------
     def _inv_freq(self):
@@ -888,10 +904,6 @@ class LatentAttentionLayerImpl(SelfAttentionLayerImpl):
     def _absorbed(self, params, q_n, q_r, rows, valid):
         """``Wuk`` folded into the query, ``Wuv`` applied after the weighted
         sum of latents: nothing is rebuilt a row."""
-        H, dn, dr, dv, C = self._dims()
-        w = params["Wukv"].reshape(C, H, dn + dv)
-        q = jnp.concatenate(
-            [jnp.einsum("bthd,chd->bthc", q_n, w[..., :dn]), q_r], axis=-1)
 
         def attend(q, valid):
             s = jnp.einsum("bthr,blr->bhtl", q, rows,
@@ -899,10 +911,21 @@ class LatentAttentionLayerImpl(SelfAttentionLayerImpl):
             p = self._softmax(s, valid).astype(rows.dtype)
             # over the whole row, the rotated key's 64 columns dropped
             # after: a slice of the rows first is a copy of the gather
-            return jnp.einsum("bhtl,blr->bthr", p, rows)[..., :C]
+            return jnp.einsum("bhtl,blr->bthr", p, rows)
 
-        oc = self._by_query_blocks(attend, q, valid)
-        return jnp.einsum("bthc,chd->bthd", oc, w[..., dn:])
+        return self._absorb(params, q_n, q_r, lambda q: self._by_query_blocks(
+            attend, q, valid))
+
+    def _absorb(self, params, q_n, q_r, read):
+        """The absorbed form around its read: ``read`` takes the query
+        ``[q_n Wuk^T | q_r]`` ([B, T, H, C + dr], the key of a cached row)
+        and gives ``sum p row`` over the rows as wide; the first C columns
+        of that go through ``Wuv``."""
+        H, dn, dr, dv, C = self._dims()
+        w = params["Wukv"].reshape(C, H, dn + dv)
+        q = jnp.concatenate(
+            [jnp.einsum("bthd,chd->bthc", q_n, w[..., :dn]), q_r], axis=-1)
+        return jnp.einsum("bthc,chd->bthd", read(q)[..., :C], w[..., dn:])
 
     def _by_query_blocks(self, attend, *args):
         """``attend`` over the T axis (axis 1 of every argument) in blocks
@@ -950,7 +973,8 @@ class LatentAttentionLayerImpl(SelfAttentionLayerImpl):
     def _paged_step(self, params, x, state0, *, mask=None):
         """``table`` [B, nb] and ``wmask`` [B, T] as in the parent: a row
         ``wmask`` holds off is zeroed and lands in the scratch page. The
-        step attends absorbed, whatever T (the class docstring)."""
+        step attends absorbed, whatever T, and at T = 1 through the fused
+        paged read where the rule engages it (the class docstring)."""
         B, T, _ = x.shape
         _, _, dr, _, C = self._dims()
         R = C + dr
@@ -979,14 +1003,27 @@ class LatentAttentionLayerImpl(SelfAttentionLayerImpl):
                 <= p[:, :, None]
             return self._absorbed(params, q_n, q_r, rows, valid)
 
-        # the gathered rows of `_SLOTS` slots at a time: at 48 slots and a
-        # table of 16,384 positions the gather and its two relayouts are
-        # 2.8 GB of temporaries at once, a third of that in groups
         G = self._SLOTS
         with jax.named_scope("latent_attention"):
-            if B <= G or B % G:
+            if self.fused_read_engages(state0.get("paged_kernel", "auto"), T,
+                                       q_n.dtype, state0.get("mesh"), slots=B,
+                                       pages=nb, block=Bk):
+                # the pages as the pool lays them out, each brought in
+                # once as key and value
+                from ...ops.paged_read import paged_read_attention
+                o = self._absorb(
+                    params, q_n, q_r, lambda q: paged_read_attention(
+                        q, cp2, None, table,
+                        self._rows_held(pos, nb, Bk, wmask),
+                        scale=self._scale(),
+                        interpret=jax.default_backend() != "tpu"))
+            elif B <= G or B % G:
                 o = read(table, q_n, q_r, p)
             else:
+                # the gathered rows of `_SLOTS` slots at a time: at 48 slots
+                # and a table of 16,384 positions the gather and its two
+                # relayouts are 2.8 GB of temporaries at once, a third of
+                # that in groups
                 o = jax.lax.map(lambda a: read(*a), tuple(
                     a.reshape((B // G, G) + a.shape[1:])
                     for a in (table, q_n, q_r, p)))

@@ -275,5 +275,8 @@ def test_a_toy_axk1_cell_runs_to_a_correct_line(tmp_path):
     assert 0 < m["moe_experts_hit_share"] <= 100
     assert 0 < m["mla_pool_peak_pct"] <= 100
     assert m["mla_rows_per_decode_token"] > 16
+    # the CPU under "auto" keeps the gather body: every page named is read
+    assert m["mla_pages_read_share"] == 100.0
+    assert "kv_pages_read_share" not in m
     assert "kv_pool_peak_pct" not in m and "eva_roll_ms" not in m
     assert "sched_iter_ms" in m and "longctx.ttft_p95_ms" in m

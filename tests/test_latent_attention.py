@@ -199,8 +199,13 @@ def test_one_leaf_and_no_contiguous_stripe(small):
     assert real.paged_leaves(64, "bfloat16") == {
         "c_pages": ((32, 1152), jnp.dtype("bfloat16"))}
     assert impl.page_recycling() is None and impl.blocks_needed(17, 8) == 3
-    assert not impl.fused_read_engages("on", 1, jnp.float32, slots=2,
-                                       pages=4, block=8)
+    # the parent's rule with this layer's page (ISSUE 35; the read itself
+    # is tests/test_latent_paged_read.py's): 73,728 B at the real widths
+    engages = impl.fused_read_engages
+    assert engages("on", 1, jnp.float32, slots=2, pages=4, block=8)
+    assert not engages("on", 16, jnp.float32, slots=2, pages=4, block=8)
+    assert not engages("off", 1, jnp.float32, slots=2, pages=4, block=8)
+    assert real._position_values() * 64 * 2 == 73728
     with pytest.raises(ValueError, match="LatentAttentionLayer.*int8"):
         impl.paged_leaves(8, "float32", "int8")
     with pytest.raises(NotImplementedError,
