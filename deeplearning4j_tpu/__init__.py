@@ -30,4 +30,4 @@ __all__ = [
 from .nn.layers import (feedforward as _ff, convolution as _conv,  # noqa: E402,F401
                         normalization as _norm, recurrent as _rec,
                         pretrain as _pre, attention as _attn,
-                        experts as _experts)
+                        experts as _experts, mamba2 as _mamba2)

@@ -93,8 +93,7 @@ from ..analysis.runtime import (CompileCounter, device_index, host_read,
                                 ledger_check_request, ledger_check_zero,
                                 ledger_forget, ledger_note)
 from ..models.sampling import sample_logits
-from ..nn.layers.attention import (LatentAttentionLayerImpl,
-                                   SelfAttentionLayerImpl)
+from ..nn.layers.attention import LatentAttentionLayerImpl
 from ..nn.layers.experts import RoutedExpertsLayerImpl
 from ..nn.layers.recurrent import (BaseRecurrentImpl,
                                    _materialize_rnn_states)
@@ -124,10 +123,10 @@ _LEDGER_KINDS = frozenset(
     ("trie_pin", "pool_block", "mask_row", "engine_slot"))
 
 
-def _carries_kv_cache(impl) -> bool:
-    """This impl carries a position-addressed K/V cache (contiguous stripe
-    or pool pages): `SelfAttentionLayerImpl` and the layers built on it."""
-    return isinstance(impl, SelfAttentionLayerImpl)
+def _keeps_pages(impl) -> bool:
+    """This impl's state is a cache addressed by position (a contiguous
+    stripe, or pool pages): asked of the layer (`keeps_pages`)."""
+    return isinstance(impl, BaseRecurrentImpl) and impl.keeps_pages()
 
 
 def _is_paged(st) -> bool:
@@ -419,7 +418,12 @@ class DecodeScheduler:
     pages back as each window closes and keeps one summary page per
     ``kv_block`` chunks (`_roll_window`); such a net is served through
     the pool only, and the prefix trie stands aside for it
-    (docs/serving.md, "The EVA cache").
+    (docs/serving.md, "The EVA cache"). A net that also has layers whose
+    state is a fixed size a slot and not addressed by position
+    (`Mamba2Layer`) keeps those per-slot leaves beside the page arrays,
+    outside this budget (``slot_state_bytes``), steps them under the same
+    write mask as the pages, and is served through the pool only, one
+    device, without the trie (docs/serving.md, "State-space layers").
 
     ``kv_block``: positions per pool block — only full blocks of a
     prompt are shared, so smaller blocks match more but cost more
@@ -613,26 +617,33 @@ class DecodeScheduler:
                                     if b >= lo]
         else:
             self.prefill_buckets = []
-        # dense chunk path needs every stateful layer to take a multi-token
-        # inference step (true of the attention KV cache: offset
-        # dynamic_update_slice writes + in-chunk causal mask). Recurrent
-        # h/c state steps one token at a time, so those nets prefill
-        # through the lax.scan chunk program instead.
-        stateful = [impl for _, impl in self._impl_items()
-                    if isinstance(impl, BaseRecurrentImpl)]
+        # three things are asked of each stateful layer, and one answer
+        # no longer stands for the others. Does its step take a chunk
+        # (`takes_chunk`: the attention KV cache does, by offset writes and
+        # an in-chunk causal mask; so does a state-space layer, in the
+        # chunked form; h/c state steps one token at a time, and a net with
+        # such a layer prefills through the lax.scan chunk program
+        # instead)? Does it keep pages (`keeps_pages`: a cache addressed by
+        # position, which the pool can hold)? Does it keep per-slot leaves
+        # of a fixed size that it holds still itself under the write mask
+        # (`masks_own_lanes`: they live in `self._states` beside the page
+        # arrays, zeroed at admission, sliced and written back by slot in a
+        # chunk, donated with the rest)?
+        stateful = {key: impl for key, impl in self._impl_items()
+                    if isinstance(impl, BaseRecurrentImpl)}
         self._chunk_dense = bool(stateful) and all(
-            _carries_kv_cache(impl) for impl in stateful)
-        attn_keys = [key for key, impl in self._impl_items()
-                     if _carries_kv_cache(impl)]
+            impl.takes_chunk() for impl in stateful.values())
+        attn_keys = [key for key, impl in stateful.items()
+                     if impl.keeps_pages()]
+        self._ssm = [key for key, impl in stateful.items()
+                     if impl.masks_own_lanes()]
         # what a request holds in the pool is asked of the layer kind
         # (`blocks_needed`), and so is whether its pages are recycled
         # while it lives (`page_recycling`: EVA's (window, chunk), None
         # for a cache of one row per position). One block table serves
         # every layer, so every layer must answer alike
-        self._attn_impl = next((impl for impl in stateful
-                                if _carries_kv_cache(impl)), None)
-        recycling = {impl.page_recycling() for impl in stateful
-                     if _carries_kv_cache(impl)}
+        self._attn_impl = stateful[attn_keys[0]] if attn_keys else None
+        recycling = {stateful[key].page_recycling() for key in attn_keys}
         self._eva: Optional[Tuple[int, int]] = None
         if recycling - {None}:
             if len(recycling) > 1:
@@ -674,6 +685,26 @@ class DecodeScheduler:
                     f"LatentAttentionLayer {latent!r} is served from the "
                     "paged pool, one device, in the compute dtype; not "
                     "served yet for it: " + ", ".join(unserved))
+        # a layer whose state is not addressed by position is stepped under
+        # the write mask that only the paged programs hand down, and a
+        # position cannot be rolled back or looked up in it: what is not
+        # served yet for it is refused here, by name
+        if self._ssm:
+            unserved = [what for what, asked in (
+                ("int8 pages (kv_dtype)", kv_dtype),
+                ("a tp mesh", mesh is not None and mesh != 1),
+                ("speculation (a rejected token's state cannot be rolled "
+                 "back)", speculate),
+                ("a contiguous cache (kv_pool_mb = 0, as rnn_time_step "
+                 "steps it)", not (kv_pool_mb and kv_pool_mb > 0)
+                 or not attn_keys or self.prefill_chunk <= 1)) if asked]
+            if unserved:
+                kind = type(stateful[self._ssm[0]].conf)
+                raise ValueError(
+                    f"{kind.__name__} {self._ssm[0]!r} is served beside a "
+                    "paged pool (kv_pool_mb > 0, attention layers that keep "
+                    "pages, chunked prefill), one device, in the compute "
+                    "dtype; not served yet for it: " + ", ".join(unserved))
         # the routed-experts layers whose per-dispatch routing counts ride
         # back with the probabilities (`_forward`, `_pack_counts`)
         self._moe = [key for key, impl in self._impl_items()
@@ -783,7 +814,9 @@ class DecodeScheduler:
                     # materialize straight into the paged layout: the
                     # contiguous stripes are never allocated. Zeros match
                     # init_state for every entry — paged requires
-                    # _chunk_dense, so all stateful layers are attention.
+                    # _chunk_dense, so every stateful layer either keeps
+                    # pages (below) or keeps per-slot leaves of a fixed
+                    # size, [n_slots, ...] here beside the page arrays.
                     # Under a mesh the page arrays stay HOST numpy here:
                     # the total pool is tp x one device's budget, so a
                     # device-side transient would OOM the very layout
@@ -890,12 +923,26 @@ class DecodeScheduler:
             self._jcow = jax.jit(self._cow_fn,
                                  donate_argnames=("states",))
         self._jsumtab = None
-        if (self._eva is not None or latent is not None) and not self.paged:
+        if (self._eva is not None or latent is not None or self._ssm) \
+                and not self.paged:
             raise ValueError(
                 f"kv_pool_mb={kv_pool_mb} holds no two blocks of "
                 f"{self.kv_block} positions: a net with "
-                f"{'EvaAttentionLayer' if latent is None else 'LatentAttentionLayer'}"
-                " is served through the paged pool only")
+                + ("EvaAttentionLayer" if self._eva is not None else
+                   "LatentAttentionLayer" if latent is not None else
+                   f"a state-space layer ({self._ssm[0]!r})")
+                + " is served through the paged pool only")
+        # what a slot holds whatever its length (the per-slot leaves of the
+        # layers in `_ssm`), beside the pool's budget and not inside it
+        self.slot_state_bytes = sum(
+            int(np.prod(leaf.shape[1:])) * leaf.dtype.itemsize
+            for key in self._ssm
+            for leaf in jax.tree_util.tree_leaves(abstract_states[key]))
+        # the prefix trie indexes pages by the tokens before them; where a
+        # request's pages are recycled while it lives, or part of its state
+        # is not addressed by position (a hit would move `pos` past it and
+        # nothing holds the state there), the trie stands aside
+        self._no_prefix = self._eva is not None or bool(self._ssm)
         if self._eva is not None:
             self._jsumtab = jax.jit(self._sumtab_fn,
                                     donate_argnames=("states",))
@@ -1027,7 +1074,7 @@ class DecodeScheduler:
                 self.draft_blocks = kk if draft_net is None else 0
                 caps = [int(getattr(impl.conf, "max_cache_len", 1024))
                         for _, impl in self._draft_impl_items()
-                        if _carries_kv_cache(impl)]
+                        if _keeps_pages(impl)]
                 self._draft_cap = min(caps) if caps else None
                 # the draft's private KV cache: contiguous per-slot
                 # stripes even under a paged main cache (K layers only,
@@ -1160,10 +1207,23 @@ class DecodeScheduler:
                 "eva_rows_summary_total",
                 help="chunk-summary rows of closed windows attended by "
                      "decode tokens")
+        if self._no_prefix:
             self._m_publish_skipped = m.counter(
                 "prefix_publish_skipped_total",
                 help="finished prompts the prefix trie did not adopt: "
-                     "their pages were recycled while the request lived")
+                     "their pages were recycled while the request lived, "
+                     "or a state that no position addresses goes with them")
+        if self._ssm:
+            # rows of per-slot state a decode dispatch names (every slot's)
+            # and rows it reads and writes, by the layer's one static rule:
+            # the step computes every lane and selects, so the bucket's
+            self._m_ssm_bucket = m.counter(
+                "ssm_rows_bucket_total",
+                help="state rows in decode dispatches: n_slots a dispatch")
+            self._m_ssm_stepped = m.counter(
+                "ssm_rows_stepped_total",
+                help="state rows decode dispatches read and wrote: every "
+                     "slot's while the step computes all lanes and selects")
         if self._moe:
             # from the routing counts each dispatch hands back
             # (`_note_routing`): pairs are (token, chosen expert), a layer
@@ -1261,7 +1321,7 @@ class DecodeScheduler:
     def _min_cache_len(self) -> Optional[int]:
         caps = []
         for _, impl in self._impl_items():
-            if _carries_kv_cache(impl):
+            if _keeps_pages(impl):
                 caps.append(int(getattr(impl.conf, "max_cache_len", 1024)))
         return min(caps) if caps else None
 
@@ -1345,7 +1405,12 @@ class DecodeScheduler:
         out = {}
         for key, st in new_states.items():
             old = old_states[key]
-            if isinstance(st, dict):
+            if key in self._ssm:
+                # held still by the layer itself, under the same mask
+                # (`_inject_paged`): a second select over the state's
+                # gigabytes would be pure cost
+                out[key] = st
+            elif isinstance(st, dict):
                 # pages (and their int8 dequant scales) are exempt like
                 # k/v: a masked slot's paged write was redirected to the
                 # scratch page in-program (wmask), so there is nothing
@@ -1388,6 +1453,10 @@ class DecodeScheduler:
                 out[key] = {**st, "table": table, "wmask": wmask,
                             "paged_kernel": self.paged_kernel,
                             "mesh": self.mesh}
+            elif key in self._ssm:
+                # a padded lane or a pad token may not advance a state
+                # either: the layer holds its leaves still where it is off
+                out[key] = {**st, "wmask": wmask}
             else:
                 out[key] = st
         return out
@@ -2194,7 +2263,7 @@ class DecodeScheduler:
         prompt token is then re-fed to produce the first output
         distribution, and its write copy-on-writes the final shared
         block (`_ensure_writable`)."""
-        if self._eva is not None:
+        if self._no_prefix:
             return  # nothing was published (`_publish_prompt`): no lookup
         B = self.pool.block
         self._m_prefix_lookups.inc()
@@ -2262,10 +2331,11 @@ class DecodeScheduler:
         one) are skipped and freed normally."""
         B = self.pool.block
         n_full = len(seq.prompt) // B
-        if self._eva is not None:
+        if self._no_prefix:
             # the prompt's pages were recycled as its windows closed, and
-            # what is left stands for this request's own window: the trie
-            # adopts nothing, and says so
+            # what is left stands for this request's own window; or the
+            # pages are whole but the state that goes with them is not
+            # addressed by position: the trie adopts nothing, and says so
             if n_full:
                 self._m_publish_skipped.inc()
             return frozenset()
@@ -3543,6 +3613,9 @@ class DecodeScheduler:
                 if self._latent:
                     self._m_mla_rows.inc(
                         sum(s.written + 1 for _, s in fed if s.sampling))
+                if self._ssm:
+                    self._m_ssm_bucket.inc(self.n_slots)
+                    self._m_ssm_stepped.inc(self.n_slots)
                 if mstate is not None:
                     probs, new_states = self._jstep_m(
                         self._params, self._variables,
@@ -4065,10 +4138,10 @@ class DecodeScheduler:
         quant = self.kv_dtype == "int8"
         heads = set()
         for _, impl in self._impl_items():
-            if _carries_kv_cache(impl):
+            if _keeps_pages(impl):
                 H = int(impl.conf.n_heads)
                 heads.add((impl._kv_heads() // self.tp, H // self.tp,
-                           int(impl.conf.n_out) // H))
+                           impl._head_dim()))
         for nb in self.table_buckets:
             hits = [v for k, v in dec.items()
                     if k[0] == self.n_slots and k[1] == nb
@@ -4134,6 +4207,12 @@ class DecodeScheduler:
             "prefill_buckets": list(self.prefill_buckets),
             "chunk_cap": self.chunk_cap,
         }
+        if self._ssm:
+            # beside the pool's budget, not inside it
+            out["slot_state"] = {"layers": len(self._ssm),
+                                 "bytes_per_slot": self.slot_state_bytes,
+                                 "bytes": self.slot_state_bytes
+                                 * self.n_slots}
         if self.maskpool is not None:
             out["grammar_masks"] = self.maskpool.stats()
         if self.paged:

@@ -290,6 +290,41 @@ class GRU(BaseRecurrentLayer):
 
 @register
 @dataclass
+class Mamba2Layer(BaseRecurrentLayer):
+    """Mamba-2 selective state-space mixer (Dao & Gu 2024, "Transformers are
+    SSMs") — see nn/layers/mamba2.py. ``n_heads`` heads of ``head_dim``
+    channels (the inner width is their product, whatever ``n_in``), each
+    with a ``[head_dim, state_size]`` float32 state; the input-dependent
+    ``B`` and ``C`` come in ``n_groups`` groups (head h reads group
+    ``h // (n_heads / n_groups)``), behind a causal depthwise conv of
+    ``conv_kernel`` taps over the ``inner + 2 n_groups state_size`` conv
+    channels. Output is gated (silu) and then RMS-normed over ``n_groups``
+    groups of the inner width. No projection has a bias; the conv has one.
+    ``chunk_size`` is the chunk of the SSD form a sequence is computed in.
+    The four sizes are the model's and have to be given."""
+
+    n_heads: Optional[int] = None
+    head_dim: Optional[int] = None
+    state_size: Optional[int] = None
+    n_groups: Optional[int] = None
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    eps: float = 1e-5
+
+    def __post_init__(self):
+        _require(self, "n_heads", "head_dim", "state_size", "n_groups")
+        if self.n_heads % self.n_groups:
+            raise ValueError(f"n_groups={self.n_groups} must divide "
+                             f"n_heads={self.n_heads}")
+
+    def set_n_in(self, input_type: InputType) -> None:
+        super().set_n_in(input_type)
+        if self.n_out is None:
+            self.n_out = self.n_in
+
+
+@register
+@dataclass
 class EmbeddingLayer(FeedForwardLayer):
     """Index -> dense vector lookup (reference nn/conf/layers/EmbeddingLayer.java).
     Input: [batch] or [batch, 1] integer indices (or one-hot [batch, n_in])."""
@@ -328,9 +363,14 @@ class GlobalPoolingLayer(Layer):
 @dataclass
 class SelfAttentionLayer(FeedForwardLayer):
     """Multi-head self-attention (no reference counterpart; long-context
-    capability — see nn/layers/attention.py). n_out must divide n_heads."""
+    capability — see nn/layers/attention.py). ``n_out`` is the model width
+    the output projection returns to. A head is ``head_dim`` wide:
+    ``n_out / n_heads`` where none is given (``n_heads`` must then divide
+    ``n_out``), the model's own where its heads are not the hidden size's
+    share (q projects to ``n_heads * head_dim``, o from it)."""
 
     n_heads: int = 4
+    head_dim: Optional[int] = None
     causal: bool = False
     # KV-cache capacity for stateful streaming inference (rnn_time_step);
     # decoding past this many positions is unsupported
@@ -403,16 +443,20 @@ class LatentAttentionLayer(SelfAttentionLayer):
 @register
 @dataclass
 class RoutedExpertsLayer(FeedForwardLayer):
-    """One device's share of a routed mixture of gated (SwiGLU) experts —
-    see nn/layers/experts.py. The router scores all ``n_experts`` and picks
+    """One device's share of a routed mixture of experts — see
+    nn/layers/experts.py. The router scores all ``n_experts`` and picks
     ``top_k`` a token; this layer holds experts ``held = (first, count)``
     and returns the part of the result they give (``n_out == n_in``). No
     capacity factor: no token is dropped. ``scoring``: "sigmoid" over the
     router's outputs (the one built); ``norm_topk`` divides the chosen
     scores by their sum (over all chosen, held or not); ``scale`` multiplies
-    the gates. ``held=None`` holds every expert. ``n_experts``, ``top_k``
-    and ``width`` (an expert's hidden width) are the model's and have to be
-    given."""
+    the gates. ``selection_bias`` adds a learned bias a router output to
+    the scores for the CHOICE alone (the gates weigh by the scores).
+    ``gated`` experts are three matrices (``act(x Wg) * x Wu) Wd``, SwiGLU
+    at the default ``expert_activation`` "swish"); ``gated=False`` is the
+    plain two-matrix expert ``act(x Wu) Wd``. ``held=None`` holds every
+    expert. ``n_experts``, ``top_k`` and ``width`` (an expert's hidden
+    width) are the model's and have to be given."""
 
     n_experts: Optional[int] = None
     held: Optional[Tuple[int, int]] = None
@@ -421,6 +465,9 @@ class RoutedExpertsLayer(FeedForwardLayer):
     norm_topk: bool = True
     scale: float = 1.0
     width: Optional[int] = None
+    gated: bool = True
+    expert_activation: str = "swish"
+    selection_bias: bool = False
 
     def __post_init__(self):
         _require(self, "n_experts", "top_k", "width")

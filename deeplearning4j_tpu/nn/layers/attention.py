@@ -62,26 +62,34 @@ class SelfAttentionLayerImpl(BaseRecurrentImpl):
                              f"of n_heads={conf.n_heads}")
         return kv
 
+    def _head_dim(self) -> int:
+        """A head's width: the conf's ``head_dim``, or the heads' share of
+        ``n_out`` where none is given."""
+        conf = self.conf
+        Dh = getattr(conf, "head_dim", None)
+        return int(Dh) if Dh is not None else conf.n_out // conf.n_heads
+
     def init_params(self, key, dtype=jnp.float32):
         conf = self.conf
         dist = conf.dist.spec() if getattr(conf, "dist", None) is not None else None
         kq, kk, kv, ko = jax.random.split(key, 4)
         model = conf.n_out
-        kv_dim = self._kv_heads() * (model // conf.n_heads)
+        Dh = self._head_dim()
+        kv_dim = self._kv_heads() * Dh
         mk = lambda k, i, o: winit.init_weights(k, (i, o), conf.weight_init or "xavier",
                                                 dist, dtype)
         return {
-            "Wq": mk(kq, conf.n_in, model),
+            "Wq": mk(kq, conf.n_in, conf.n_heads * Dh),
             "Wk": mk(kk, conf.n_in, kv_dim),
             "Wv": mk(kv, conf.n_in, kv_dim),
-            "Wo": mk(ko, model, model),
+            "Wo": mk(ko, conf.n_heads * Dh, model),
             "b": jnp.full((model,), float(conf.bias_init or 0.0), dtype),
         }
 
     # -- recurrent-state protocol (KV cache) ----------------------------------
     def init_state(self, batch: int, dtype=jnp.float32):
         conf = self.conf
-        Dh = conf.n_out // conf.n_heads
+        Dh = self._head_dim()
         Hkv = self._kv_heads()  # GQA: the cache shrinks with the KV heads
         L = int(getattr(conf, "max_cache_len", 1024))
         return {"k": jnp.zeros((batch, L, Hkv, Dh), dtype),
@@ -95,7 +103,7 @@ class SelfAttentionLayerImpl(BaseRecurrentImpl):
         conf = self.conf
         B, T, _ = x.shape
         H = conf.n_heads
-        Dh = conf.n_out // H
+        Dh = self._head_dim()
 
         def proj(w, heads):
             return jnp.einsum("btf,fo->bto", x, params[w]).reshape(
@@ -150,7 +158,7 @@ class SelfAttentionLayerImpl(BaseRecurrentImpl):
                                 a1 * sin + a2 * cos], axis=-1)
 
     def _out(self, params, o, B, T):
-        out = jnp.einsum("btm,mn->btn", o.reshape(B, T, self.conf.n_out),
+        out = jnp.einsum("btm,mn->btn", o.reshape(B, T, -1),
                          params["Wo"]) + params["b"]
         return self.activation_fn()(out)
 
@@ -267,7 +275,17 @@ class SelfAttentionLayerImpl(BaseRecurrentImpl):
                              pos + T)
         return y, {"k": kc, "v": vc, "pos": next_pos}
 
-    # -- what the serving engine asks of a layer that carries a paged cache
+    # -- what the serving engine asks of a stateful layer ----------------------
+    def takes_chunk(self) -> bool:
+        """The cached step is multi-token: offset writes and an in-chunk
+        causal mask."""
+        return True
+
+    def keeps_pages(self) -> bool:
+        """One cached row a position (or what a subclass keeps of them)."""
+        return True
+
+    # -- and of one that keeps pages
     def blocks_needed(self, depth: int, block: int) -> int:
         """Pool blocks a request holds once ``depth`` positions are cached:
         one row a position, for as long as the request lives."""
@@ -288,8 +306,7 @@ class SelfAttentionLayerImpl(BaseRecurrentImpl):
         Here a key row and a value row of the compact K/V heads a position;
         with ``cache_dtype="int8"`` int8 values beside one float32
         dequantization scale per (position, head)."""
-        page = (int(block), self._kv_heads(),
-                self.conf.n_out // self.conf.n_heads)
+        page = (int(block), self._kv_heads(), self._head_dim())
         if cache_dtype == "int8":
             return {"k_pages": (page, jnp.int8), "v_pages": (page, jnp.int8),
                     "k_scales": (page[:2], jnp.float32),
@@ -320,7 +337,7 @@ class SelfAttentionLayerImpl(BaseRecurrentImpl):
     def _position_values(self) -> int:
         """Values a position keeps in one page leaf: what the kernel's DMA
         of a page brings in, over ``block``."""
-        return self._kv_heads() * (self.conf.n_out // self.conf.n_heads)
+        return self._kv_heads() * self._head_dim()
 
     @staticmethod
     def _page_of(table, p, Bk, wmask=None):
@@ -515,7 +532,7 @@ class EvaAttentionLayerImpl(SelfAttentionLayerImpl):
     def init_params(self, key, dtype=jnp.float32):
         p = super().init_params(key, dtype)
         del p["b"]
-        Dh = self.conf.n_out // self.conf.n_heads
+        Dh = self._head_dim()
         for i, name in enumerate(("mu", "phi")):
             p[name] = (jax.random.normal(jax.random.fold_in(key, i),
                                          (self._kv_heads(), Dh), jnp.float32)

@@ -6,9 +6,12 @@ which experts it holds (``held = (first, count)``), and returns the part of
 the result its own experts give,
 
     sigma = sigmoid(x Wr)                     all n_experts outputs, float32
-    T     = the top_k largest of sigma
+    T     = the top_k largest of sigma (+ b_sel where ``selection_bias``:
+            the bias chooses and does not weigh)
     g_e   = scale * sigma_e / sum_T sigma     (norm_topk; over ALL chosen)
-    y     = sum_{e in T, e held} g_e E_e(x),  E_e(x) = (silu(x Wg_e) * x Wu_e) Wd_e
+    y     = sum_{e in T, e held} g_e E_e(x),  E_e(x) = (act(x Wg_e) * x Wu_e) Wd_e
+            (``gated``, act = silu by default), or E_e(x) = act(x Wu_e) Wd_e
+            (``gated=False``: the plain two-matrix expert, e.g. act = relu2)
 
 What the absent experts would add is left out; summing the shares of every
 device gives the whole layer (tests/test_routed_experts.py). There is no
@@ -36,6 +39,7 @@ import jax.numpy as jnp
 
 from .base import LayerImpl, register_impl
 from .. import weights as winit
+from ...ops import activations
 
 _TILE = 128     # rows of one expert a grouped step multiplies at once
 
@@ -69,35 +73,51 @@ class RoutedExpertsLayerImpl(LayerImpl):
             return jnp.stack([winit.init_weights(kk, (i, o), init, dist, dtype)
                               for kk in jax.random.split(k, G)])
 
-        return {"Wr": winit.init_weights(kr, (d, conf.n_experts), init, dist,
-                                         dtype),
-                "Wg": stack(kg, d, f), "Wu": stack(ku, d, f),
-                "Wd": stack(kd, f, d)}
+        params = {"Wr": winit.init_weights(kr, (d, conf.n_experts), init,
+                                           dist, dtype),
+                  "Wu": stack(ku, d, f), "Wd": stack(kd, f, d)}
+        if conf.gated:
+            params["Wg"] = stack(kg, d, f)
+        if conf.selection_bias:
+            params["b_sel"] = jnp.zeros((conf.n_experts,), dtype)
+        return params
 
     # -- routing: one function, shared by both paths ----------------------------
     def route(self, params, x):
         """x [N, d] -> (experts [N, top_k] int32 over all n_experts, gates
-        [N, top_k] float32): ``topk_method`` none, i.e. no groups and no
-        selection bias, the ``top_k`` largest of all the scores."""
+        [N, top_k] float32): no groups, the ``top_k`` largest of all the
+        scores, or of the scores plus the selection bias where the layer
+        has one (the gates are of the scores alone)."""
         conf = self.conf
         logits = jnp.einsum("nd,de->ne", x, params["Wr"],
                             preferred_element_type=jnp.float32)
         if conf.scoring != "sigmoid":
             raise ValueError(f"scoring {conf.scoring!r} is not built: "
                              "'sigmoid' is")
-        top, idx = jax.lax.top_k(jax.nn.sigmoid(logits), int(conf.top_k))
+        sigma = jax.nn.sigmoid(logits)
+        if conf.selection_bias:
+            _, idx = jax.lax.top_k(
+                sigma + params["b_sel"].astype(jnp.float32),
+                int(conf.top_k))
+            top = jnp.take_along_axis(sigma, idx, axis=-1)
+        else:
+            top, idx = jax.lax.top_k(sigma, int(conf.top_k))
         if conf.norm_topk:
             top = top / jnp.sum(top, axis=-1, keepdims=True)
         return idx.astype(jnp.int32), top * float(conf.scale)
 
-    @staticmethod
-    def _expert(params, e, xs):
+    def _expert(self, params, e, xs):
         """Expert ``e`` (held index, may be traced) on rows xs [m, d]."""
         f32 = jnp.float32
-        g = jnp.dot(xs, params["Wg"][e], preferred_element_type=f32)
-        u = jnp.dot(xs, params["Wu"][e], preferred_element_type=f32)
-        h = (jax.nn.silu(g) * u).astype(xs.dtype)
-        return jnp.dot(h, params["Wd"][e], preferred_element_type=f32)
+        act = activations.get(self.conf.expert_activation)
+        if self.conf.gated:
+            g = jnp.dot(xs, params["Wg"][e], preferred_element_type=f32)
+            u = jnp.dot(xs, params["Wu"][e], preferred_element_type=f32)
+            h = act(g) * u
+        else:
+            h = act(jnp.dot(xs, params["Wu"][e], preferred_element_type=f32))
+        return jnp.dot(h.astype(xs.dtype), params["Wd"][e],
+                       preferred_element_type=f32)
 
     def _dense(self, params, x, local, gates):
         """Every held expert over every token, weighted by its gate where

@@ -51,6 +51,27 @@ class BaseRecurrentImpl(LayerImpl):
                            train=False, rng=None, mask=None) -> Tuple[Array, State]:
         raise NotImplementedError
 
+    # -- what the serving engine asks of a stateful layer ---------------------
+    # (`inference/engine.py`: three questions, answered by the layer kind)
+    def takes_chunk(self) -> bool:
+        """Whether the inference step takes T > 1 tokens at per-slot state
+        in one forward (a prefill chunk). An h/c layer steps one token at a
+        time: the engine scans it."""
+        return False
+
+    def keeps_pages(self) -> bool:
+        """Whether the state is a cache addressed by position, which the
+        engine may keep in pool pages behind a block table (the layer then
+        also answers `paged_leaves`, `blocks_needed`, `page_recycling`)."""
+        return False
+
+    def masks_own_lanes(self) -> bool:
+        """Whether the layer keeps per-slot leaves of a fixed size that it
+        itself leaves exactly as they were where the engine's write mask
+        (``wmask`` [B, T] bool, injected into its state) is off: the engine
+        then hands it the mask and does not freeze those leaves again."""
+        return False
+
     def _mask_carry(self, new_state: State, old_state: State, m_t: Array) -> State:
         """Masked timesteps keep the previous state (variable-length support)."""
         return {k: m_t * new_state[k] + (1.0 - m_t) * old_state[k] for k in new_state}

@@ -86,6 +86,12 @@ def swish(x: Array) -> Array:
     return jax.nn.silu(x)
 
 
+def relu2(x: Array) -> Array:
+    """Squared ReLU, ``relu(x)**2``."""
+    r = jax.nn.relu(x)
+    return r * r
+
+
 ACTIVATIONS: dict[str, Callable[[Array], Array]] = {
     "identity": identity,
     "linear": identity,
@@ -105,6 +111,7 @@ ACTIVATIONS: dict[str, Callable[[Array], Array]] = {
     "softmax": softmax,
     "gelu": gelu,
     "swish": swish,
+    "relu2": relu2,
 }
 
 

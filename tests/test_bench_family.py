@@ -1,5 +1,6 @@
 """The benchmark's family loader, in tier-1 (ISSUE 28 asked for it, ISSUE 30
-brings it with the second real family, ISSUE 34 the third): every configuration of
+brings it with the second real family, ISSUE 34 the third, ISSUE 36 the
+fourth): every configuration of
 `BENCHMARK.json` resolves to its family's four files, an unknown `model_type`
 names the directory to add, a family lacking a file or a function fails at
 load and not in mid-run, and `evabyte-d16.json` is the published
@@ -51,11 +52,12 @@ def test_the_scheduler_takes_every_workloads_engine_block_whole(path):
     assert engine["kv_pool_mb"] > 0
 
 
-def test_the_benchmark_has_two_families():
-    """... and since ISSUE 34 a third, `axk1`."""
+def test_every_family_directory_is_some_configurations_model_type():
+    """Four families since ISSUE 36, and no directory that no configuration
+    names (nor a configuration without its directory)."""
     types = {json.loads((REPO / c["file"]).read_text())["model_type"]
              for c in BENCH["configs"]}
-    assert types == {"starcoder2", "evabyte", "axk1"}
+    assert types == {"starcoder2", "evabyte", "axk1", "nemotron_h"}
     assert {p.name for p in (REPO / "benchmark" / "families").iterdir()
             if p.is_dir() and p.name != "__pycache__"} == types
 
@@ -280,3 +282,157 @@ def test_a_toy_axk1_cell_runs_to_a_correct_line(tmp_path):
     assert "kv_pages_read_share" not in m
     assert "kv_pool_peak_pct" not in m and "eva_roll_ms" not in m
     assert "sched_iter_ms" in m and "longctx.ttft_p95_ms" in m
+
+
+# -- the fourth family: Nemotron-H's share of an 8-chip deployment (ISSUE 36)
+
+def _nemotron():
+    entry = next(c for c in BENCH["configs"]
+                 if c["name"] == "nemotron3-nano-ep8")
+    path = REPO / entry["file"]
+    return entry, path, json.loads(path.read_text())
+
+
+def test_nemotron3_nano_ep8_is_the_published_configuration_but_for_reduced():
+    entry, path, cfg = _nemotron()
+    source = json.loads(path.with_suffix(".published.json").read_text())
+    assert source.pop("source") == entry["source"] == cfg["source"]
+    assert cfg["reduced"] == entry["reduced"] == \
+        ["n_routed_experts", "vocab_size"]
+    assert cfg["published"] == {"n_routed_experts": 128,
+                                "vocab_size": 131072}
+    for key, value in source.items():
+        if key in cfg["reduced"]:
+            assert cfg["published"][key] == value and cfg[key] < value, key
+        else:
+            assert cfg[key] == value, key
+    # no cut in depth: all 52 blocks of the pattern, 23 M / 23 E / 6 *
+    pattern = cfg["hybrid_override_pattern"]
+    assert cfg["num_hidden_layers"] == len(pattern) == 52
+    assert [pattern.count(c) for c in "ME*"] == [23, 23, 6]
+    assert [i for i, c in enumerate(pattern) if c == "*"] == \
+        [5, 12, 19, 26, 33, 42]
+    # the floors of a cut: >= 8 experts, >= an eighth of the vocabulary;
+    # every width as published
+    assert (cfg["n_routed_experts"], cfg["router_outputs"],
+            cfg["num_experts_per_tok"]) == (16, 128, 6)
+    assert cfg["vocab_size"] * 8 >= cfg["published"]["vocab_size"]
+    assert (cfg["hidden_size"], cfg["mamba_num_heads"], cfg["mamba_head_dim"],
+            cfg["ssm_state_size"], cfg["n_groups"], cfg["conv_kernel"],
+            cfg["chunk_size"], cfg["head_dim"], cfg["num_attention_heads"],
+            cfg["num_key_value_heads"], cfg["moe_intermediate_size"],
+            cfg["moe_shared_expert_intermediate_size"]) == \
+        (2688, 64, 64, 128, 8, 4, 128, 128, 32, 2, 1856, 3712)
+    assert cfg["deployment"].startswith("one chip's share of an 8-chip")
+    assert "5,258,420,544" in cfg["deployment"] \
+        and "49,082,368" in cfg["deployment"]
+    for needle in ("no positional embedding", "not expand x hidden_size",
+                   "selection bias", "head width", "A_log = ln U(1, 16)",
+                   "keys not read"):
+        assert any(needle in a for a in cfg["assumed"]), needle
+    assert any("one-hot" in d for d in cfg["departures"])
+
+
+def test_nemotron_s_work_charges_state_by_live_slots_and_experts_by_routing():
+    """The issue's arithmetic; then a decode step's bytes: the state of the
+    live slots alone, read and written once each, and the held experts by
+    what the dispatches hit."""
+    _, _, cfg = _nemotron()
+    work = family.load(REPO, cfg).work
+    assert work.mamba_matmul_params(cfg) + work.mamba_vector_params(cfg) \
+        == 38_742_208
+    assert work.attn_params(cfg) == 23_396_352
+    assert work.expert_params(cfg) == 9_977_856
+    assert work.published_params(cfg) == 5_258_420_544
+    # the graph's own biases on top: embedding, six attention outputs, the
+    # shared experts' two, the head
+    assert work.param_count(cfg) == 5_258_420_544 + 2688 + 6 * 2688 \
+        + 23 * (3712 + 2688) + 16384
+    assert work.state_bytes(cfg) == 49_082_368
+    assert work.kv_bytes_per_position(cfg) == 6144
+    assert work.routing(cfg, None) == (16 / 128, 1.0)
+    _, b0 = work.decode_step(cfg, [])
+    _, b1 = work.decode_step(cfg, [300])
+    _, b9 = work.decode_step(cfg, [300] * 9)
+    per_slot = 2 * 49_082_368 + 300 * 6144 + 6144 + 2688 * 2
+    assert b1 - b0 == per_slot and b9 - b0 == 9 * per_slot
+    experts = 23 * 16 * 9_977_856 * 2
+    hit_all = _counters(moe_pairs_routed_total=1380, moe_pairs_held_total=172,
+                        moe_expert_slots_total=368, moe_experts_hit_total=368)
+    hit_none = _counters(moe_pairs_routed_total=1380, moe_pairs_held_total=0,
+                         moe_expert_slots_total=368, moe_experts_hit_total=0)
+    f_all, b_all = work.decode_step(cfg, [300], run=hit_all)
+    f_none, b_none = work.decode_step(cfg, [300], run=hit_none)
+    assert b_all - b_none == experts and b_all == b1
+    # 6 experts a token x 23 blocks x the held share of the pairs
+    assert f_all - f_none == pytest.approx(
+        6 * 23 * 172 / 1380 * 2 * 9_977_856)
+    outside = work.matmul_params_outside_experts(cfg)
+    assert f_none == 2 * (outside + 2688 * 16384) \
+        + work.scan_flops_per_token(cfg) + 300 * 6 * 4 * 32 * 128
+    assert work.scan_flops_per_token(cfg) == 23 * (5 * 64 * 64 * 128
+                                                   + 2 * 4 * 6144)
+    # a chunk: one slot's state once, the scan by its real tokens
+    fc, bc = work.prefill_chunk(cfg, 200, 256, False, run=hit_none)
+    assert fc == 200 * 2 * outside + 200 * work.scan_flops_per_token(cfg) \
+        + (200 * 256 + 200 * 201 // 2) * 6 * 4 * 32 * 128
+    # 1,200 pairs a block reach all but 1 in 12,000 of the held experts
+    _, bc_all = work.prefill_chunk(cfg, 200, 256, False)
+    assert bc_all - bc == pytest.approx(experts, rel=1e-3)
+    _, bc0 = work.prefill_chunk(cfg, 200, 0, False, run=hit_none)
+    assert bc - bc0 == 256 * 6144
+
+
+def test_a_toy_nemotron_cell_runs_to_a_correct_line(tmp_path):
+    """The command itself, on the CPU at the tests' small size, in a scratch
+    root: the fourth family through `run.py` to a `correct` line, with the
+    readers this PR adds finding what they read."""
+    import os
+    import subprocess
+    from benchmark.tests.util import make_root
+    sys.path.insert(0, str(Path(__file__).resolve().parent))
+    from nemotron_util import cfg
+    root = make_root(tmp_path, config="tiny-nemotron", cell="toy.nemo",
+                     config_keys=cfg(4, 8),
+                     like="nemotron3-nano-ep8.chat-burst")
+    p = subprocess.run(
+        [sys.executable, str(root / "benchmark" / "run.py"), "--bench-root",
+         str(root), "--workload", "toy.nemo", "--seconds", "3", "--seed",
+         "3000000013", "--rehearse-cpu", "--trace", "1"],
+        capture_output=True, text=True, cwd=str(root), timeout=900,
+        env={**os.environ, "PYTHONPATH": str(REPO)})
+    assert p.returncode == 0, p.stderr[-3000:]
+    line = json.loads(p.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["failed"] == 0
+    for name, (value, limit) in line["checks"].items():
+        assert value <= limit, name
+    m = {k: v["value"] for k, v in line["metrics"].items()}
+    # every slot's row is stepped whatever is live, today
+    assert m["ssm_rows_stepped_share"] == 100.0
+    assert 0 < m["ssm_state_rows_peak_pct"] <= 100
+    assert 0 < m["nemo.moe_held_pair_share"] < 100
+    assert 0 < m["nemo.moe_experts_hit_share"] <= 100
+    # the CPU under "auto" keeps the gather body: every page named is read
+    assert m["nemo.kv_pages_read_share"] == 100.0
+    assert "kv_pages_read_share" not in m and "moe_held_pair_share" not in m
+    assert "kv_pool_peak_pct" not in m and "mla_pool_peak_pct" not in m
+    # the pool's blocks beside the state rows: which of the two fills
+    assert 0 < m["nemo.kv_pool_peak_pct"] <= 100
+    assert "sched_iter_ms" in m and "nemo.ttft_p95_ms" in m \
+        and "nemo.queue_p95_ms" in m and "nemo.gen_late_p95_ms" in m
+
+
+def test_a_program_without_the_layer_fails_the_family_at_load(tmp_path):
+    """What the parent commit does with the new cell: the family's graph
+    names what the program lacks when it is loaded, a KeyError that `run.py`
+    turns into exit 2 before any device is asked for."""
+    src = (REPO / "benchmark/families/nemotron_h/graph.py").read_text()
+    here = tmp_path / "benchmark" / "families" / "nemotron_h"
+    here.mkdir(parents=True)
+    for part in family.PARTS:
+        text = (REPO / f"benchmark/families/nemotron_h/{part}.py").read_text()
+        (here / f"{part}.py").write_text(text)
+    (here / "graph.py").write_text(src.replace(
+        "import Mamba2Layer  # noqa", "import Mamba3Layer  # noqa"))
+    with pytest.raises(KeyError, match="nemotron_h.*Mamba2Layer"):
+        family.load(tmp_path, {"model_type": "nemotron_h"})

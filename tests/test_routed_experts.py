@@ -176,3 +176,104 @@ def test_a_scoring_that_is_not_built_is_refused(small):
         n_in=D, n_out=D, n_experts=E, top_k=K, width=32, scoring="softmax"))
     with pytest.raises(ValueError, match="scoring 'softmax' is not built"):
         other.route(lp, x.reshape(-1, D))
+
+
+# -- the plain two-matrix relu2 expert and the selection bias (ISSUE 36) ----
+# at the `nemotron_h` tests' small size: hidden 48, 16 experts of width 24 of
+# which 3 a token, one shared expert of 40, against that family's reference
+
+@pytest.fixture(scope="module")
+def nemo():
+    import nemotron_util as nu
+    fam, params, net = nu.load()
+    name = next(n for n in net._impls if n.startswith("moe"))
+    i = int(name[3:])
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, 29, nu.CFG["hidden_size"]))
+    return nu, fam, params["blocks"][i], net._impls[name], net.params[name], x
+
+
+def _nemo_ref(nu, fam, p, x, first=0, count=16, chosen=None):
+    """(routed part without the shared expert, the shared expert, chosen)."""
+    m = {**dict(fam.reference.dims(nu.cfg(first, count)))}
+    p = {**p, **{k: p[k][first:first + count] for k in ("we_up", "we_down")}}
+    with jax.default_matmul_precision("highest"):
+        y, pick = fam.reference._routed(x, p, m, None, chosen)
+        zero = {**p, "we_up": p["we_up"] * 0, "we_down": p["we_down"] * 0}
+        shared, _ = fam.reference._routed(x, zero, m, None, chosen)
+    return y - shared, shared, pick
+
+
+def test_relu2_is_the_square_of_relu():
+    from deeplearning4j_tpu.ops import activations
+    x = jnp.asarray([-2.0, -0.0, 0.5, 3.0])
+    np.testing.assert_array_equal(activations.get("relu2")(x),
+                                  jnp.asarray([0.0, 0.0, 0.25, 9.0]))
+
+
+def test_the_plain_relu2_expert_is_the_reference(nemo):
+    nu, fam, p, impl, lp, x = nemo
+    assert set(lp) == {"Wr", "b_sel", "Wu", "Wd"} and not impl.conf.gated
+    with jax.default_matmul_precision("highest"):
+        got, var = impl.forward(lp, x)
+    want, shared, _ = _nemo_ref(nu, fam, p, x)
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < 5e-6
+    assert float(jnp.abs(want).max()) > 0.05
+    assert int(np.asarray(var["routing_counts"]).sum()) == 2 * 29 * 3
+    # a gated layer over the same stacks is another function
+    gated = impl_for(RoutedExpertsLayer(
+        n_in=48, n_out=48, n_experts=16, top_k=3, scale=2.5, width=24,
+        selection_bias=True, activation="identity"))
+    other, _ = gated.forward({**lp, "Wg": lp["Wu"]}, x)
+    assert np.abs(np.asarray(other) - np.asarray(want)).max() > 1e-3
+
+
+def test_the_selection_bias_chooses_and_does_not_weigh(nemo):
+    """A bias that lifts expert 5 over every score puts it into every
+    token's set; the gates of the chosen stay their own scores over their
+    sum: the layer equals the reference made to take that choice, and the
+    weights of a token's unchanged choices move only through the sum."""
+    nu, fam, p, impl, lp, x = nemo
+    xf = x.reshape(-1, x.shape[-1])
+    idx0, g0 = impl.route(lp, xf)
+    lifted = {**lp, "b_sel": lp["b_sel"].at[5].set(10.0)}
+    idx1, g1 = impl.route(lifted, xf)
+    assert bool(jnp.all(jnp.any(idx1 == 5, axis=-1)))
+    assert not bool(jnp.all(jnp.any(idx0 == 5, axis=-1)))
+    sigma = jax.nn.sigmoid(xf @ lp["Wr"])
+    top = jnp.take_along_axis(sigma, idx1, -1)
+    np.testing.assert_allclose(g1, 2.5 * top / top.sum(-1, keepdims=True),
+                               rtol=1e-5)
+    # no bias at all: the plain top-k of the scores
+    none = {**lp, "b_sel": jnp.zeros_like(lp["b_sel"])}
+    idx2, _ = impl.route(none, xf)
+    np.testing.assert_array_equal(
+        np.sort(idx2, -1), np.sort(jax.lax.top_k(sigma, 3)[1], -1))
+    with jax.default_matmul_precision("highest"):
+        got, _ = impl.forward(lifted, x)
+    want, _, pick = _nemo_ref(nu, fam, {**p, "b_sel": lifted["b_sel"]}, x)
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < 5e-6
+    np.testing.assert_array_equal(np.sort(np.asarray(pick).reshape(-1, 3), -1),
+                                  np.sort(np.asarray(idx1), -1))
+
+
+def test_the_eight_shares_and_the_shared_expert_once_are_the_uncut_layer(nemo):
+    """ISSUE 36's deployment in small: 16 experts 8 ways, 2 a share; what
+    every chip computes alike (the shared expert) counted once."""
+    nu, fam, p, impl, lp, x = nemo
+    whole, shared, _ = _nemo_ref(nu, fam, p, x)
+    parts = []
+    for first in range(0, 16, 2):
+        share = impl_for(RoutedExpertsLayer(
+            n_in=48, n_out=48, n_experts=16, held=(first, 2), top_k=3,
+            scale=2.5, width=24, gated=False, expert_activation="relu2",
+            selection_bias=True, activation="identity"))
+        cut = {"Wr": lp["Wr"], "b_sel": lp["b_sel"],
+               "Wu": lp["Wu"][first:first + 2], "Wd": lp["Wd"][first:first + 2]}
+        with jax.default_matmul_precision("highest"):
+            y, var = share.forward(cut, x)
+        ref, _, _ = _nemo_ref(nu, fam, p, x, first, 2)
+        assert np.abs(np.asarray(y) - np.asarray(ref)).max() < 5e-6
+        parts.append((np.asarray(y), int(var["routing_counts"].sum())))
+    total = sum(y for y, _ in parts) + np.asarray(shared)
+    assert np.abs(total - np.asarray(whole + shared)).max() < 1e-5
+    assert sum(n for _, n in parts) == 2 * 29 * 3
