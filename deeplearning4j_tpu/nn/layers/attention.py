@@ -987,11 +987,46 @@ class LatentAttentionLayerImpl(SelfAttentionLayerImpl):
             return y, state0
         return self._paged_step(params, x, state0, mask=mask)
 
+    @classmethod
+    def _write_rows(cls, cp, table, pos, rows, wmask, Bk):
+        """``cp`` with the rows [B, T, R] of positions ``pos`` on laid into
+        the pages ``table`` names, one whole page row at a time: each page
+        row the step touches (``J`` of them: T // 2 + 1 at k = 2, T at
+        k = 1, 1 at T = 1) is gathered, the halves that a live position of
+        this step owns are laid over it, and it is written back by one
+        scatter whose window is the row's whole last dimension, the class
+        of the K/V layers' ``kp.at[blk, off].set``. A window of R lanes at
+        ``(off % k) R`` compiled to a ``while`` of one trip a position on a
+        v5e (PERF.md section 6, PR 37). A row that no live position in the
+        table owns (``wmask`` off, beyond the table, or the extra row) goes
+        to the scratch page with the scratch row's own values, so that no
+        two entries address one row of a slot's page and the scratch page
+        keeps finite rows."""
+        B, T, R = rows.shape
+        nb, k = table.shape[1], cp.shape[2] // R
+        J = (T + 2 * k - 2) // k
+        r = pos[:, None] // k + jnp.arange(J, dtype=pos.dtype)      # [B, J]
+        t = (r[..., None] * k + jnp.arange(k, dtype=pos.dtype)
+             - pos[:, None, None]).reshape(B, J * k)  # step index of a half
+        ts = jnp.clip(t, 0, T - 1)
+        own = (t >= 0) & (t < T) & (r * k // Bk < nb).repeat(k, 1)
+        if wmask is not None:
+            own &= jnp.take_along_axis(jnp.broadcast_to(wmask, (B, T)), ts,
+                                       1)
+        blk, off = cls._page_of(table, r * k, Bk)
+        page = jnp.where(own.reshape(B, J, k).any(-1), blk, 0)
+        row = off // k
+        new = jnp.take_along_axis(rows, ts[..., None], 1)
+        old = cp[page, row].reshape(B, J * k, R)
+        return cp.at[page, row].set(
+            jnp.where(own[..., None], new, old).reshape(B, J, k * R))
+
     def _paged_step(self, params, x, state0, *, mask=None):
         """``table`` [B, nb] and ``wmask`` [B, T] as in the parent: a row
-        ``wmask`` holds off is zeroed and lands in the scratch page. The
-        step attends absorbed, whatever T, and at T = 1 through the fused
-        paged read where the rule engages it (the class docstring)."""
+        ``wmask`` holds off is never written to a slot's page. The rows go
+        into the pool a whole page row at a time (`_write_rows`). The step
+        attends absorbed, whatever T, and at T = 1 through the fused paged
+        read where the rule engages it (the class docstring)."""
         B, T, _ = x.shape
         _, _, dr, _, C = self._dims()
         R = C + dr
@@ -1004,16 +1039,7 @@ class LatentAttentionLayerImpl(SelfAttentionLayerImpl):
         overflow = (pos + T) > L
         q_n, q_r, rows_new = self._project(params, x, pos0=pos)
         p = pos[:, None] + jnp.arange(T, dtype=pos.dtype)[None, :]   # [B, T]
-        blk, off = self._page_of(table, p, Bk, wmask)
-        if wmask is not None:       # pages hold finite rows only
-            rows_new = jnp.where(wmask[..., None], rows_new, 0)
-        # one window of R values a row, at (page, off // k, (off % k) R)
-        cp2 = jax.lax.scatter(
-            cp, jnp.stack([blk, off // k, off % k * R], -1).reshape(-1, 3),
-            rows_new.reshape(-1, R),
-            jax.lax.ScatterDimensionNumbers(
-                update_window_dims=(1,), inserted_window_dims=(0, 1),
-                scatter_dims_to_operand_dims=(0, 1, 2)))
+        cp2 = self._write_rows(cp, table, pos, rows_new, wmask, Bk)
         def read(table, q_n, q_r, p):
             rows = cp2[table].reshape(table.shape[0], L, R)
             valid = jnp.arange(L, dtype=pos.dtype)[None, None, :] \

@@ -6,7 +6,10 @@ over ``cp2[table]``), on one set of pages: the layer's own step with
 is 48 + 16 = 64 wide, so that two positions share a 128-wide page row as two
 of A.X-K1's 576 share 1,152; pages of 8 positions (4 rows), a table bucket of
 8 blocks. Below that the engine: the same tokens either way, and what it
-counts. Last, the kernel at A.X-K1's widths through the chip's compiler."""
+counts. Last, the kernel, and the layer's write into the pool (ISSUE 37), at
+A.X-K1's widths through the chip's compiler."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -250,8 +253,11 @@ def test_what_keeps_the_gather_body(layer, monkeypatch):
         fn, params = _step_fn(layer, x, **injected)
         eqns = list(_eqns(jax.make_jaxpr(fn)(params, x, state).jaxpr))
         kernels = [e for e in eqns if e.primitive.name == "pallas_call"]
+        # a gather of whole pages; the write's of the rows it touches is
+        # one of rows
         gathers = [e for e in eqns if e.primitive.name == "gather"
-                   and e.invars[0].aval.shape == shape]
+                   and e.invars[0].aval.shape == shape
+                   and e.params["slice_sizes"] == (1,) + shape[1:]]
         assert all(e.params["name"] == "paged_read_rows" for e in kernels)
         assert (len(kernels), len(gathers)) in ((1, 0), (0, 1))
         return bool(kernels)
@@ -385,3 +391,36 @@ def test_the_kernel_compiles_for_a_v5e_at_the_cell_s_shapes(one_chip):
         sds((48, 256), jnp.int32), sds((48, 256), jnp.int32)).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 1
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("slots,T", [(1, 512), (48, 1)],
+                         ids=["chunk", "decode"])
+def test_the_pool_write_compiles_to_no_loop_for_a_v5e(one_chip, monkeypatch,
+                                                      slots, T):
+    """The layer's paged step at A.X-K1's widths over the cell's pool: the
+    rows go in by one native scatter, and no ``while`` carries the pool (the
+    window scatter it replaced was one of a trip a position, ISSUE 37). The
+    step as the chip traces it: the fused read at T = 1."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    impl = impl_for(LatentAttentionLayer(
+        n_in=7168, n_out=7168, n_heads=64, q_lora_rank=1536,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, activation="identity"))
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda: impl.init_params(jax.random.PRNGKey(0), jnp.bfloat16)))
+    state = {"c_pages": sds((9986, 32, 1152), jnp.bfloat16),
+             "pos": sds((slots,), jnp.int32),
+             "table": sds((slots, 32), jnp.int32),
+             "wmask": sds((slots, T), jnp.bool_)}
+    text = jax.jit(lambda p, x, st: impl._paged_step(p, x, st)[1],
+                   donate_argnums=(2,)).lower(
+        params, sds((slots, T, 7168), jnp.bfloat16), state
+    ).compile().as_text()
+    loops = [ln for ln in text.splitlines() if " while(" in ln]
+    assert not [ln for ln in loops if "bf16[9986,32,1152]" in ln]
+    assert len(re.findall(r"bf16\[9986,32,1152\]\S* scatter\(", text)) == 1
