@@ -559,16 +559,18 @@ class DecodeScheduler:
         # only (the flight recorder's discipline); profile=False (or an
         # injected disabled profiler) reduces every stamp to one
         # attribute test — the bench-gated disarmed configuration
+        sfx = self.tracer.track_scope("engine")
+        self._sched_track = "scheduler" + sfx
         self.profiler = profiler if profiler is not None else \
             StepPhaseProfiler(self.metrics, enabled=bool(profile))
+        # one `sched_iter` record per booked iteration on our track
+        self.profiler.attach(self.tracer, self._sched_track)
         # serializes attribute_costs' seconds-long first computation:
         # two concurrent /debug/engine reads must not both trace the
         # whole program family (never touched by the scheduler thread)
         self._attr_lock = threading.Lock()
         self._attr_failed = False  # one-shot: a backend without a cost
         # model fails ONCE, not seconds of re-tracing per /debug poll
-        sfx = self.tracer.track_scope("engine")
-        self._sched_track = "scheduler" + sfx
         self._slot_tracks = [f"slot {i}{sfx}" for i in range(self.n_slots)]
         self._graph = hasattr(net.conf, "vertices")  # facade detection
         self._dtype = _compute_dtype_of(net.conf.conf)
@@ -3026,28 +3028,24 @@ class DecodeScheduler:
             ids = np.zeros((bucket,), np.int32)
             ids[:n_real] = seq.prompt[seq.fed:seq.fed + n_real]
             failpoints.fire("dispatch.prefill")
-            self.profiler.count("prefill", bucket)
             if self.tracer.enabled:  # keep tracing-off allocation-free
                 self.tracer.begin("prefill_chunk",
                                   track=self._slot_tracks[i],
                                   args={"request": seq.handle.request_id,
                                         "bucket": bucket, "tokens": n_real})
+            up = [self._dev_index(i), self._dev_array(ids),
+                  self._dev_index(n_real)]
             if self.paged:
                 # table bucket covers the PADDED chunk end so the
                 # layer's overflow guard never trips on pad lanes
-                probs, self._states = self._jprefill(
-                    self._params, self._variables,
-                    self._dev_index(i), self._dev_array(ids),
-                    self._dev_index(n_real),
-                    self._dev_array(self._table_for(seq.written + bucket)),
-                    self._states)
-                seq.written += n_real
-            else:
-                probs, self._states = self._jprefill(
-                    self._params, self._variables,
-                    self._dev_index(i), self._dev_array(ids),
-                    self._dev_index(n_real), self._states)
-                seq.written += n_real  # host pos mirror (spec fixpos)
+                up.append(self._dev_array(
+                    self._table_for(seq.written + bucket)))
+            # stamped after the uploads: the jit call alone lies between
+            # this instant and the program's start on the device
+            self.profiler.count("prefill", bucket)
+            probs, self._states = self._jprefill(
+                self._params, self._variables, *up, self._states)
+            seq.written += n_real  # host pos mirror (spec fixpos)
             if self.speculate and seq.draft_fed == seq.fed \
                     and self._draft_cap is not None \
                     and seq.draft_fed + bucket <= self._draft_cap:
@@ -3593,10 +3591,10 @@ class DecodeScheduler:
             if self.tracer.enabled:  # keep tracing-off allocation-free
                 self.tracer.begin("decode_step", track=self._sched_track,
                                   args={"live_slots": len(fed)})
+            up = [self._dev_array(ids), self._dev_array(live)]
             if self.paged:
                 table = self._table_for(max(s.written + 1
                                             for _, s in fed))
-                prof.count("decode", table.shape[1])
                 nb = table.shape[1]
                 named = self.n_slots * self._pages_listed(nb)
                 self._m_pages_bucket.inc(named)
@@ -3616,30 +3614,17 @@ class DecodeScheduler:
                 if self._ssm:
                     self._m_ssm_bucket.inc(self.n_slots)
                     self._m_ssm_stepped.inc(self.n_slots)
-                if mstate is not None:
-                    probs, new_states = self._jstep_m(
-                        self._params, self._variables,
-                        self._dev_array(ids), self._dev_array(live),
-                        self._dev_array(table), self._dev_array(mstate),
-                        self._masks, self._states)
-                else:
-                    probs, new_states = self._jstep(
-                        self._params, self._variables,
-                        self._dev_array(ids), self._dev_array(live),
-                        self._dev_array(table), self._states)
+                up.append(self._dev_array(table))
             else:
-                prof.count("decode", 0)
-                if mstate is not None:
-                    probs, new_states = self._jstep_m(
-                        self._params, self._variables,
-                        self._dev_array(ids), self._dev_array(live),
-                        self._dev_array(mstate), self._masks,
-                        self._states)
-                else:
-                    probs, new_states = self._jstep(
-                        self._params, self._variables,
-                        self._dev_array(ids), self._dev_array(live),
-                        self._states)
+                nb = 0
+            if mstate is not None:
+                up += [self._dev_array(mstate), self._masks]
+            # stamped after the uploads: the jit call alone lies between
+            # this instant and the program's start on the device
+            prof.count("decode", nb)
+            probs, new_states = (self._jstep if mstate is None
+                                 else self._jstep_m)(
+                self._params, self._variables, *up, self._states)
             self._states = new_states
             prof.begin("decode_wait")
             probs, routed = self._unpack_counts(

@@ -22,10 +22,15 @@ cumulative ``phase_seconds``, the per-phase histograms
 (``decode_step_phase_seconds{phase=...}``) and, while a `jax.profiler`
 trace is on, a ``sched/<phase>`` annotation on the scheduler thread's
 host line inside one ``sched_iter`` step annotation per iteration — the
-program's phases on the device trace's own clock. Appends are plain
+program's phases on the device trace's own clock. Given the engine's
+flight recorder (:meth:`StepPhaseProfiler.attach`), each booked iteration
+also leaves one ``sched_iter`` span on the scheduler's track of the ring,
+always on: its phases and dispatches as offsets from its begin, and the
+thread's CPU seconds outside the ``*_wait`` phases (also summed into the
+counter ``sched_host_cpu_seconds_total``). Appends are plain
 scheduler-thread float arithmetic on preallocated state (the trace
 buffer's lock-free single-writer discipline). What the plane costs on
-the chip is in `PERF.md` (section 6, PR 26).
+the chip is in `PERF.md` (section 6, PR 26 and PR 38).
 
 **Cost attribution** (:func:`program_costs` + the profiler's rolling
 FLOPs window). At warmup, every compiled program family (decode /
@@ -95,6 +100,7 @@ PHASES = ("admit", "prefill_launch", "prefill_wait", "prefill_read",
           "decode_read", "accept", "verify", "flush")
 _READ_OF = {p: p[:-len("_wait")] + "_read" for p in PHASES
             if p.endswith("_wait")}
+_WAITS = frozenset(_READ_OF)
 _NO_SPAN = contextlib.nullcontext()
 
 # Published per-chip peaks keyed by jax ``device_kind`` — the one table
@@ -304,6 +310,28 @@ class StepPhaseProfiler:
     the interval whose seconds its phase is given. A phase may open more
     than once in an iteration (``accept`` follows a prompt's last chunk
     and the decode step); its seconds add up.
+
+    The scheduler thread's CPU time (``time.thread_time``) is read where
+    the iteration begins and ends and where a ``*_wait`` phase begins and
+    ends, so CPU spent inside a blocking wait stays out: what an iteration
+    burns outside its waits goes to ``sched_host_cpu_seconds_total``. Set
+    beside the same phases' wall time it is not 100 % even in a sound run
+    (64-88 % on the v5e's host, PR 38: the ``*_read`` phases sleep in the
+    copy, which counts as host wall time), so a held thread (another
+    process, the machine, the GIL) is a share well under its cell's usual.
+    ``thread_time`` moves in 10 ms ticks on that host: a sum over many
+    iterations is sound, one iteration's ``cpu_s`` only where it is long.
+
+    With a recorder attached, :meth:`iter_end` appends one ``sched_iter``
+    span at the iteration's close, begin and end records both stamped
+    then, in ring order like every other record. The begin's arguments
+    are ``end`` (the iteration's length: it began ``end`` seconds before
+    the record's time), ``phases`` ``[(phase, offset s)]`` in order from
+    that begin (each phase runs to the next one's offset, the last to
+    ``end``), ``dispatches`` ``[(family, bucket, offset s)]`` (stamped by
+    :meth:`count`, after the uploads and just before the jit call) and
+    ``cpu_s``. Two ring appends an iteration; nothing per phase, and
+    nothing at all with the recorder disabled or absent.
     """
 
     def __init__(self, metrics: Optional[MetricsRegistry] = None, *,
@@ -346,10 +374,21 @@ class StepPhaseProfiler:
         # flops-share gauges)
         self.family_dispatches: Dict[str, int] = {}
         self.family_flops: Dict[str, float] = {}
-        # per-iteration scratch, reset by iter_begin
-        self._iter_counts: List[Tuple[str, int, int]] = []
+        # per-iteration scratch, reset by iter_begin: (family, bucket,
+        # offset) a dispatch, and with a recorder (phase, offset) a phase
+        # begun; both lists are handed whole to the iteration's record
+        self._iter_counts: List[Tuple[str, int, float]] = []
+        self._marks: Optional[List[Tuple[str, float]]] = None
         self._phase = "admit"  # the open phase
         self._t_phase = 0.0    # when it began
+        self._t_iter = 0.0     # when the iteration began
+        # CPU seconds outside the waits: accumulated, and where the open
+        # stretch began (thread_time); _in_wait = a *_wait phase is open
+        self._cpu = 0.0
+        self._cpu_mark = 0.0
+        self._in_wait = False
+        self._tracer = None    # attach(): the engine's enabled recorder
+        self._track = "scheduler"
         # the open trace annotations (a TraceMe starts when constructed
         # and records when stopped; both cost well under a microsecond
         # with no trace on)
@@ -378,6 +417,20 @@ class StepPhaseProfiler:
                 help="rolling attributed memory traffic (cost_analysis "
                      "bytes accessed), GB/s")
             self._g_share: Dict[str, object] = {}
+            self._c_cpu = m.counter(
+                "sched_host_cpu_seconds_total",
+                help="scheduler thread CPU seconds (thread_time) in "
+                     "booked iterations outside the *_wait phases; over "
+                     "those phases' wall seconds, well under 1 = the "
+                     "thread was held off the CPU")
+
+    def attach(self, tracer, track: str) -> None:
+        """The engine's flight recorder and its scheduler track: from now
+        on each booked iteration leaves one ``sched_iter`` span there.
+        A disabled profiler or recorder keeps none."""
+        self._tracer = tracer if self.enabled and tracer is not None \
+            and tracer.enabled else None
+        self._track = track
 
     # -- hot path (scheduler thread only) ----------------------------------
     def iter_begin(self, annotate: bool = True) -> None:
@@ -392,7 +445,12 @@ class StepPhaseProfiler:
         if self._iter_counts:
             self._iter_counts.clear()
         self._phase = "admit"
-        self._t_phase = time.monotonic()
+        self._t_phase = self._t_iter = time.monotonic()
+        self._cpu = 0.0
+        self._in_wait = False
+        self._cpu_mark = time.thread_time()
+        if self._tracer is not None:
+            self._marks = [("admit", 0.0)]
         if annotate:
             self._annotate("admit")
 
@@ -413,6 +471,16 @@ class StepPhaseProfiler:
         self._close(now)
         self._phase = phase
         self._t_phase = now
+        wait = phase in _WAITS
+        if wait is not self._in_wait:     # a wait begins or ends
+            c = time.thread_time()
+            if wait:
+                self._cpu += c - self._cpu_mark
+            else:
+                self._cpu_mark = c
+            self._in_wait = wait
+        if self._marks is not None:
+            self._marks.append((phase, now - self._t_iter))
         self._annotate(phase)
 
     @contextlib.contextmanager
@@ -451,10 +519,11 @@ class StepPhaseProfiler:
     def iter_abandon(self) -> None:
         """The pass found nothing to run: its annotations close, and
         nothing is booked (a 10 Hz idle wake stamping microsecond admit
-        phases would swamp the histograms)."""
+        phases would swamp the histograms) and nothing is recorded."""
         self._stop(self._span)
         self._stop(self._iter_span)
         self._span = self._iter_span = None
+        self._marks = None
 
     def idle(self):
         """Context manager around the scheduler's idle wait: a
@@ -463,31 +532,52 @@ class StepPhaseProfiler:
         no phase."""
         return TraceAnnotation("sched/idle") if self.enabled else _NO_SPAN
 
-    def count(self, family: str, bucket: int, n: int = 1) -> None:
-        """Stamp ``n`` dispatches of ``(family, bucket)`` this iteration
-        (one list append; costs resolve at iter_end)."""
+    def count(self, family: str, bucket: int) -> None:
+        """Stamp one dispatch of ``(family, bucket)`` this iteration, after
+        its arguments are on the device and just before the jit call that
+        launches it (one list append; costs resolve at iter_end; with a
+        recorder, the offset is the lower bound on the program's start
+        that a reader of the record aligns by)."""
         if self.enabled:
-            self._iter_counts.append((family, bucket, n))
+            self._iter_counts.append(
+                (family, bucket, time.monotonic() - self._t_iter
+                 if self._tracer is not None else 0.0))
 
     def iter_end(self, tokens: int = 0) -> None:
         """Close the iteration: resolve this iteration's dispatches
-        against the cost table, push one ring entry, and refresh the
-        derived gauges every ``gauge_every`` iterations."""
+        against the cost table, push one ring entry, book its CPU
+        seconds, append its ``sched_iter`` record to an attached
+        recorder, and refresh the derived gauges every ``gauge_every``
+        iterations."""
         if not self.enabled:
             return
+        if not self._in_wait:
+            self._cpu += time.thread_time() - self._cpu_mark
         now = time.monotonic()
+        counts = self._iter_counts
+        if self._marks is not None:
+            # at once, so that the record's own time is `now` to a
+            # microsecond: a reader takes the begin as its time less end
+            self._tracer.begin(
+                "sched_iter", track=self._track,
+                args={"end": now - self._t_iter, "phases": self._marks,
+                      "dispatches": counts, "cpu_s": self._cpu})
+            self._tracer.end("sched_iter", track=self._track)
+            self._marks = None
+            self._iter_counts = []      # the record keeps the old list
         self._close(now)
+        self._c_cpu.inc(self._cpu)
         self._stop(self._iter_span)  # the step ends with its last phase
         self._iter_span = None
         flops = bytes_ = 0.0
-        for family, bucket, n in self._iter_counts:
+        for family, bucket, _ in counts:
             c = self.costs.get((family, bucket))
             self.family_dispatches[family] = \
-                self.family_dispatches.get(family, 0) + n
+                self.family_dispatches.get(family, 0) + 1
             if c is not None:
-                f = c["flops"] * n
+                f = c["flops"]
                 flops += f
-                bytes_ += c["bytes"] * n
+                bytes_ += c["bytes"]
                 self.family_flops[family] = \
                     self.family_flops.get(family, 0.0) + f
         self.flops_total += flops

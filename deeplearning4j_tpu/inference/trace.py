@@ -35,7 +35,12 @@ Record taxonomy (the span tree every request gets):
   plus scheduler-level instants: slot ``admit``/``free`` occupancy
   changes, ``pool_evict``/``pool_publish`` from the KV pool, ``compile``
   events (via `analysis.runtime.CompileCounter` cache-size deltas), and
-  ``reject`` instants for backpressure 503s / 413s / 504s.
+  ``reject`` instants for backpressure 503s / 413s / 504s; and one
+  ``sched_iter`` span per booked scheduler iteration on the scheduler
+  track, written whole at its close by `profiler.StepPhaseProfiler`
+  (begin and end both stamped then, in ring order; the iteration began
+  ``end`` seconds before the record, its phases and dispatches are
+  offsets from that begin, with its CPU seconds).
 
   Paged-KV engines (engine.paged) add block-lifecycle instants on the
   slot tracks — ``block_alloc`` (lazy allocation as ``pos`` crosses a

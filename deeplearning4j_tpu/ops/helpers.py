@@ -125,9 +125,10 @@ def lstm_sequence(xproj_t: Array, rw: Array, peep: Array, h0: Array, c0: Array,
 # -- pool2d --------------------------------------------------------------------
 
 def _pool2d_default(x: Array, *, kind, kernel, stride, padding, pnorm=2) -> Array:
-    # NOTE (r4 device-trace study, tools/trace_alexnet.py): reduce_window is
-    # the RIGHT lowering here. Alternatives tried and measured worse on the
-    # full AlexNet step: rank-6 reshape+max (its gradient materializes
+    # NOTE (r4 device-trace study, tools/trace_alexnet.py, last in commit
+    # dd74740): reduce_window is the RIGHT lowering here. Alternatives
+    # tried and measured worse on the full AlexNet step: rank-6
+    # reshape+max (its gradient materializes
     # [B,H/2,2,W/2,2,C] broadcasts) and strided-slice pairwise max (layout
     # copies around every strided read). select-and-scatter for the 2x2/s2
     # backward runs at ~memory roofline for the large shapes; the remaining
@@ -213,8 +214,9 @@ def bn_act_pool(x, gamma, beta, *, eps=1e-5, activation="relu"):
     ONE composite op, returning (pooled, batch_mean32, batch_var32).
 
     Why a composite exists at the seam: the device trace of the AlexNet
-    train step (tools/trace_alexnet.py) shows XLA's BACKWARD for this
-    layer-pair costs ~4 HBM passes over the largest activations
+    train step (tools/trace_alexnet.py, last in commit dd74740) shows
+    XLA's BACKWARD for this layer-pair costs ~4 HBM passes over the
+    largest activations
     (select-and-scatter pool grad + act/BN-dx passes + two stat-grad
     reductions); a fused custom-VJP kernel does it in two
     (ops/pallas_kernels.py). Reference analog: the cuDNN BN helper fuses

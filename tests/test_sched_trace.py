@@ -9,7 +9,10 @@ time, the wait for the device and the copy to the host are told apart, and
 a disarmed profiler books and annotates nothing. (iii) The names that the
 benchmark's accepted readers match — the two paged programs' module names
 and the ring's dispatch spans — are pinned, so that a refactor cannot null
-a roofline in silence.
+a roofline in silence. (iv) Each booked iteration leaves one `sched_iter`
+record on the flight recorder's ring (ISSUE 38) from which the benchmark's
+readers rebuild its phases and dispatches, and the scheduler thread's CPU
+seconds outside the waits go to `sched_host_cpu_seconds_total`.
 """
 import glob
 import re
@@ -28,6 +31,8 @@ from deeplearning4j_tpu.inference.kvpool import SCRATCH_BLOCK
 from deeplearning4j_tpu.inference.trace import FlightRecorder
 from deeplearning4j_tpu.models.zoo import transformer_lm
 from deeplearning4j_tpu.nn.graph import ComputationGraph
+
+from benchmark.harness import engine_driver
 
 V = 13
 CHUNK = 16
@@ -128,7 +133,8 @@ def test_trace_carries_the_phases_on_the_schedulers_line(eng, tmp_path):
 
 # ------------------------------------------ (ii) phases partition the lap --
 class _Ticks:
-    """A clock that advances one millisecond per reading."""
+    """A clock that advances one millisecond per reading; the thread's CPU
+    clock stands still."""
 
     def __init__(self):
         self.now = 0.0
@@ -136,6 +142,9 @@ class _Ticks:
     def monotonic(self):
         self.now += 0.001
         return self.now
+
+    def thread_time(self):
+        return 0.0
 
 
 def test_phases_partition_the_iteration(monkeypatch):
@@ -312,6 +321,163 @@ def test_ring_carries_the_dispatch_spans_the_benchmark_reads(eng):
     assert [(e["args"]["bucket"], e["args"]["tokens"]) for e in chunks] \
         == [(16, 16), (16, 8)]
     assert all(e["args"]["request"] == h.request_id for e in chunks)
-    # no record per phase: the profiler's trace shows those
+    # no record per phase: one `sched_iter` an iteration carries them
     assert not any(e["name"] in profiler_mod.PHASES
-                   or e["name"].startswith("sched") for e in new)
+                   or e["name"].startswith("sched/") for e in new)
+
+
+# ------------------------------------- (iv) the iteration on the ring ------
+def _sched_iters(eng, t_lo, t_hi):
+    """What a benchmark reader gets: `engine_driver.spans_between`'s begin
+    records, arguments merged in."""
+    return [s for s in engine_driver.spans_between(eng, t_lo, t_hi)
+            if s["name"] == "sched_iter"]
+
+
+def _snap(eng):
+    prof = eng.profiler
+    return (dict(prof.phase_seconds), prof.iterations,
+            dict(prof.family_dispatches),
+            eng.metrics.snapshot()["counters"]["sched_host_cpu_seconds_total"])
+
+
+def test_each_booked_iteration_leaves_one_record_that_rebuilds_it(eng):
+    time.sleep(0.25)                          # between iterations
+    ph0, it0, d0, cpu0 = _snap(eng)
+    t_lo = time.monotonic()
+    eng.generate(_prompt(4, 40), 6, timeout=120)
+    time.sleep(0.35)                          # three idle wakes, no record
+    t_hi = time.monotonic()
+    ph1, it1, d1, cpu1 = _snap(eng)
+    recs = _sched_iters(eng, t_lo, t_hi)
+    n = it1 - it0
+    assert n >= 6 and len(recs) == n          # one a booked iteration
+    assert all(s["track"] == eng._sched_track for s in recs)
+    # the phases, each to the next one's offset and the last to `end`, add
+    # up per phase to what the profiler booked
+    rebuilt = dict.fromkeys(ph0, 0.0)
+    for s in recs:
+        offs = [off for _, off in s["phases"]] + [s["end"]]
+        assert s["phases"][0] == ("admit", 0.0)
+        assert offs == sorted(offs)
+        for (name, off), nxt in zip(s["phases"], offs[1:]):
+            rebuilt[name] += nxt - off
+    for name in ph0:
+        assert abs(rebuilt[name] - (ph1[name] - ph0[name])) <= 1e-6 * n
+    # every dispatch is there with its bucket, inside its iteration
+    disp = [d for s in recs for d in s["dispatches"]]
+    assert sum(1 for f, _, _ in disp if f == "decode") \
+        == d1["decode"] - d0["decode"] >= 5
+    assert sum(1 for f, _, _ in disp if f == "prefill") \
+        == d1["prefill"] - d0["prefill"] == 3
+    assert {b for f, b, _ in disp if f == "decode"} \
+        <= set(eng.table_buckets)
+    assert {b for f, b, _ in disp if f == "prefill"} \
+        <= set(eng.prefill_buckets)
+    assert all(0 <= off <= s["end"] for s in recs
+               for _, _, off in s["dispatches"])
+    # CPU seconds outside the waits: no more than their wall seconds
+    wall = sum(ph1[k] - ph0[k] for k in ph0 if not k.endswith("_wait"))
+    assert 0 <= cpu1 - cpu0 <= wall + 1e-4 * n
+    assert cpu1 - cpu0 == pytest.approx(sum(s["cpu_s"] for s in recs))
+    # written at the close, in ring order: the record's time is the
+    # iteration's end, after every record of its own, and its end record
+    # sits beside it, so the Chrome export stays nested
+    evs = [e for e in eng.tracer.events()
+           if e["track"] == eng._sched_track]
+    for i, e in enumerate(evs):
+        if e["name"] == "sched_iter" and e["ph"] == "B":
+            assert (evs[i + 1]["ph"], evs[i + 1]["name"]) \
+                == ("E", "sched_iter")
+    seqs = [e["seq"] for e in eng.tracer.events()]
+    assert seqs == sorted(seqs)
+    chrome = [e for e in eng.tracer.chrome_trace()["traceEvents"]
+              if e.get("name") in ("sched_iter", "decode_step")]
+    depth = 0
+    for e in chrome:
+        depth += {"B": 1, "E": -1}.get(e["ph"], 0)
+        assert 0 <= depth <= 1, "a span opened inside another"
+
+
+def test_a_tail_of_the_serving_ring_misses_nothing(eng):
+    """`TraceAggregator` tails the engine's ring by cursor while it serves:
+    the `sched_iter` records, written at the close in ring order, leave no
+    hole that reads as a `ring_dropped`."""
+    from deeplearning4j_tpu.serving.telemetry import TraceAggregator
+    agg = TraceAggregator([], client_recorder=eng.tracer)
+    agg.sync_clocks()
+    agg.poll()                                # up to now
+    src = agg._sources[0]
+    seq0 = src.cursor
+    hs = [eng.submit(_prompt(20 + k, 24 + 4 * k), 5) for k in range(3)]
+    while not all(h.done() for h in hs):
+        agg.poll()
+        time.sleep(0.002)
+    for h in hs:
+        h.result(timeout=120)
+    agg.poll()
+    assert agg.stats()["dropped_total"] == 0
+    assert not any(e["name"] == "ring_dropped" for e in src.events)
+    got = [e["seq"] for e in src.events if e["seq"] >= seq0]
+    assert got == list(range(seq0, src.cursor))   # every record, once
+    assert sum(e["name"] == "sched_iter" for e in src.events) >= 5
+
+
+def test_no_record_without_an_enabled_recorder():
+    off = FlightRecorder(64, enabled=False)
+    prof = StepPhaseProfiler(MetricsRegistry())
+    prof.attach(off, "scheduler")
+    _one_pass(prof)
+    assert prof.iterations == 1 and prof._marks is None
+    assert off.events() == [] and set(off._buf) == {None}
+    # an armed recorder gets the two records of the same pass
+    on = FlightRecorder(64)
+    prof.attach(on, "scheduler")
+    _one_pass(prof)
+    prof.iter_begin()
+    prof.iter_abandon()                      # an idle wake writes nothing
+    assert [(e["ph"], e["name"]) for e in on.events()] == [
+        ("B", "sched_iter"), ("E", "sched_iter")]
+    args = on.events()[0]["args"]
+    assert [p for p, _ in args["phases"]] == [
+        "admit", "decode_wait", "decode_read", "accept"]
+    assert [(f, b) for f, b, _ in args["dispatches"]] == [("decode", 0)]
+
+
+def test_a_disarmed_profiler_never_reads_the_cpu_clock(monkeypatch):
+    class _NoCpu:
+        monotonic = staticmethod(time.monotonic)
+
+        @staticmethod
+        def thread_time():
+            raise AssertionError("thread_time read by a disarmed profiler")
+
+    monkeypatch.setattr(profiler_mod, "time", _NoCpu)
+    prof = StepPhaseProfiler(MetricsRegistry(), enabled=False)
+    prof.attach(FlightRecorder(64), "scheduler")
+    _one_pass(prof)
+    assert prof._tracer is None and prof.iterations == 0
+
+
+def test_cpu_outside_the_waits_is_what_is_booked(monkeypatch):
+    """thread_time is read at the iteration's begin and end and where a
+    wait begins and ends; what the thread burns inside a wait stays out."""
+    class _Cpu(_Ticks):
+        cpu = 0.0
+
+        def thread_time(self):
+            self.cpu += 0.010
+            return self.cpu
+
+    clock = _Cpu()
+    monkeypatch.setattr(profiler_mod, "time", clock)
+    m = MetricsRegistry()
+    prof = StepPhaseProfiler(m)
+    prof.iter_begin()                    # cpu 0.01
+    prof.begin("decode_launch")
+    prof.begin("decode_wait")            # 0.02: 0.01 outside
+    prof.ready()                         # 0.03
+    prof.begin("accept")
+    prof.iter_end()                      # 0.04: 0.01 outside
+    assert m.snapshot()["counters"]["sched_host_cpu_seconds_total"] \
+        == pytest.approx(0.02)

@@ -1,6 +1,6 @@
 """Micro-benchmark max-pool 2x2/s2 fwd+bwd variants on AlexNet shapes,
 measured INSIDE a lax.scan so the dispatch+fetch round trip amortizes away
-(see tools/xplane_summary.py).
+(see tools/xplane_summary.py, last in commit dd74740).
 
 Run on the chip.
 """
