@@ -98,6 +98,7 @@ from ..nn.layers.experts import RoutedExpertsLayerImpl
 from ..nn.layers.recurrent import (BaseRecurrentImpl,
                                    _materialize_rnn_states)
 from ..nn.multilayer import _compute_dtype_of
+from ..ops.grouped_matmul import row_tile, tile_visits
 from . import failpoints
 from .batcher import QueueFullError, bucket_for, pow2_buckets
 from .kvpool import PAGE_KEYS, SCRATCH_BLOCK, KVPool
@@ -1243,6 +1244,11 @@ class DecodeScheduler:
             self._m_moe_hit = m.counter(
                 "moe_experts_hit_total",
                 help="of those, the experts at least one token chose")
+            self._m_moe_passes = m.counter(
+                "moe_weight_passes_total",
+                help="(expert, row tile) visits of the grouped-matmul "
+                     "kernel, decode dispatches: over moe_experts_hit_total "
+                     "how often a hit expert's weights are walked")
         if latent is not None:
             # rows decode tokens attended over, from host-side depths
             self._m_mla_rows = m.counter(
@@ -3666,6 +3672,9 @@ class DecodeScheduler:
         if decode:
             self._m_moe_slots.inc(counts.size)
             self._m_moe_hit.inc(int((counts > 0).sum()))  # graftlint: disable=JG006
+            # the visits the kernel's grid made, by its own function
+            self._m_moe_passes.inc(int(tile_visits(  # graftlint: disable=JG006
+                counts, row_tile(self.n_slots * self._moe_top_k)).sum()))
 
     def _trace_compiles(self) -> None:
         """Instant event per NEW XLA program: the per-family jit-cache

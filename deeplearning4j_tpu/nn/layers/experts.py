@@ -19,20 +19,34 @@ capacity factor and no token is dropped. On one device the layer runs
 without its exchange; nothing here stands in for the absent devices
 (`parallel/moe.py` is another thing: top-1, capacity-dropping, `shard_map`).
 
-The grouped path (`_grouped`, inference) sorts the token-expert pairs that
-fall on held experts by expert and walks them in tiles of ``tm`` rows, each
-tile one expert's: a `fori_loop` over the tiles that hold a pair, so an
-expert no token chose is not read and the cost follows the routing, at
-static shapes (``tokens * top_k`` pairs at most). The dense path (`_dense`,
-training: the loop's trip count is data) runs every held expert over every
-token under a mask, and is what the grouped path is tested against.
+The grouped path (`_grouped`, inference) sorts the ``tokens * top_k``
+token-expert pairs by held expert (pairs not ours last), gathers their rows
+once, and multiplies each matrix in one grouped-matmul kernel call over the
+sorted rows (`ops/grouped_matmul.py`): the kernel walks the (expert, row
+tile) visits of the experts that hold a pair, streaming each visited
+expert's weights while the previous block is multiplied, so an expert no
+token chose is never read and the cost follows the routing, at static
+shapes. The arithmetic is `_expert`'s: operands promoted as its `jnp.dot`
+promotes them, float32 accumulation, ``h`` rounded to the activations' dtype before the
+down matrix; the down matrix's call adds each row, times its float32 gate,
+into its token's row, so no scatter and no loop over ``[tokens, hidden]``
+is left to XLA. A stack whose width is not a multiple of 128 (Nemotron's
+up matrix, 1,856) is laid out by the TPU with its depth minor, and the
+kernel reads it transposed, as it lies: a bitcast, not a copy. Tiles follow
+the shapes (`row_tile`, `_depth_block`); nothing chooses the path but
+``train``. The dense path (`_dense`, training) runs every held expert over
+every token under a mask, and is what the grouped path is tested against.
 
 At inference the layer hands back, as its ``routing_counts`` variable, the
 pairs that fell on each held expert (int32 [count]); the serving engine
-reads them with the step's probabilities (`inference/engine.py`). A feature
-``mask`` ([B, T]; the engine's live lanes) keeps padded lanes out of the
-routing altogether."""
+reads them with the step's probabilities (`inference/engine.py`) and counts,
+besides, the kernel's visits from them by the function its grid uses
+(`tile_visits`: ``moe_weight_passes_total``, one a hit expert when its pairs
+fit one row tile). A feature ``mask`` ([B, T]; the engine's live lanes)
+keeps padded lanes out of the routing altogether."""
 from __future__ import annotations
+
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -40,8 +54,8 @@ import jax.numpy as jnp
 from .base import LayerImpl, register_impl
 from .. import weights as winit
 from ...ops import activations
-
-_TILE = 128     # rows of one expert a grouped step multiplies at once
+from ...ops.grouped_matmul import (grouped_matmul, grouped_matmul_sum,
+                                   row_tile)
 
 
 @register_impl("RoutedExpertsLayer")
@@ -135,30 +149,22 @@ class RoutedExpertsLayerImpl(LayerImpl):
         return y
 
     def _grouped(self, params, x, local, gates, counts):
-        """The pairs on held experts, sorted by expert, in tiles of ``tm``
-        rows of one expert each; only tiles that hold a pair run."""
-        _, G = self._held()
+        """The pairs sorted by expert (those not ours last), each matrix one
+        grouped-matmul kernel call over the sorted rows; the down matrix's
+        call adds each row, under its gate, into its token's row."""
         N, k = local.shape
-        tm = min(_TILE, -(-N // 8) * 8)
+        act = activations.get(self.conf.expert_activation)
         order = jnp.argsort(local.reshape(-1), stable=True)   # ours first
-        tok = (order // k).astype(jnp.int32)
-        gate = gates.reshape(-1)[order]
-        tiles = -(-counts // tm)                               # [G] per expert
-        tile_end = jnp.cumsum(tiles)
-        row0 = jnp.cumsum(counts) - counts     # an expert's first sorted row
-        lane = jnp.arange(tm, dtype=jnp.int32)
-
-        def tile(t, y):
-            e = jnp.searchsorted(tile_end, t, side="right").astype(jnp.int32)
-            at = (t - (tile_end[e] - tiles[e])) * tm + lane    # rows within e
-            src = jnp.minimum(row0[e] + at, N * k - 1)
-            ours = at < counts[e]
-            rows = tok[src]
-            out = self._expert(params, e, x[rows])
-            return y.at[rows].add(out * jnp.where(ours, gate[src], 0.0)[:, None])
-
-        return jax.lax.fori_loop(0, tile_end[-1], tile,
-                                 jnp.zeros(x.shape, jnp.float32))
+        tok = order // k
+        xs = x[tok]
+        kw = dict(tm=row_tile(N * k), interpret=jax.default_backend() != "tpu")
+        mm = partial(grouped_matmul, sizes=counts, **kw)
+        if self.conf.gated:
+            h = act(mm(xs, params["Wg"])) * mm(xs, params["Wu"])
+        else:
+            h = act(mm(xs, params["Wu"]))
+        return grouped_matmul_sum(h.astype(xs.dtype), params["Wd"], counts,
+                                  tok, gates.reshape(-1)[order], n=N, **kw)
 
     def forward(self, params, x, *, train=False, rng=None, variables=None,
                 mask=None):
